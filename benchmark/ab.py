@@ -93,7 +93,6 @@ def run_leg(
     """One subprocess bench leg, bracketed by calibration probes."""
     probe_before = calibrate.calibration_probe()
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.setdefault("NARWHAL_TPU_PREWARM", "0")
     env["NARWHAL_PERF_LEDGER"] = "0"  # the driver appends the one record
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tmp:

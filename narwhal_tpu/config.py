@@ -29,12 +29,10 @@ Stake = int
 
 
 class ConfigError(ValueError):
-    """Operator-facing misconfiguration (mis-sized shard count, bad flag
-    combination): always fatal at boot, never fallback-able. Distinct from
-    plain ValueError so callers with a documented degradation path (e.g.
-    strict-rule nodes falling back to host crypto when the device verifier
-    fails for NON-config reasons) can re-raise config mistakes while still
-    degrading on environmental ones."""
+    """Operator-facing misconfiguration (mis-sized shard count, more shards
+    than devices, a device backend with no device, bad flag combination):
+    always fatal at boot. Distinct from plain ValueError so a caller can
+    tell an operator's mistake from an environmental failure."""
 
 
 @dataclass

@@ -1,15 +1,21 @@
 """ctypes loader for the native (C++) runtime components.
 
-Builds native/storage_engine.cpp into a shared library on first use (cached
-by source mtime) and exposes a thin wrapper. Loading is best-effort: when the
-toolchain or library is unavailable the callers fall back to the pure-Python
-implementations, so the framework never hard-depends on a compiler at
-runtime. Disable explicitly with NARWHAL_NATIVE=0.
+Builds native/*.cpp into shared libraries on first use and exposes thin
+wrappers. A library is keyed by the CONTENT of its source and build flags
+(`lib<name>.<sha256 prefix>.so`, git-ignored): a stale library copied
+along with the tree, or one whose mtime a copy did not keep, can never be
+what loads — only a library built from the `.cpp` beside it. Loading is
+best-effort: when the toolchain or library is unavailable the callers fall
+back to the pure-Python implementations, so the framework never
+hard-depends on a compiler at runtime (`chip_smoke.py` does: it fails
+rather than run the fallbacks). Disable explicitly with NARWHAL_NATIVE=0.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -18,9 +24,8 @@ logger = logging.getLogger("narwhal.native")
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_ROOT, "native", "storage_engine.cpp")
-_LIB = os.path.join(_ROOT, "native", "libnarwhal_storage.so")
 _SCALAR_SRC = os.path.join(_ROOT, "native", "scalar_ops.cpp")
-_SCALAR_LIB = os.path.join(_ROOT, "native", "libnarwhal_scalar.so")
+_CXX = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
 
 _lib: ctypes.CDLL | None = None
 _tried = False
@@ -28,23 +33,31 @@ _scalar: ctypes.CDLL | None = None
 _scalar_tried = False
 
 
-def _build_lib(src: str, lib: str, extra: list[str]) -> bool:
+def _build_lib(src: str, stem: str, extra: list[str]) -> str | None:
+    """Path of the library built from `src` as it is now, building it if
+    no library with this content key exists; None when the build fails."""
     try:
-        if os.path.exists(lib) and os.path.getmtime(lib) >= os.path.getmtime(src):
-            return True
+        with open(src, "rb") as f:
+            key = hashlib.sha256(f.read() + " ".join(_CXX + extra).encode())
+        base = os.path.join(os.path.dirname(src), f"lib{stem}")
+        lib = f"{base}.{key.hexdigest()[:16]}.so"
+        if os.path.exists(lib):
+            return lib
+        # Build beside the target and rename into place: concurrent node
+        # processes (LocalBench boots a fleet at once) each see either no
+        # library or a whole one.
+        tmp = f"{lib}.{os.getpid()}.tmp"
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", lib, src, *extra],
-            check=True,
-            capture_output=True,
+            [*_CXX, "-o", tmp, src, *extra], check=True, capture_output=True
         )
-        return True
+        os.replace(tmp, lib)
+        for old in glob.glob(f"{base}.*so"):  # also matches lib<stem>.so
+            if old != lib:
+                os.unlink(old)  # libraries of earlier source revisions
+        return lib
     except (OSError, subprocess.CalledProcessError) as e:
         logger.warning("native build of %s failed: %s", os.path.basename(src), e)
-        return False
-
-
-def _build() -> bool:
-    return _build_lib(_SRC, _LIB, ["-lz"])
+        return None
 
 
 def load() -> ctypes.CDLL | None:
@@ -55,10 +68,11 @@ def load() -> ctypes.CDLL | None:
     _tried = True
     if os.environ.get("NARWHAL_NATIVE", "1") == "0":
         return None
-    if not os.path.exists(_SRC) or not _build():
+    path = _build_lib(_SRC, "narwhal_storage", ["-lz"])
+    if path is None:
         return None
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
     except OSError as e:
         logger.warning("native storage engine load failed: %s", e)
         return None
@@ -113,10 +127,11 @@ def load_scalar() -> ctypes.CDLL | None:
     _scalar_tried = True
     if os.environ.get("NARWHAL_NATIVE", "1") == "0":
         return None
-    if not os.path.exists(_SCALAR_SRC) or not _build_lib(_SCALAR_SRC, _SCALAR_LIB, []):
+    path = _build_lib(_SCALAR_SRC, "narwhal_scalar", [])
+    if path is None:
         return None
     try:
-        lib = ctypes.CDLL(_SCALAR_LIB)
+        lib = ctypes.CDLL(path)
     except OSError as e:
         logger.warning("native scalar pipeline load failed: %s", e)
         return None

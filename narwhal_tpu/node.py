@@ -192,9 +192,19 @@ class PrimaryNode:
                 f"--verify-shards {verify_shards} requires --crypto-backend "
                 f"tpu (got {crypto_backend!r})"
             )
+        if "tpu" in (crypto_backend, dag_backend):
+            # A device backend never serves from the host: no chip (JAX on
+            # a CPU platform the operator did not ask for), a device
+            # verifier that cannot be built, or more shards than devices
+            # each stop the boot here.
+            from .tpu import require_device_platform
+
+            require_device_platform(
+                "--crypto-backend" if crypto_backend == "tpu" else "--dag-backend"
+            )
         crypto_pool = None
         if crypto_backend == "tpu":
-            from .tpu.verifier import AsyncVerifierPool, VerifyService
+            from .tpu.verifier import VerifyService
 
             if rule == "cofactored":
                 logger.warning(
@@ -204,39 +214,15 @@ class PrimaryNode:
                     "consensus-split hazard on crafted torsion signatures"
                 )
             mode = "msm" if rule == "cofactored" else "item"
-            try:
-                # ONE pipelined service per process: every node on this
-                # host shares flushes, so the device link RTT is paid per
-                # merged batch, not per protocol hop (the VERDICT r3
-                # crypto=tpu stall at N=20). --verify-shards N spreads
-                # every flush over an N-device 'data' mesh
-                # (verifier.data_mesh); bucket divisibility is validated
-                # inside the TpuVerifier constructor, so a mis-sized mesh
-                # fails the boot, not the first dispatch.
-                crypto_pool = VerifyService.shared(mode, shards=verify_shards)
-            except ConfigError:
-                # Mis-sized shard count / bad mesh: operator error, never
-                # fallback. Plain ValueErrors from inside jax/TpuVerifier
-                # device init are ENVIRONMENTAL and fall through to the
-                # documented strict-rule host-crypto degradation below.
-                raise
-            except Exception:
-                # Under the cofactored rule the device path is mandatory: a
-                # host fallback would run the STRICT accept set — a
-                # consensus-split hazard (safety beats liveness; the node
-                # refuses to start instead). Strict-rule nodes degrade to
-                # the host pool, which implements the same accept set.
-                if rule == "cofactored":
-                    raise RuntimeError(
-                        "TPU verifier unavailable but the committee's "
-                        "verify rule requires it (host fallback implements "
-                        "a different accept set); refusing to start"
-                    )
-                logger.exception(
-                    "TPU verifier unavailable; degrading to the host pool "
-                    "(same strict accept set)"
-                )
-                crypto_pool = AsyncVerifierPool()
+            # ONE pipelined service per process: every node on this host
+            # shares flushes, so dispatch + readback latency is paid per
+            # merged batch, not per protocol hop. --verify-shards N
+            # spreads every flush over an N-device 'data' mesh
+            # (verifier.data_mesh); bucket divisibility is validated
+            # inside the TpuVerifier constructor, so a mis-sized mesh
+            # fails the boot, not the first dispatch. Whatever this
+            # raises stops the boot, under both verify rules.
+            crypto_pool = VerifyService.shared(mode, shards=verify_shards)
         elif crypto_backend == "pool":
             from .tpu.verifier import AsyncVerifierPool
 
@@ -296,36 +282,14 @@ class PrimaryNode:
                     consensus_protocol
                 ]
                 # --dag-shards > 1: shard the committee axis of the window
-                # over an 'auth' device mesh (ICI collectives). The CPU
-                # fallback only helps when the host platform is forced to
-                # multiple virtual devices (tests/dryrun set
-                # xla_force_host_platform_device_count); a plain single-chip
-                # host raises rather than silently degrading, and falling
-                # back from a too-small accelerator platform is logged so
-                # no benchmark silently attributes CPU numbers to the chip.
+                # over an 'auth' device mesh (ICI collectives) of the first
+                # dag_shards of jax.devices(). (Tests force the CPU
+                # platform to 8 host devices, so theirs is the CPU list.)
                 mesh = None
                 if dag_shards > 1:
-                    import jax
-                    import numpy as _np
-                    from jax.sharding import Mesh
+                    from .tpu import device_mesh
 
-                    devs = jax.devices()
-                    if len(devs) < dag_shards:
-                        cpus = jax.devices("cpu")
-                        if len(cpus) < dag_shards:
-                            raise ValueError(
-                                f"--dag-shards {dag_shards} exceeds available "
-                                f"devices ({len(devs)} {devs[0].platform}, "
-                                f"{len(cpus)} cpu)"
-                            )
-                        logger.warning(
-                            "--dag-shards %d exceeds the %d-device %s "
-                            "backend; sharding over %d virtual CPU devices "
-                            "instead",
-                            dag_shards, len(devs), devs[0].platform, dag_shards,
-                        )
-                        devs = cpus
-                    mesh = Mesh(_np.array(devs[:dag_shards]), ("auth",))
+                    mesh = device_mesh(dag_shards, "auth", "--dag-shards")
                 protocol = protocol_cls(
                     committee, storage.consensus_store, parameters.gc_depth,
                     mesh=mesh,

@@ -463,8 +463,8 @@ def verify_batch_kernel(a_y, a_sign, r_y, r_sign, k_digits, s_digits):
 # dispatchable stages. The monolith above compiles as ONE XLA module whose
 # graph holds ~3.5 exponentiation-ladder instances (A decompress, R
 # decompress, the final fe_invert) plus the 64-window scan — minutes of
-# single-core LLVM per (kernel, mesh shape), the MULTICHIP_r05 rc=124
-# bill. The staged pipeline compiles three bounded modules instead:
+# LLVM per (kernel, mesh shape) on XLA:CPU. The staged pipeline compiles
+# three bounded modules instead:
 #
 #   decompress (ONE ladder, dispatched twice: A then R — one compile
 #   serves both point sets, and the msm pipeline reuses the same stage)
@@ -477,7 +477,8 @@ def verify_batch_kernel(a_y, a_sign, r_y, r_sign, k_digits, s_digits):
 # body and the epilogue are the same functions, batched the same way — so
 # verdicts are bit-equal (pinned by tests/test_multichip.py). The mesh-
 # sharded verifier dispatches these; the single-chip path keeps the
-# monolith (one dispatch per bucket matters through a high-RTT link).
+# monolith (one dispatch per bucket). Which family a locally attached
+# chip prefers is unmeasured — ROADMAP D1 picks one from chip numbers.
 # ---------------------------------------------------------------------------
 
 

@@ -6,27 +6,25 @@ for separately before unifying them:
 
 - **Compile dedupe.** Each `jax.jit(...)` call owns its own trace/compile
   cache, so two wrappers over the same kernel+mesh each pay the full
-  multi-minute XLA compile (the MULTICHIP_r05 rc=124 bill: verifier.py's
-  `_sharded_kernels` and dag_kernels' per-mesh jits were separate caches
-  that could still double-compile through independent construction
-  paths). The registry is the single map (kernel, mesh shape) -> compiled
-  wrapper; every verifier/engine over the same mesh gets the SAME object.
+  XLA compile (verifier.py's `_sharded_kernels` and dag_kernels' per-mesh
+  jits were separate caches that could still double-compile through
+  independent construction paths). The registry is the single map
+  (kernel, mesh shape) -> compiled wrapper; every verifier/engine over
+  the same mesh gets the SAME object.
 - **Compile-wall accounting.** The first dispatch of a (kernel, mesh
   shape, operand shapes) tuple is trace + XLA compile + one execute;
   steady-state dispatches are milliseconds. The registry times every
-  first dispatch and exposes `compile_walls()` so the dryrun/bench
-  artifacts can attribute a slow run to the exact compile that ate it —
-  the MULTICHIP timeline was reconstructed from slow_operation_alarm
-  stderr; now it is part of the result JSON.
+  first dispatch and exposes `compile_walls()` so the smoke/dryrun/bench
+  artifacts can attribute a slow run to the exact compile that ate it.
 - **Buffer donation.** The device-resident window kernels (`roll_window`,
   `place_batch`) update [W, N, N] tensors in place semantically; without
   donation XLA must keep both generations live and copy. Donation is a
   per-kernel property, declared once at registration.
 
-The persistent compilation cache (tpu/__init__.enable_compilation_cache,
-opt-in via NARWHAL_JAX_CACHE_DIR for CPU targets) composes with this:
-the registry guarantees one compile per process, the cache makes that
-compile a deserialization in every process after the first.
+The persistent compilation cache (tpu/__init__.enable_compilation_cache)
+composes with this: the registry guarantees one compile per process, the
+cache makes that compile a deserialization in every process after the
+first.
 """
 
 from __future__ import annotations

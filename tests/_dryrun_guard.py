@@ -6,8 +6,8 @@ dispatch that library code (an unmeshed TpuBullshark, exactly what
 `--dag-backend tpu` wires without `--dag-shards`) reaches through the
 process-default device — and exits non-zero if any kernel output or
 device-resident window tensor lands outside the pinned device list (the
-MULTICHIP_r02/r04 failure class: module-level jits following the process
-default backend instead of the dry run's pinned devices).
+failure class: module-level jits following the process default device
+instead of the dry run's pinned devices).
 
 Executed in its own process: the spy run compiles a kernel set for a
 non-default device, and XLA:CPU's compiler has crashed when that compile

@@ -427,44 +427,74 @@ def test_verify_shards_validated_and_wired(tmp_path):
             node.crypto_pool.shutdown()
 
 
-def test_environmental_valueerror_keeps_host_crypto_fallback(tmp_path, monkeypatch):
-    """ADVICE r5 low (node.py:160): a ValueError escaping VerifyService
-    device init for NON-config reasons (a jax backend hiccup, not operator
-    error) must keep the documented strict-rule host-crypto fallback. Only
-    ConfigError skips it; under the cofactored rule ANY failure refuses to
-    start (host fallback would run a different accept set)."""
-    from dataclasses import replace
-
+def _bare_primary(**kw):
     from narwhal_tpu.fixtures import CommitteeFixture
     from narwhal_tpu.node import NodeStorage, PrimaryNode
-    from narwhal_tpu.tpu.verifier import AsyncVerifierPool, VerifyService
 
     fx = CommitteeFixture(size=4)
     auth = fx.authorities[0]
+    return PrimaryNode(
+        auth.keypair,
+        fx.committee,
+        fx.worker_cache,
+        kw.pop("parameters", fx.parameters),
+        NodeStorage(None),
+        **kw,
+    )
+
+
+@pytest.mark.parametrize("rule", ["strict", "cofactored"])
+def test_tpu_crypto_backend_never_degrades_to_host(monkeypatch, rule):
+    """--crypto-backend tpu never serves from the host: whatever stops the
+    device verifier from being built — an environmental failure inside
+    jax as much as an operator's ConfigError — stops the boot, under BOTH
+    verify rules (the strict rule used to log one line and serve from the
+    host pool, looking healthy with no chip behind it)."""
+    from dataclasses import replace
+
+    from narwhal_tpu.config import Parameters
+    from narwhal_tpu.tpu.verifier import VerifyService
 
     def boom(mode, shards=1, **kw):
         raise ValueError("XLA backend initialization failed")  # environmental
 
     monkeypatch.setattr(VerifyService, "shared", boom)
-
-    def make(**kw):
-        return PrimaryNode(
-            auth.keypair,
-            fx.committee,
-            fx.worker_cache,
-            kw.pop("parameters", fx.parameters),
-            NodeStorage(None),
-            **kw,
-        )
-
-    node = make(crypto_backend="tpu")
-    assert isinstance(node.crypto_pool, AsyncVerifierPool)  # degraded, same accept set
-
-    with pytest.raises(RuntimeError, match="refusing to start"):
-        make(
+    with pytest.raises(ValueError, match="XLA backend initialization failed"):
+        _bare_primary(
             crypto_backend="tpu",
-            parameters=replace(fx.parameters, verify_rule="cofactored"),
+            parameters=replace(Parameters(), verify_rule=rule),
         )
+
+
+@pytest.mark.parametrize(
+    "backend", [{"crypto_backend": "tpu"}, {"dag_backend": "tpu"}]
+)
+def test_device_backend_refuses_an_unasked_for_cpu_platform(monkeypatch, backend):
+    """With libtpu installed and no chip JAX itself lands on CPU; a node
+    asked for a device backend then refuses to boot unless JAX_PLATFORMS
+    names cpu explicitly (conftest does, for every other test)."""
+    from narwhal_tpu.config import ConfigError
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(ConfigError, match="no accelerator"):
+        _bare_primary(**backend)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")  # cpu named: a rehearsal
+    node = _bare_primary(dag_backend="tpu")
+    node.storage.close()
+
+
+def test_more_shards_than_devices_is_a_config_error():
+    """Neither shard flag moves to virtual CPU devices behind the
+    operator's back: conftest forces 8 host devices, 16 is too many."""
+    from narwhal_tpu.config import ConfigError
+    from narwhal_tpu.tpu.verifier import data_mesh
+
+    with pytest.raises(ConfigError, match="exceeds the 8 cpu devices"):
+        data_mesh(16)
+    with pytest.raises(ConfigError, match="exceeds the 8 cpu devices"):
+        _bare_primary(crypto_backend="tpu", verify_shards=16)
+    with pytest.raises(ConfigError, match="--dag-shards 16 exceeds the 8 cpu"):
+        _bare_primary(dag_backend="tpu", dag_shards=16)
 
 
 @pytest.mark.slow  # the device-crypto kernel compiles take minutes on a
