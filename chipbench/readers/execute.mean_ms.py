@@ -1,0 +1,6 @@
+"""Mean of the execute stage over the window: histogram sum over count, all validators."""
+
+
+def read(obs):
+    total, count = obs["window"]["stages"]["execute"]
+    return 1000.0 * total / count if count else None
