@@ -104,42 +104,6 @@ def _run_leg(n_devices: int, dryrun: bool) -> dict:
     return record
 
 
-def _epilogue_profile() -> dict:
-    """ROADMAP item 5's denominator, measured alongside the dryrun leg: a
-    small traced FusedCertificatePipeline run (the test_multichip shape —
-    fixed bucket 32 on a 4-device mesh) whose flight dump feeds
-    tools/perf/epilogue.attribute into the per-batch breakdown. The
-    sub-span books (epilogue_unpack + epilogue_commit vs host_epilogue)
-    must balance within 10% — the acceptance gate for the attributor."""
-    import jax
-
-    from narwhal_tpu.consensus import ConsensusState
-    from narwhal_tpu.fixtures import CommitteeFixture, make_signed_certificates
-    from narwhal_tpu.stores import NodeStorage
-    from narwhal_tpu.tpu.dag_kernels import TpuBullshark
-    from narwhal_tpu.tpu.pipeline import FusedCertificatePipeline
-    from narwhal_tpu.tpu.verifier import TpuVerifier, data_mesh
-    from narwhal_tpu.tracing import Tracer
-    from narwhal_tpu.types import Certificate
-    from tools.perf import epilogue
-
-    f = CommitteeFixture(size=4)
-    genesis = {c.digest for c in Certificate.genesis(f.committee)}
-    certs, _ = make_signed_certificates(f, 1, 10, genesis)
-    tracer = Tracer(node="epilogue-profile", enabled=True, sample=1.0, ring=2048)
-    verifier = TpuVerifier(
-        max_bucket=32, msm_min_bucket=16, mode="item", fixed_bucket=True,
-        mesh=data_mesh(4, devices=jax.devices("cpu")[:4]),
-    )
-    state = ConsensusState(Certificate.genesis(f.committee))
-    engine = TpuBullshark(f.committee, NodeStorage(None).consensus_store, 50)
-    pipe = FusedCertificatePipeline(verifier, engine, state, tracer=tracer)
-    for lo in range(0, len(certs), 8):  # 8 certs x 3 sigs = 24 <= bucket 32
-        pipe.feed(certs[lo:lo + 8])
-    pipe.drain()
-    return epilogue.attribute([tracer.dump()])
-
-
 def leg_main(n_devices: int, dryrun: bool) -> None:
     """Subprocess body: sharded verify rate + compile walls (+ the driver
     dryrun contract when --dryrun). Emits ONE marked JSON line."""
@@ -163,9 +127,6 @@ def leg_main(n_devices: int, dryrun: bool) -> None:
         t0 = time.perf_counter()
         __graft_entry__.dryrun_multichip(n_devices, devices=jax.devices("cpu"))
         result["dryrun_wall_s"] = round(time.perf_counter() - t0, 1)
-        t0 = time.perf_counter()
-        result["epilogue_attribution"] = _epilogue_profile()
-        result["epilogue_profile_wall_s"] = round(time.perf_counter() - t0, 1)
 
     kp = KeyPair.generate()
     items = [

@@ -13,7 +13,9 @@ from __future__ import annotations
 import asyncio
 import logging
 
+from .. import tracing
 from ..channels import Channel, Subscriber, Watch
+from ..clock import now
 from ..config import Committee
 from ..stores import CertificateStore, ConsensusStore
 from ..types import Certificate, ConsensusOutput, ReconfigureNotification, Round
@@ -53,6 +55,9 @@ class Consensus:
         # the simnet safety/liveness oracles read the exact commit sequence
         # here without adding a channel (and without racing the executor).
         self.commit_tap = commit_tap
+        tracer = getattr(metrics, "tracer", None)
+        self.node = tracer.node if tracer is not None else ""
+        self._walks = 0
         self.consensus_index = consensus_store.last_consensus_index()
         self.state = ConsensusState.new_from_store(
             Certificate.genesis(committee),
@@ -131,29 +136,39 @@ class Consensus:
                         # scatter + per-event dispatches with readbacks
                         # deferred one event (the fused pipeline), instead
                         # of one full dispatch round trip per certificate.
-                        sequence = await self.protocol.process_batch_async(
-                            self.state, self.consensus_index, batch
-                        )
-                        await self._emit(sequence)
+                        await self._emit(await self._walk(batch))
                     else:
                         for certificate in batch:
-                            await self._process(certificate)
+                            await self._emit(await self._walk([certificate]))
         finally:
             recon_task.cancel()
             cert_task.cancel()
 
-    async def _process(self, certificate: Certificate) -> None:
-        if hasattr(self.protocol, "process_certificate_async"):
-            # Device-backed protocols overlap their device->host readback
-            # with the rest of the node's event loop.
-            sequence = await self.protocol.process_certificate_async(
-                self.state, self.consensus_index, certificate
-            )
-        else:
-            sequence = self.protocol.process_certificate(
-                self.state, self.consensus_index, certificate
-            )
-        await self._emit(sequence)
+    async def _walk(self, certs: list[Certificate]) -> list[ConsensusOutput]:
+        """One call into the ordering engine, with its `walk` record in the
+        process flight ring (node, certificates in, outputs committed,
+        t_start, t_done) and the profiler's mark around it. Both span the
+        call's awaits: wall time of the coroutine, not the loop's alone."""
+        protocol = self.protocol
+        self._walks += 1
+        t_start = now()
+        with tracing.annotation("narwhal/commit_walk", seq=self._walks, certs=len(certs)):
+            if len(certs) > 1:
+                sequence = await protocol.process_batch_async(
+                    self.state, self.consensus_index, certs
+                )
+            elif hasattr(protocol, "process_certificate_async"):
+                # Device-backed protocols overlap their device->host readback
+                # with the rest of the node's event loop.
+                sequence = await protocol.process_certificate_async(
+                    self.state, self.consensus_index, certs[0]
+                )
+            else:
+                sequence = protocol.process_certificate(
+                    self.state, self.consensus_index, certs[0]
+                )
+        tracing.flight("walk", self.node, len(certs), len(sequence), t_start, now())
+        return sequence
 
     async def _emit(self, sequence: list[ConsensusOutput]) -> None:
         if sequence:

@@ -19,6 +19,7 @@ from __future__ import annotations
 import asyncio
 import logging
 
+from .. import tracing
 from ..channels import Channel
 from ..stores import BatchStore
 from ..types import Batch, ConsensusOutput
@@ -82,7 +83,12 @@ class ExecutorCore:
         try:
             while True:
                 output, batches, t_commit = await self.rx_subscriber.recv()
-                await self.execute_certificate(output, batches)
+                # The mark spans the awaits below: the coroutine's wall
+                # time, other tasks' turns on the loop included.
+                with tracing.annotation(
+                    "narwhal/execute", index=output.consensus_index
+                ):
+                    await self.execute_certificate(output, batches)
                 if self.metrics is not None and t_commit is not None:
                     # Span-unified close: one call emits both the execute
                     # stage histogram sample and (when tracing) the span

@@ -20,6 +20,7 @@ import logging
 
 import grpc
 
+from . import tracing
 from .consensus.dag import ValidatorDagError
 from .proto import narwhal_pb2 as pb
 
@@ -238,6 +239,7 @@ class GrpcPublicApi:
             cap = int.from_bytes(request_bytes[:4], "little")
             max_events = cap or None
         dump = self.tracer.dump(max_events)
+        dump["process"] = tracing.flight_dump(max_events or dump["ring_capacity"])
         return json.dumps(dump, sort_keys=True, separators=(",", ":")).encode()
 
     # -- lifecycle ---------------------------------------------------------

@@ -162,6 +162,12 @@ class Registry:
         self._metrics[metric.name] = metric
         return metric
 
+    def mount(self, metric: _Metric) -> _Metric:
+        """Show a metric object this registry did not create: a
+        process-wide series (the shared verify service's, the loop's
+        heartbeat) that every co-hosted node's scrape should carry."""
+        return self._register(metric)
+
     def get(self, name: str) -> _Metric | None:
         return self._metrics.get(name)
 

@@ -184,6 +184,12 @@ class WireStats:
         }
 
 
+def _request_failed(counters: "WireCounters | None", cause: str) -> None:
+    """Count one request of PeerClient/PeerLink that got no answer."""
+    if counters is not None:
+        counters.record_failed(cause)
+
+
 class WireCounters:
     """Per-ROLE wire accounting (one instance per primary/worker network,
     unlike the process-wide WireStats): every frame the role writes or
@@ -208,6 +214,7 @@ class WireCounters:
         "_label_cache",
         "_sent_children",
         "_recv_children",
+        "_failed_m",
     )
 
     def __init__(self, registry=None):
@@ -217,6 +224,7 @@ class WireCounters:
         self.frames_received = 0
         self._sent_bytes_m = self._recv_bytes_m = None
         self._sent_frames_m = self._recv_frames_m = None
+        self._failed_m = None
         self._label_cache: dict[tuple[int, int], tuple[str, str]] = {}
         # Labelled-child cache: (tag, lane) -> (bytes child, frames child).
         # labels() re-stringifies + re-hashes on every call; at N=200 the
@@ -245,6 +253,14 @@ class WireCounters:
                 "Frames read by this role, by message type and lane",
                 labels=("msg_type", "lane"),
             )
+            self._failed_m = registry.counter(
+                "rpc_requests_failed_total",
+                "Requests of this role that did not get their answer "
+                "(cause=timeout: the deadline passed, the link stays; "
+                "cause=link_lost: the transport failed, every request on "
+                "it fails; cause=refused: the peer answered with an error)",
+                labels=("cause",),
+            )
 
     def _labels(self, tag: int, lane: int) -> tuple[str, str]:
         pair = self._label_cache.get((tag, lane))
@@ -256,6 +272,10 @@ class WireCounters:
             pair = (name, str(lane))
             self._label_cache[(tag, lane)] = pair
         return pair
+
+    def record_failed(self, cause: str) -> None:
+        if self._failed_m is not None:
+            self._failed_m.labels(cause).inc()
 
     def record_sent(self, tag: int, wire_len: int, lane: int = 0) -> None:
         self.bytes_sent += wire_len
@@ -680,18 +700,28 @@ class PeerClient:
         try:
             self._sender.send(KIND_REQ, rid, tag, body)
             return await asyncio.wait_for(fut, timeout)
-        except (ConnectionError, OSError) as e:
+        except asyncio.TimeoutError:
+            # Before OSError: since Python 3.11 this IS the builtin
+            # TimeoutError, an OSError. A missed deadline fails this
+            # request alone; the link and its other requests stay.
             # Register/await/cleanup idiom: each task pops only the rid it
             # registered itself — concurrent requests touch disjoint keys.
             self._pending.pop(rid, None)  # lint: allow(await-interleaved-rmw)
+            _request_failed(self._counters, "timeout")
+            raise RpcTimeout(f"request to {self.address} timed out")
+        except (ConnectionError, OSError) as e:
+            self._pending.pop(rid, None)
+            _request_failed(self._counters, "link_lost")
             self._teardown(RpcError(str(e)))
             raise RpcError(f"send to {self.address} failed: {e}") from e
         except RpcError:
             self._pending.pop(rid, None)
+            # The peer's own error answer, or a teardown that failed
+            # every request in flight on this connection.
+            _request_failed(
+                self._counters, "link_lost" if self._sender is None else "refused"
+            )
             raise
-        except asyncio.TimeoutError:
-            self._pending.pop(rid, None)
-            raise RpcTimeout(f"request to {self.address} timed out")
 
     async def oneway(self, msg) -> None:
         """Enqueue a fire-and-forget frame (KIND_ONEWAY): no response, no
@@ -870,18 +900,24 @@ class PeerLink:
         try:
             self._sender.send(KIND_REQ, rid, tag, body, lane)
             return await asyncio.wait_for(fut, timeout)
-        except (ConnectionError, OSError) as e:
+        except asyncio.TimeoutError:
+            # Before OSError (see PeerClient.request): a missed deadline
+            # fails this request and leaves the pooled link, and every
+            # other request in flight on it, alone.
             # Register/await/cleanup idiom: each task pops only the rid it
             # registered itself — concurrent requests touch disjoint keys.
             self._pending.pop(rid, None)  # lint: allow(await-interleaved-rmw)
+            _request_failed(self._counters, "timeout")
+            raise RpcTimeout(f"request to {self.address} (lane {lane}) timed out")
+        except (ConnectionError, OSError) as e:
+            self._pending.pop(rid, None)
+            _request_failed(self._counters, "link_lost")
             self._teardown(RpcError(str(e)))
             raise RpcError(f"send to {self.address} failed: {e}") from e
         except RpcError:
             self._pending.pop(rid, None)
+            _request_failed(self._counters, "link_lost" if self.closed else "refused")
             raise
-        except asyncio.TimeoutError:
-            self._pending.pop(rid, None)
-            raise RpcTimeout(f"request to {self.address} (lane {lane}) timed out")
 
     async def oneway(self, msg, lane: int) -> None:
         """Fire-and-forget frame on `lane` (same caller contract as

@@ -32,6 +32,7 @@ from .primary.block_remover import BlockRemover
 from .primary.block_synchronizer import BlockSynchronizer
 from .primary.block_waiter import BlockWaiter
 from .stores import NodeStorage
+from . import tracing
 from .tracing import Tracer
 from .types import ConsensusOutput, PublicKey
 from .worker import Worker
@@ -104,6 +105,14 @@ class PrimaryNode:
         self.tracer = Tracer(node=f"primary-{self.name.hex()[:8]}")
         # Group-commit instruments (fused-WAL group size / flush latency).
         storage.engine.attach_metrics(self.registry)
+        # Process-wide series this node's scrape carries too: the loop's
+        # heartbeat lateness and the shared verify service's rows and waits
+        # (zero on a backend that runs no such service).
+        from .tpu.verifier import SERVICE_ROWS, SERVICE_WAIT
+
+        for series in (tracing.LOOP_LAG, SERVICE_ROWS, SERVICE_WAIT):
+            self.registry.mount(series)
+        self._heartbeat = False
         # Registered at assembly (not inside the monitor coroutine) so the
         # metrics catalog extractor sees the full surface without spawning.
         self._backpressure_gauge = self.registry.gauge(
@@ -416,6 +425,13 @@ class PrimaryNode:
             if restored:
                 logger.info("Replaying %d consensus outputs after restart", len(restored))
         await self.primary.spawn()
+        from .network import transport as _transport
+
+        if not _transport.simnet_active():
+            # Under simnet's virtual clock a timer is never late, and a
+            # 20 ms one would multiply the events of a seeded scenario.
+            tracing.heartbeat_acquire()
+            self._heartbeat = True
         if self.consensus is not None:
             self._tasks.append(self.consensus.spawn())
         if self.executor is not None:
@@ -434,8 +450,6 @@ class PrimaryNode:
         # other RpcServer, but grpc.aio binds REAL sockets — skipped there,
         # keeping simulated committees at zero sockets (the interop edge is
         # meaningless inside a simulation anyway).
-        from .network import transport as _transport
-
         self.api.set_primary_address(self.primary.address)
         self.api_address = await self.api.spawn("127.0.0.1:0")
         if _transport.simnet_active():
@@ -547,8 +561,6 @@ class PrimaryNode:
             )
             if stall_armed and stale is not None and stale > stall_after:
                 stall_armed = False
-                from . import tracing
-
                 tracing.on_anomaly(
                     f"commit_stall node={self.name.hex()[:8]} "
                     f"stale={stale:.1f}s committed={committed}"
@@ -570,6 +582,9 @@ class PrimaryNode:
         # post-mortem dumps (test hooks, scenario teardown) must survive
         # the tracer's owner being garbage collected.
         self.tracer.archive()
+        if self._heartbeat:
+            self._heartbeat = False
+            tracing.heartbeat_release()
         for t in self._tasks:
             t.cancel()
         await drain_cancelled(self._tasks, who="primary-node")

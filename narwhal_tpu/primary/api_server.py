@@ -25,6 +25,7 @@ from __future__ import annotations
 import json
 import logging
 
+from .. import tracing
 from ..config import Committee
 from ..messages import (
     FlightDumpMsg,
@@ -198,6 +199,7 @@ class ConsensusApi:
                 "Telemetry.DumpFlightRecorder: node mounted no tracer"
             )
         dump = self.tracer.dump(msg.max_events or None)
+        dump["process"] = tracing.flight_dump(msg.max_events or dump["ring_capacity"])
         return FlightDumpResponse(
             json.dumps(dump, sort_keys=True, separators=(",", ":")).encode()
         )

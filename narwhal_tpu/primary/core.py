@@ -13,7 +13,9 @@ from __future__ import annotations
 import asyncio
 import logging
 
+from .. import tracing
 from ..channels import Channel, Subscriber, Watch
+from ..clock import now
 from ..config import Committee, WorkerCache
 from ..crypto import SignatureService
 from ..network import NetworkClient
@@ -289,8 +291,17 @@ class Core:
                 # proposed the header this certificate certifies. The causal
                 # key hops header -> certificate here, so record the link
                 # edge the waterfall joins on.
-                self.metrics.certify_timer.stop(certificate.header.digest)
+                took = self.metrics.certify_timer.stop(certificate.header.digest)
                 tracer = self.metrics.tracer
+                if took is not None:
+                    # The same window for the process flight ring, always
+                    # on: what the `stage` records of this header's hops
+                    # are laid against (certify's verify-stage share).
+                    t1 = now()
+                    tracing.flight(
+                        "certify", certificate.header.digest.hex(),
+                        tracer.node if tracer is not None else "", t1 - took, t1,
+                    )
                 if (
                     tracer is not None
                     and tracer.enabled

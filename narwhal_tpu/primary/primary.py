@@ -299,6 +299,7 @@ class Primary:
                 crypto_pool,
                 self.tx_primary_messages,
                 rx_reconfigure=self.tx_reconfigure,
+                tracer=tracer,
             )
         else:
             self.verifier_stage = None

@@ -9,6 +9,14 @@ arriving messages into fixed-shape device batches), and only successfully
 verified messages are forwarded to the Core wrapped in `PreVerified` so its
 sanitize step skips redundant signature work. The Core state machine stays
 single-threaded; only crypto becomes pipelined + batched.
+
+Every header, vote and certificate that passes through leaves one `stage`
+record in the process flight ring (tracing.flight): (kind, the header digest
+the message is about, node, t_in, t_verdict, t_forwarded, outcome), where
+t_verdict is when the pool's answers were all in (t_in where nothing had to be
+asked) and t_forwarded when `tx_out.send` returned. With NARWHAL_TRACE on the
+same interval is a `verify_stage` span on the node's tracer, keyed by that
+header digest, so `waterfall()` shows it under `certify`.
 """
 
 from __future__ import annotations
@@ -16,7 +24,9 @@ from __future__ import annotations
 import asyncio
 import logging
 
+from .. import tracing
 from ..channels import Channel
+from ..clock import now
 from ..config import Committee, WorkerCache
 from ..types import Certificate, DagError, Header, InvalidEpoch, Vote
 
@@ -41,6 +51,7 @@ class VerifierStage:
         tx_out: Channel,
         rx_reconfigure=None,  # Watch[ReconfigureNotification]: epoch swaps
         max_pending: int = 1_024,
+        tracer=None,  # tracing.Tracer: the node's span sink (and its label)
     ):
         self._committee = committee
         self.worker_cache = worker_cache
@@ -49,6 +60,8 @@ class VerifierStage:
         self.rx_reconfigure = rx_reconfigure
         self._sem = asyncio.Semaphore(max_pending)
         self._tasks: set[asyncio.Task] = set()
+        self.tracer = tracer
+        self.node = tracer.node if tracer is not None else ""
 
     @property
     def committee(self) -> Committee:
@@ -73,16 +86,44 @@ class VerifierStage:
         task.add_done_callback(_done)
 
     async def _verify(self, msg) -> None:
+        if isinstance(msg, Header):
+            kind, key = "header", msg.digest
+        elif isinstance(msg, Vote):
+            kind, key = "vote", msg.header_digest
+        elif isinstance(msg, Certificate):
+            kind, key = "certificate", msg.header.digest
+        else:
+            await self.tx_out.send(msg)
+            return
+        t_in = now()
+        forward, outcome, t_verdict = await self._decide(kind, msg, t_in)
+        if forward is not None:
+            await self.tx_out.send(forward)
+        t_forwarded = now()
+        tracing.flight(
+            "stage", kind, key.hex(), self.node, t_in, t_verdict, t_forwarded, outcome
+        )
+        tracer = self.tracer
+        if tracer is not None and tracer.enabled and tracer.sampled(key):
+            tracer.span("verify_stage", key, t_in, t_forwarded, {"kind": kind})
+            if kind == "certificate":
+                # A validator that is not the author never records the
+                # certify hop: give its own dump the header -> certificate edge.
+                tracer.link("verify_stage", key, msg.digest)
+
+    async def _decide(self, kind: str, msg, t_in: float):
+        """(what to forward or None, outcome, t_verdict) for one header,
+        vote or certificate."""
         agg_group = None
         agg_committee = None
         try:
-            if isinstance(msg, Header):
+            if kind == "header":
                 msg.verify(self.committee, self.worker_cache, check_signature=False)
                 items = [msg.signature_item()]
-            elif isinstance(msg, Vote):
+            elif kind == "vote":
                 msg.verify(self.committee, check_signature=False)
                 items = [msg.signature_item()]
-            elif isinstance(msg, Certificate) and msg.is_compact:
+            elif msg.is_compact:
                 # Half-aggregated proof: one aggregate check for the vote
                 # quorum + the embedded header's own signature. The
                 # content-keyed front cache short-circuits the transcript
@@ -101,7 +142,7 @@ class VerifierStage:
                             "verifier stage dropped compact certificate with "
                             "known-bad aggregate proof"
                         )
-                        return
+                        return None, "known_bad", t_in
                     if not msg.is_genesis():
                         msg.header.verify(
                             agg_committee, self.worker_cache, check_signature=False
@@ -114,16 +155,13 @@ class VerifierStage:
                             agg_committee, self.worker_cache, check_signature=False
                         )
                         items.append(msg.header.signature_item())
-            elif isinstance(msg, Certificate):
+            else:
                 items = msg.verify_items(self.committee)
                 if items:
                     msg.header.verify(
                         self.committee, self.worker_cache, check_signature=False
                     )
                     items.append(msg.header.signature_item())
-            else:
-                await self.tx_out.send(msg)
-                return
         except InvalidEpoch:
             # NOT this stage's call: the Core buffers exactly-one-epoch-ahead
             # messages for replay after its reconfigure notification lands
@@ -131,40 +169,41 @@ class VerifierStage:
             # Forward RAW (un-preverified): the Core re-runs the full
             # sanitize path — including signatures, against whatever
             # committee it holds when the message is finally handled.
-            await self.tx_out.send(msg)
-            return
+            return msg, "other_epoch", t_in
         except DagError as e:
             logger.debug("verifier stage dropped malformed message: %s", e)
-            return
-        if items or agg_group is not None:
-            try:
-                awaitables = [self.pool.verify(pk, m, sig) for pk, m, sig in items]
-                if agg_group is not None:
-                    awaitables.append(self.pool.verify_aggregate(*agg_group))
-                results = await asyncio.gather(*awaitables)
-            except Exception:
-                # Backend dispatch failure with the host fallback disabled
-                # (cofactored committees: a strict-rule fallback would be a
-                # consensus-split hazard). Drop the message — conservative
-                # rejection affects liveness, never safety — and say so.
-                logger.exception(
-                    "verify backend failed; dropping %s (no host fallback "
-                    "under this committee's accept rule)",
-                    type(msg).__name__,
-                )
-                return
+            return None, "malformed", t_in
+        if not items and agg_group is None:
+            return PreVerified(msg), "nothing_to_ask", t_in
+        try:
+            awaitables = [self.pool.verify(pk, m, sig) for pk, m, sig in items]
             if agg_group is not None:
-                # Publish the paid-for MSM verdict under the front key so
-                # every later copy of this certificate — same node's relay
-                # duplicates or a co-hosted peer's — skips the transcript.
-                msg.record_aggregate_verdict(agg_committee, bool(results[-1]))
-            if not all(results):
-                logger.warning(
-                    "verifier stage rejected %s with bad signature",
-                    type(msg).__name__,
-                )
-                return
-        await self.tx_out.send(PreVerified(msg))
+                awaitables.append(self.pool.verify_aggregate(*agg_group))
+            results = await asyncio.gather(*awaitables)
+        except Exception:
+            # Backend dispatch failure with the host fallback disabled
+            # (cofactored committees: a strict-rule fallback would be a
+            # consensus-split hazard). Drop the message — conservative
+            # rejection affects liveness, never safety — and say so.
+            logger.exception(
+                "verify backend failed; dropping %s (no host fallback "
+                "under this committee's accept rule)",
+                type(msg).__name__,
+            )
+            return None, "backend_failed", now()
+        t_verdict = now()
+        if agg_group is not None:
+            # Publish the paid-for MSM verdict under the front key so
+            # every later copy of this certificate — same node's relay
+            # duplicates or a co-hosted peer's — skips the transcript.
+            msg.record_aggregate_verdict(agg_committee, bool(results[-1]))
+        if not all(results):
+            logger.warning(
+                "verifier stage rejected %s with bad signature",
+                type(msg).__name__,
+            )
+            return None, "rejected", t_verdict
+        return PreVerified(msg), "verified", t_verdict
 
     def shutdown(self) -> None:
         for t in list(self._tasks):

@@ -45,6 +45,9 @@ import time
 import zlib
 from typing import Iterable, Iterator
 
+from . import tracing
+from .clock import now
+
 _HDR = struct.Struct("<II")  # payload_len, crc32
 
 
@@ -66,6 +69,7 @@ class StorageStats:
         if ops > cls.max_group_ops:
             cls.max_group_ops = ops
         cls.flush_seconds_total += flush_seconds
+        tracing.flight("wal_flush", ops, flush_seconds, now())
 
     @classmethod
     def snapshot(cls) -> dict:

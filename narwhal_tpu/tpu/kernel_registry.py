@@ -33,6 +33,8 @@ import threading
 import time
 from typing import Any, Callable, Sequence
 
+from .. import tracing
+
 _LOCK = threading.Lock()
 # kernel name -> TrackedKernel (the module-level, unsharded entry point)
 _KERNELS: dict[str, "TrackedKernel"] = {}
@@ -103,6 +105,9 @@ class TrackedKernel:
             # compile + one (async-dispatched) execute. Keep the first
             # observation — a racing second dispatch just hit the cache.
             _WALLS.setdefault(key, wall)
+        # The same, with its instant, in the process flight ring: a
+        # reader counts the first dispatches that fell inside a window.
+        tracing.flight("compile", self.name, key[2], time.monotonic(), wall)
         return out
 
     def lower(self, *args, **kwargs):

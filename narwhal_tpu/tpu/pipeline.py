@@ -36,7 +36,6 @@ import collections
 import logging
 from typing import Iterable, Sequence
 
-from ..clock import now
 from ..types import Certificate, ConsensusOutput
 
 logger = logging.getLogger("narwhal.tpu.pipeline")
@@ -51,28 +50,16 @@ class FusedCertificatePipeline:
     the number of verify batches kept in flight (2 = double-buffered)."""
 
     def __init__(
-        self, verifier, engine, state, start_index: int = 0, depth: int = 2,
-        tracer=None,
+        self, verifier, engine, state, start_index: int = 0, depth: int = 2
     ):
         self.verifier = verifier
         self.engine = engine
         self.state = state
         self.consensus_index = start_index
         self.depth = max(1, depth)
-        self.tracer = tracer
         self._inflight: collections.deque = collections.deque()
         self.outputs: list[ConsensusOutput] = []
         self.rejected: list[Certificate] = []
-
-    def _span_key(self, certs: Sequence[Certificate]):
-        """Device sub-spans are per-batch, keyed by the batch's first
-        certificate digest (the batch has no digest of its own); the n=
-        attribute records how many certificates the span covers."""
-        tracer = self.tracer
-        if tracer is None or not tracer.enabled or not certs:
-            return None
-        key = certs[0].digest
-        return key if tracer.sampled(key) else None
 
     def feed(self, certs: Sequence[Certificate], committee=None) -> None:
         """Pack + dispatch one verify batch; resolves the oldest in-flight
@@ -85,21 +72,13 @@ class FusedCertificatePipeline:
         while len(self._inflight) >= self.depth:
             self._resolve_one()
         committee = committee or self.engine.committee
-        span_key = self._span_key(certs)
-        t_pack = now()
         items: list = []
         groups: list = []
         # Input order preserved: ("item", cert, lo, hi) spans index into the
         # item verdicts, ("group", cert, g) into the group verdicts; g/lo of
         # None marks a signature-free certificate (genesis): valid.
         spans: list[tuple] = []
-        # Staging split (traced batches only — the untraced path pays no
-        # extra clock reads): items_s is the full-format per-vote item
-        # staging, groups_s the compact-format aggregate decompress. The
-        # epilogue attributor (tools/perf/epilogue.py) keys on these.
-        items_s = groups_s = 0.0
         for cert in certs:
-            t_cert = now() if span_key is not None else 0.0
             if cert.is_compact:
                 group = cert.aggregate_group(committee)
                 if group is None:
@@ -107,47 +86,18 @@ class FusedCertificatePipeline:
                 else:
                     spans.append(("group", cert, len(groups)))
                     groups.append(group)
-                if span_key is not None:
-                    groups_s += now() - t_cert
             else:
                 cert_items = cert.verify_items(committee)
                 spans.append(("item", cert, len(items), len(items) + len(cert_items)))
                 items.extend(cert_items)
-                if span_key is not None:
-                    items_s += now() - t_cert
-        t_dispatch = now()
         handle = self.verifier.submit(items)
         ghandle = self.verifier.submit_groups(groups) if groups else None
-        if span_key is not None:
-            n = len(certs)
-            self.tracer.span("device_pack", span_key, t_pack, t_dispatch, {"n": n})
-            # Sub-spans laid out back to back inside device_pack: widths are
-            # the measured per-branch staging time, which is what the
-            # attributor consumes.
-            self.tracer.span(
-                "pack_items", span_key, t_pack, t_pack + items_s,
-                {"n_items": len(items)},
-            )
-            self.tracer.span(
-                "pack_groups", span_key, t_pack + items_s,
-                t_pack + items_s + groups_s, {"n_groups": len(groups)},
-            )
-            self.tracer.span("device_dispatch", span_key, t_dispatch, now(), {"n": n})
-        self._inflight.append((spans, handle, ghandle, span_key))
+        self._inflight.append((spans, handle, ghandle))
 
     def _resolve_one(self) -> None:
-        spans, handle, ghandle, span_key = self._inflight.popleft()
-        t_collect = now()
+        spans, handle, ghandle = self._inflight.popleft()
         ok = self.verifier.collect(handle)
         gok = self.verifier.collect_groups(ghandle) if ghandle is not None else []
-        if span_key is not None:
-            # collect() blocks on the device->host verdict copies: the
-            # mask-readback sub-span of this batch's device-plane timeline.
-            self.tracer.span(
-                "device_mask_readback", span_key, t_collect, now(),
-                {"n": len(spans)},
-            )
-        t_epilogue = now()
         accepted: list[Certificate] = []
         for span in spans:
             if span[0] == "group":
@@ -162,31 +112,12 @@ class FusedCertificatePipeline:
                 accepted.append(cert)
             else:
                 self.rejected.append(cert)
-        t_unpack = now()
         if accepted:
             outs = self.engine.process_batch(
                 self.state, self.consensus_index, accepted
             )
             self.consensus_index += len(outs)
             self.outputs.extend(outs)
-        if span_key is not None:
-            # Host-side epilogue, split so its books balance: unpack
-            # (verdict routing) + commit (process_batch: DAG insert, commit
-            # walk, output bookkeeping) partition [t_epilogue, t_end]
-            # exactly — a stage added outside the two sub-spans shows up as
-            # unattributed drift in tools/perf/epilogue.py.
-            t_end = now()
-            self.tracer.span(
-                "epilogue_unpack", span_key, t_epilogue, t_unpack,
-                {"n": len(spans)},
-            )
-            self.tracer.span(
-                "epilogue_commit", span_key, t_unpack, t_end,
-                {"n_accepted": len(accepted)},
-            )
-            self.tracer.span(
-                "host_epilogue", span_key, t_epilogue, t_end, {"n": len(spans)}
-            )
 
     def drain(self) -> list[ConsensusOutput]:
         """Resolve every in-flight batch and return the full committed

@@ -38,8 +38,10 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import tracing
 from ..config import ConfigError
 from ..crypto import BatchItem
+from ..metrics import Counter, Histogram
 
 logger = logging.getLogger("narwhal.tpu.verifier")
 
@@ -171,6 +173,13 @@ def msm_epilogue_check(
         acc = ref.point_double(acc)
     # Identity ⇔ X ≡ 0 and Y ≡ Z (mod p).
     return acc[0] % ref.P == 0 and (acc[1] - acc[2]) % ref.P == 0
+
+
+# What `submit` and `submit_groups` return: opaque to callers but for
+# `padded`, the rows handed to the device with their padding (0 where no
+# row passed the host prechecks).
+SubmitHandle = collections.namedtuple("SubmitHandle", "ok idx outs packed items padded")
+GroupsHandle = collections.namedtuple("GroupsHandle", "ok candidates outs groups padded")
 
 
 class TpuVerifier:
@@ -365,7 +374,8 @@ class TpuVerifier:
         """Pack + precheck on host and enqueue the device dispatch(es).
         Returns an opaque handle for `collect` — dispatch is asynchronous, so
         several submitted batches stay in flight and the device readback
-        latency overlaps the next batch's host packing and compute.
+        latency overlaps the next batch's host packing and compute. The
+        handle's `padded` is the rows handed to the device, padding included.
 
         The per-item host work (SHA-512 challenge, canonicality checks,
         msm scalars) runs in native/scalar_ops.cpp when available — the
@@ -373,7 +383,7 @@ class TpuVerifier:
         per 32k batch vs ~3 ms native)."""
         n = len(items)
         if n == 0:
-            return (np.zeros(0, bool), np.zeros(0, np.int64), [], None, items)
+            return SubmitHandle(np.zeros(0, bool), np.zeros(0, np.int64), [], None, items, 0)
         ok = np.zeros(n, bool)
         lib = _scalar_lib()
         if lib is not None:
@@ -383,7 +393,7 @@ class TpuVerifier:
 
         idx = np.flatnonzero(precheck)
         if idx.size == 0:
-            return (ok, idx, [], None, items)
+            return SubmitHandle(ok, idx, [], None, items, 0)
 
         # Compact to precheck-passing rows (contiguous for the C fold and
         # the device upload).
@@ -427,7 +437,8 @@ class TpuVerifier:
             for arr in arrays:
                 arr.copy_to_host_async()
             outs.append((kind, lo, hi, pad, out))
-        return (ok, idx, outs, packed, items)
+        padded = sum(hi - lo + pad for _, lo, hi, pad, _ in outs)
+        return SubmitHandle(ok, idx, outs, packed, items, padded)
 
     def _dispatch_items(self, packed, lo, hi, pad):
         """Per-item Straus kernel over one padded bucket (k/s scalar rows
@@ -538,7 +549,8 @@ class TpuVerifier:
         Each signer contributes two kernel rows (A_i with scalar w z k, and
         R_i — fed through the A slot — with scalar w z; the R slot's
         128-bit scalar lane is too narrow for the 256-bit products). Zero
-        R-slot rows are inert. Returns a handle for `collect_groups`."""
+        R-slot rows are inert. Returns a handle for `collect_groups`; its
+        `padded` is the rows handed to the device, padding included."""
         import os as _os
 
         n_groups = len(groups)
@@ -562,11 +574,13 @@ class TpuVerifier:
             chunk = candidates[lo:hi]
             lo = hi
             outs.append((chunk, self._dispatch_group_chunk(chunk, rows)))
-        return (ok, candidates, outs, groups)
+        padded = sum(d[2] for _, d in outs if d is not None)
+        return GroupsHandle(ok, candidates, outs, groups, padded)
 
     def _dispatch_group_chunk(self, chunk, rows):
         """One msm dispatch over the doubled rows of `chunk`'s groups.
-        Returns ((device out), sum_s) like _dispatch_msm."""
+        Returns ((device out), sum_s, bucket): _dispatch_msm's pair and the
+        rows the dispatch was padded to."""
         L = self.kernel.ref.L
         lib = _scalar_lib()
         sum_s = 0
@@ -648,14 +662,14 @@ class TpuVerifier:
         )
         for arr in out:
             arr.copy_to_host_async()
-        return (out, sum_s)
+        return (out, sum_s, bucket)
 
     def _chunk_passes(self, dispatched) -> bool:
         """Force one `_dispatch_group_chunk` result: device validity lanes
         plus the host epilogue identity."""
         if dispatched is None:
             return False
-        (va_dev, vr_dev, valid_dev), sum_s = dispatched
+        (va_dev, vr_dev, valid_dev), sum_s, _ = dispatched
         valid = np.asarray(valid_dev)
         return bool(valid.all()) and msm_epilogue_check(
             np.asarray(va_dev), np.asarray(vr_dev), sum_s, self.kernel
@@ -675,7 +689,7 @@ class TpuVerifier:
         across dispatches isn't supported."""
         from ..types import host_verify_aggregate
 
-        ok, candidates, outs, groups = handle
+        ok, candidates, outs, groups, _ = handle
         for chunk, dispatched in outs:
             if self._chunk_passes(dispatched):
                 for g, *_ in chunk:
@@ -717,7 +731,7 @@ class TpuVerifier:
         offending signatures (rare path: only adversarial/corrupt input);
         strict-kernel rejects are then re-checked against the cofactored
         rule so the msm mode's accept set stays deterministic."""
-        ok, idx, outs, packed, items = handle
+        ok, idx, outs, packed, items, _ = handle
         if idx.size:
             results = np.zeros(idx.size, bool)
             # In msm mode EVERY verdict is the device-computed cofactored
@@ -767,6 +781,27 @@ def data_mesh(shards: int, devices=None):
     return device_mesh(shards, "data", "--verify-shards", devices)
 
 
+# The service's two scrape series. Process-wide like the service: every
+# node mounts them in its registry (`Registry.mount`), so a co-hosted
+# committee's scrapes all show the one service they share.
+SERVICE_ROWS = Counter(
+    "verify_service_rows_total",
+    "Rows the shared verify service handed to the device per lane "
+    "(kind=useful: signatures, and 2 per signer of a certificate proof; "
+    "kind=padded: the buckets dispatched)",
+    ("lane", "kind"),
+)
+SERVICE_WAIT = Histogram(
+    "verify_service_wait_seconds",
+    "Where a verification waits in the shared service (phase=queue: "
+    "enqueued -> its flush sealed, per entry; phase=turnaround: sealed -> "
+    "verdicts posted, per flush; phase=wake: posted -> the waiting "
+    "coroutine resumed, per entry)",
+    ("phase",),
+)
+_LANES = {"s": "singles", "g": "groups"}
+
+
 class VerifyService:
     """Process-wide pipelined verification front for the TPU backend.
 
@@ -790,7 +825,14 @@ class VerifyService:
                   loop.call_soon_threadsafe.
     A bounded in-flight queue applies backpressure when the device falls
     behind. Presents the AsyncVerifierPool interface (`await verify(...)`,
-    `close()`)."""
+    `close()`).
+
+    Flight record (tracing.flight, always on, one per flush; layout in
+    tracing.FLIGHT_FIELDS): `flush`, and `wake` once its waiters have all
+    resumed. One stamp, `t_posted`, closes a flush: `collect` returned and
+    the verdicts went to the waiters' loops, with nothing in between. The
+    same sums feed SERVICE_ROWS and SERVICE_WAIT; the dispatch and the
+    collect each run under a `tracing.annotation` carrying the flush's seq."""
 
     _shared: dict[str, "VerifyService"] = {}
 
@@ -819,6 +861,10 @@ class VerifyService:
         self._wake = threading.Condition(self._lock)
         self._inflight: queue.Queue = queue.Queue(maxsize=inflight)
         self._closed = False
+        self._seq = 0  # flushes sealed; the submit thread's alone
+        # Guards the per-flush wake tallies: waiters of one flush may
+        # resume on different loops (threads).
+        self._wake_lock = threading.Lock()
         self._submit_thread = threading.Thread(
             target=self._submit_loop, daemon=True, name="verify-submit"
         )
@@ -870,33 +916,45 @@ class VerifyService:
         return svc
 
     async def verify(self, public_key: bytes, message: bytes, signature: bytes) -> bool:
-        loop = asyncio.get_running_loop()
-        fut: asyncio.Future = loop.create_future()
-        with self._wake:
-            if self._closed:
-                # The submit thread is gone (or draining): an enqueued
-                # future would never resolve.
-                raise RuntimeError("verify service shut down")
-            self._pending.append(
-                ((public_key, message, signature), loop, fut, time.monotonic())
-            )
-            self._wake.notify()
-        return await fut
+        return await self._enqueue(self._pending, (public_key, message, signature))
 
     async def verify_aggregate(self, items, zs, s_agg: int) -> bool:
         """Half-aggregated certificate proof (compact certs): queued on the
         group lane and checked on device — many groups fuse into one msm
         dispatch under an outer random combination."""
+        return await self._enqueue(self._pending_groups, (items, zs, s_agg))
+
+    async def _enqueue(self, lane: collections.deque, item):
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
+        tally: list = []  # the collect thread puts the flush's wake tally here
         with self._wake:
             if self._closed:
+                # The submit thread is gone (or draining): an enqueued
+                # future would never resolve.
                 raise RuntimeError("verify service shut down")
-            self._pending_groups.append(
-                ((items, zs, s_agg), loop, fut, time.monotonic())
-            )
+            lane.append((item, loop, fut, time.monotonic(), tally))
             self._wake.notify()
-        return await fut
+        try:
+            return await fut
+        finally:
+            if tally:
+                self._woke(tally[0], time.monotonic())
+
+    def _woke(self, tally: list, t: float) -> None:
+        """One waiter of a posted flush resumed. tally = [seq, entries,
+        t_posted, resumed, summed lag, longest lag]; the last to resume
+        writes the flush's `wake` record."""
+        lag = max(0.0, t - tally[2])
+        with self._wake_lock:
+            tally[3] += 1
+            tally[4] += lag
+            if lag > tally[5]:
+                tally[5] = lag
+            done = tally[3] == tally[1]
+        SERVICE_WAIT.labels("wake").observe(lag)
+        if done:
+            tracing.flight("wake", tally[0], tally[3], tally[4], tally[5], tally[2])
 
     def _seal(self) -> list | None:
         """Under the lock: a singles batch worth dispatching, or None."""
@@ -963,52 +1021,75 @@ class VerifyService:
                         )
                     self._inflight.put(None)  # collector shutdown
                     return
+            t_seal = time.monotonic()
             if batch is not None:
-                items = [e[0] for e in batch]
-                try:
-                    handle = self.verifier.submit(items)
-                except Exception as e:
-                    logger.exception("verify submit failed for %d items", len(items))
-                    self.flushes["submit_failed"] += 1
-                    self._resolve_error(batch, e)
-                else:
-                    self.flushes["singles"] += 1
-                    self._inflight.put(("s", handle, batch))
+                self._dispatch("s", batch, len(batch), t_seal)
             if gbatch is not None:
-                groups = [e[0] for e in gbatch]
-                try:
-                    ghandle = self.verifier.submit_groups(groups)
-                except Exception as e:
-                    logger.exception(
-                        "aggregate submit failed for %d groups", len(groups)
-                    )
-                    self.flushes["submit_failed"] += 1
-                    self._resolve_error(gbatch, e)
-                else:
-                    self.flushes["groups"] += 1
-                    self._inflight.put(("g", ghandle, gbatch))
+                rows = sum(2 * len(e[0][0]) for e in gbatch)
+                self._dispatch("g", gbatch, rows, t_seal)
+
+    def _dispatch(self, kind: str, entries: list, useful: int, t_seal: float) -> None:
+        """Submit thread: hand one sealed lane batch to the device and pass
+        it, with what the flush record needs, to the collect thread."""
+        lane = _LANES[kind]
+        self._seq += 1
+        seq = self._seq
+        waits = [t_seal - e[3] for e in entries]
+        queue_wait = SERVICE_WAIT.labels("queue")
+        for w in waits:
+            queue_wait.observe(w)
+        submit = self.verifier.submit_groups if kind == "g" else self.verifier.submit
+        try:
+            with tracing.annotation("narwhal/verify_submit", seq=seq, lane=lane):
+                handle = submit([e[0] for e in entries])
+        except Exception as e:
+            logger.exception("verify submit failed for %d %s entries", len(entries), lane)
+            self.flushes["submit_failed"] += 1
+            t_failed = time.monotonic()
+            self._resolve_error(entries, e)
+            tracing.flight(
+                "flush", seq, lane, len(entries), useful, 0, entries[0][3], sum(waits),
+                t_seal, t_failed, t_failed, f"submit: {e!r}"[:160],
+            )
+            return
+        t_dispatched = time.monotonic()
+        SERVICE_ROWS.labels(lane, "useful").inc(useful)
+        SERVICE_ROWS.labels(lane, "padded").inc(handle.padded)
+        self.flushes[lane] += 1
+        meta = (seq, lane, len(entries), useful, handle.padded, entries[0][3], sum(waits),
+                t_seal, t_dispatched)
+        self._inflight.put((kind, handle, entries, meta))
 
     def _collect_loop(self) -> None:
         while True:
             got = self._inflight.get()
             if got is None:
                 return
-            kind, handle, entries = got
+            kind, handle, entries, meta = got
+            collect = self.verifier.collect_groups if kind == "g" else self.verifier.collect
+            failure = None
             try:
-                if kind == "g":
-                    results = self.verifier.collect_groups(handle)
-                else:
-                    results = self.verifier.collect(handle)
+                with tracing.annotation("narwhal/verify_collect", seq=meta[0], lane=meta[1]):
+                    results = collect(handle)
             except Exception as e:
                 logger.exception("verify collect failed for %d entries", len(entries))
                 self.flushes["collect_failed"] += 1
+                failure = f"collect: {e!r}"[:160]
+                t_posted = time.monotonic()
                 self._resolve_error(entries, e)
-                continue
-            for (item, loop, fut, _), res in zip(entries, results):
-                self._post(loop, fut, res, None)
+            else:
+                # t_posted: the verdicts start on their way to the waiters'
+                # loops. Each waiter finds the flush's wake tally in its entry.
+                t_posted = time.monotonic()
+                tally = [meta[0], len(entries), t_posted, 0, 0.0, 0.0]
+                for (_, loop, fut, _, slot), res in zip(entries, results):
+                    slot.append(tally)
+                    self._post(loop, fut, res, None)
+            SERVICE_WAIT.labels("turnaround").observe(t_posted - meta[7])
+            tracing.flight("flush", *meta, t_posted, failure)
 
     def _resolve_error(self, entries, exc) -> None:
-        for _, loop, fut, _ in entries:
+        for _, loop, fut, _, _ in entries:
             self._post(loop, fut, None, exc)
 
     @staticmethod

@@ -13,8 +13,8 @@ This module is that record, in two bounded pieces:
   header digest → certificate digest), so tracing adds ZERO wire bytes:
   `link` events recorded where the chain hops (batch digests folded into a
   proposed header, a header certified) let `waterfall()` stitch per-stage
-  spans (seal / propose / certify / commit / execute, plus the device-plane
-  sub-spans from tpu/pipeline.py) into one end-to-end timeline per
+  spans (seal / propose / certify / commit / execute, plus verify_stage
+  from primary/verifier_stage.py) into one end-to-end timeline per
   certificate, joining across the dumps of every node that touched it.
   Span timestamps come from `clock.now()` — the running loop's time — so
   under simnet's virtual clock a seeded scenario produces a bit-identical
@@ -30,6 +30,26 @@ This module is that record, in two bounded pieces:
   NARWHAL_FLIGHT_DIR) so commit-stall detectors, simnet oracles and the
   pytest failure hook can attach the evidence to the failure they report.
 
+* **Process flight ring** — one more bounded ring, module-level, for
+  records whose source is no node: the shared verify service (`flush`,
+  `wake`), the verifier stage (`stage`), the commit walk (`walk`), the
+  core (`certify`), the loop heartbeat (`lag`), the kernel registry
+  (`compile`), the WAL (`wal_flush`) and the workers' first submission
+  (`ingest_first`). It records always, like `instant`: every record is
+  per flush, per protocol message, per walk or per stall, never per
+  signature or per frame. `flight(kind, ...)` appends one tuple, laid
+  out as `FLIGHT_FIELDS` says (this module owns the layout; readers go by
+  field name); `flight_dump()` copies it out with the (`time.monotonic()`,
+  `time.time_ns()`) pair taken when the generation started, which lays
+  the records on any wall-clock timeline (a profiler's). Record times are
+  `clock.now()` on a loop and `time.monotonic()` on a thread: one clock
+  outside simnet. `annotation(name, **meta)` is the same sites' mark on
+  the profiler's own clock: a `jax.profiler.TraceAnnotation` when JAX is
+  loaded, nothing otherwise. A mark around an `await` (the commit walk,
+  the executor) is as wide as the coroutine's wall time, other tasks'
+  turns on the loop included: what held the loop meanwhile is read from
+  the `lag` records, not from the mark's width.
+
 Overhead discipline: span recording on the hot path is gated by
 `Tracer.enabled` (NARWHAL_TRACE, default off) — when disabled the only cost
 at an instrumented site is one attribute read and a falsy branch. When
@@ -41,12 +61,17 @@ seeded simnet replay samples identically.
 
 from __future__ import annotations
 
+import asyncio
 import collections
+import contextlib
 import json
 import os
+import sys
+import time
 import weakref
 
 from .clock import now as _now
+from .metrics import Histogram
 
 # Ordered ring of recently archived dumps (nodes that shut down, anomaly
 # snapshots): bounded so a long test session cannot grow without limit.
@@ -67,13 +92,171 @@ _LIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
 _GENERATION: int = 0
 
 
+# The process flight ring. Appends come from the loop thread(s) and from
+# the verify service's submit and collect threads: `deque.append` of one
+# tuple is atomic, and readers copy on read (`flight_dump`), as
+# `Tracer.dump` does. Sized to outlast a traced benchmark run with room to
+# spare: four co-hosted validators write ~380 records a second under the
+# busiest cell's load and ~520 when idle (rounds spin faster), and the
+# harness keeps the committee alive for the minute or two its profiler
+# takes to write a trace before any reader runs (PERF.md section 6, PR 26).
+# Full, it holds about 50 MB.
+FLIGHT_RING = 1 << 18
+FLIGHT: collections.deque = collections.deque(maxlen=FLIGHT_RING)
+# (time.monotonic(), time.time_ns()) at generation start: what lays the
+# ring's monotonic stamps on a wall-clock timeline.
+_FLIGHT_ANCHOR: tuple[float, int] = (time.monotonic(), time.time_ns())
+
+
 def new_generation() -> int:
     """Start a new tracer incarnation; previously constructed tracers
     become invisible to `live_dumps`/`on_anomaly` (their rings stay
-    reachable through direct references and the archive)."""
-    global _GENERATION
+    reachable through direct references and the archive). The process
+    flight ring starts empty: each in-process cluster's window is its own."""
+    global _GENERATION, _FLIGHT_ANCHOR
     _GENERATION += 1
+    FLIGHT.clear()
+    _FLIGHT_ANCHOR = (time.monotonic(), time.time_ns())
     return _GENERATION
+
+
+# The records' layout, owned here: kind -> the fields that follow the kind,
+# in order. A record is a namedtuple whose first field is `kind` (a plain
+# tuple to `deque.append` and to JSON), so the sites write positionally and
+# the readers (chipbench/readers/, tools/perf/flight_profile.py) read by
+# name; a site that passes the wrong number of fields raises where it stands.
+# Times are seconds on `time.monotonic()`.
+FLIGHT_FIELDS = {
+    # tpu/verifier.py VerifyService, one per flush: lane singles|groups;
+    # useful rows = signatures, or 2 per signer of a proof; padded = the
+    # buckets its submit dispatched; t_oldest = the first entry's enqueue;
+    # wait_sum = sum(seal - enqueue) over entries; t_dispatched = submit
+    # returned; t_posted = collect returned and the verdicts went to the
+    # waiters' loops; failure = None or what failed.
+    "flush": "seq lane entries useful padded t_oldest wait_sum t_seal t_dispatched t_posted failure",
+    # the same flush's waiters, once all resumed: posted -> resumed
+    "wake": "seq entries lag_sum lag_max t_posted",
+    # primary/verifier_stage.py, one per header|vote|certificate (msg);
+    # key = the header digest the message is about
+    "stage": "msg key node t_in t_verdict t_forwarded outcome",
+    # primary/core.py at certify_timer.stop: the certify span of header `key`
+    "certify": "key node t0 t1",
+    # consensus/runner.py, one per call into the ordering engine
+    "walk": "node certs outputs t_start t_done",
+    # the loop heartbeat below: a late wake, or a second of quiet ones
+    "lag": "due woke quiet quiet_sum",
+    # tpu/kernel_registry.py, a first dispatch of (kernel, shapes)
+    "compile": "kernel shapes t wall_s",
+    # storage.py StorageStats.record_group, one per fused WAL flush
+    "wal_flush": "ops flush_s t",
+    # worker/worker.py, a worker's first non-empty submission
+    "ingest_first": "node t",
+}
+FLIGHT_RECORD = {
+    kind: collections.namedtuple(kind, "kind " + fields) for kind, fields in FLIGHT_FIELDS.items()
+}
+
+
+def flight(kind: str, *fields) -> None:
+    """Append one record to the process flight ring. Always on: callers
+    stay off the per-signature and per-frame path."""
+    # One ring for the process by design: its writers are whatever has no
+    # node of its own or spans several; `deque.append` of one tuple is atomic.
+    FLIGHT.append(FLIGHT_RECORD[kind](kind, *fields))  # lint: allow(multi-task-mutation)
+
+
+def flight_dump(max_events: int | None = None) -> dict:
+    """Self-contained, JSON-able snapshot of the process flight ring; it
+    outlives `Cluster.shutdown()`, so a reader can take it after the drain."""
+    events = list(FLIGHT)
+    if max_events is not None and max_events > 0:
+        events = events[-max_events:]
+    return {
+        "node": "process",
+        "generation": _GENERATION,
+        "anchor": {"monotonic": _FLIGHT_ANCHOR[0], "time_ns": _FLIGHT_ANCHOR[1]},
+        "ring_capacity": FLIGHT.maxlen,
+        "events": events,
+    }
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **meta):
+    """A mark on the profiler's own clock around a host step
+    (`narwhal/verify_submit`, `narwhal/verify_collect`,
+    `narwhal/commit_walk`, `narwhal/execute`): a level-1 TraceMe, so a
+    `jax.profiler` session with `host_tracer_level >= 1` holds it on the
+    host plane beside the device's `XLA Ops`. Outside a session it costs
+    one atomic read; in a process that never loaded JAX, nothing."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name, **meta)
+
+
+# -- loop heartbeat ----------------------------------------------------------
+
+HEARTBEAT_PERIOD = 0.02
+# One series for the process, mounted in every node's registry
+# (`Registry.mount`): a deployment runs one loop per process, and the
+# co-hosted nodes of a `Cluster` share theirs.
+LOOP_LAG = Histogram(
+    "loop_lag_seconds",
+    "How late the event loop ran a 20 ms heartbeat timer (scheduled wake "
+    "vs actual): what every other callback on this loop waited as well",
+    (),
+    buckets=(0.001, 0.005, 0.01, 0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5),
+)
+_HEARTBEATS: dict = {}  # running loop -> [task, holders]
+
+
+async def _heartbeat(period: float) -> None:
+    """Every wake goes to the histogram. The ring takes a `lag` record
+    (due, woke, quiet wakes since the last record, their summed lateness)
+    for each wake later than one period, and one a second otherwise, so a
+    reader has every late wake exactly and the count of the rest."""
+    loop = asyncio.get_running_loop()
+    observe = LOOP_LAG.labels().observe
+    quiet, quiet_sum = 0, 0.0
+    every = max(1, round(1.0 / period))
+    while True:
+        due = loop.time() + period
+        await asyncio.sleep(period)
+        woke = loop.time()
+        late = woke - due
+        observe(late)
+        if late <= period:
+            quiet, quiet_sum = quiet + 1, quiet_sum + late
+            if quiet < every:
+                continue
+        flight("lag", due, woke, quiet, quiet_sum)
+        quiet, quiet_sum = 0, 0.0
+
+
+def heartbeat_acquire() -> None:
+    """Start the running loop's heartbeat, or join the one it has: one per
+    loop however many nodes share it. Paired with `heartbeat_release`."""
+    loop = asyncio.get_running_loop()
+    for gone in [lp for lp in _HEARTBEATS if lp.is_closed()]:
+        del _HEARTBEATS[gone]  # a loop that closed without its release
+    slot = _HEARTBEATS.get(loop)
+    if slot is None or slot[0].done():
+        slot = _HEARTBEATS[loop] = [asyncio.ensure_future(_heartbeat(HEARTBEAT_PERIOD)), 0]
+    slot[1] += 1
+
+
+def heartbeat_release() -> None:
+    loop = asyncio.get_running_loop()
+    slot = _HEARTBEATS.get(loop)
+    if slot is None:
+        return
+    slot[1] -= 1
+    if slot[1] <= 0:
+        slot[0].cancel()
+        del _HEARTBEATS[loop]
 
 
 def _env_flag(name: str, default: str = "0") -> bool:
@@ -214,8 +397,9 @@ def live_dumps(max_events: int | None = None) -> list[dict]:
 
 
 def all_dumps(max_events: int | None = None) -> list[dict]:
-    """Live rings plus the bounded archive of already-torn-down nodes."""
-    return list(ARCHIVE) + live_dumps(max_events)
+    """Live rings plus the bounded archive of already-torn-down nodes,
+    and the process flight ring."""
+    return list(ARCHIVE) + live_dumps(max_events) + [flight_dump(max_events)]
 
 
 def on_anomaly(reason: str) -> list[dict]:
@@ -248,9 +432,9 @@ def waterfall(dumps: list[dict]) -> dict[str, dict]:
 
     Returns {certificate_digest_hex: {"stages": {stage: [t0, t1]}, ...}}
     where the stages of batches folded into the certificate's header (seal,
-    propose, and the device sub-spans) are re-keyed under the certificate
-    via the recorded link chain. Each stage keeps the earliest-opening span
-    observed for that key across all dumps."""
+    propose, and the header's verify_stage hops) are re-keyed under the
+    certificate via the recorded link chain. Each stage keeps the
+    earliest-opening span observed for that key across all dumps."""
     spans: dict[str, dict[str, tuple[float, float]]] = {}
     parent_of: dict[str, list[str]] = {}  # child key -> parent keys
     for d in dumps:

@@ -20,7 +20,9 @@ import asyncio
 import logging
 import os
 
+from .. import tracing
 from ..channels import Channel, Watch, drain_cancelled, metered_channel
+from ..clock import now
 from ..config import Committee, Parameters, WorkerCache, env_float, pacing_enabled
 from ..messages import (
     BackpressureMsg,
@@ -86,6 +88,7 @@ class Worker:
 
             tracer = Tracer(node=f"worker-{name.hex()[:8]}-{worker_id}")
         self.tracer = tracer
+        self._ingest_seen = False  # the `ingest_first` flight record is out
         self.metrics = WorkerMetrics(self.registry, tracer=tracer)
         self.benchmark = benchmark
 
@@ -422,6 +425,11 @@ class Worker:
         count = msg.count
         if count == 0:
             return None  # empty submission: no-op, never an empty batch
+        if not self._ingest_seen:
+            # Once per worker: where a reader of the process flight ring
+            # finds the instant the clients' load began.
+            self._ingest_seen = True
+            tracing.flight("ingest_first", self.tracer.node, now())
         await self.ingest_gate.admit()
         frames = msg.frames
         validate_tx_frames(frames, count)

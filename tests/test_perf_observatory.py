@@ -1,5 +1,5 @@
 """Perf observatory gates: calibration probe, commit-keyed ledger schema,
-A/B verdict logic, epilogue attribution, simnet profiler attribution,
+A/B verdict logic, simnet profiler attribution,
 waterfall edge cases, and the TELEMETRY_ADDR boot-line contract.
 
 The ledger schema tests here ARE the tier-1 gate the ledger docstring
@@ -15,7 +15,7 @@ import pytest
 from benchmark import ab
 from benchmark.local import parse_telemetry_addr
 from narwhal_tpu import tracing
-from tools.perf import calibrate, epilogue, ledger, simnet_profile
+from tools.perf import calibrate, ledger, simnet_profile
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -239,124 +239,6 @@ def test_decide_noise_band_swallows_small_delta():
     # Same-side spread of 20% must swallow a 10% head/base delta.
     v = ab.decide([100.0, 120.0], [110.0, 132.0], _QUIET)
     assert v["verdict"] == "null"
-
-
-# ------------------------------------------------- epilogue attribution
-
-
-def test_epilogue_attribute_books_balance_synthetic():
-    dumps = [{
-        "events": [
-            ("span", "device_pack", "aa", 0.0, 0.1, {"n": 8}),
-            ("span", "pack_items", "aa", 0.0, 0.06, {"n_items": 24}),
-            ("span", "pack_groups", "aa", 0.06, 0.1, {"n_groups": 2}),
-            ("span", "device_dispatch", "aa", 0.1, 0.12, {"n": 8}),
-            ("span", "device_mask_readback", "aa", 0.5, 0.7, {"n": 8}),
-            ("span", "host_epilogue", "aa", 0.7, 1.7, {"n": 8}),
-            ("span", "epilogue_unpack", "aa", 0.7, 0.9, {"n": 8}),
-            ("span", "epilogue_commit", "aa", 0.9, 1.7, {"n_accepted": 8}),
-            ("span", "seal", "aa", 0.0, 1.0, None),  # non-device: ignored
-        ]
-    }]
-    report = epilogue.attribute(dumps)
-    assert report["totals"]["batches"] == 1
-    row = report["batches"][0]
-    assert row["n"] == 8
-    assert row["epilogue_rel_err"] == pytest.approx(0.0, abs=1e-6)
-    assert row["epilogue_parts_s"] == pytest.approx(1.0)
-    assert report["totals"]["epilogue_rel_err"] <= 0.10
-    # epilogue dominates this synthetic timeline: 1.0 of 1.32 total
-    assert report["totals"]["epilogue_share_of_batch"] == pytest.approx(
-        1.0 / 1.32, abs=0.01
-    )
-    table = epilogue.render_table(report)
-    assert "books balance" in table and "aa" in table
-
-
-def test_epilogue_attribute_reports_unattributed_drift():
-    """A stage added inside host_epilogue WITHOUT a sub-span must surface
-    as unattributed time / rel err, not vanish."""
-    dumps = [{
-        "events": [
-            ("span", "host_epilogue", "bb", 0.0, 1.0, {"n": 4}),
-            ("span", "epilogue_unpack", "bb", 0.0, 0.2, {"n": 4}),
-            ("span", "epilogue_commit", "bb", 0.2, 0.6, {"n_accepted": 4}),
-        ]
-    }]
-    row = epilogue.attribute(dumps)["batches"][0]
-    assert row["epilogue_unattributed_s"] == pytest.approx(0.4)
-    assert row["epilogue_rel_err"] == pytest.approx(0.4)
-
-
-class _StubCert:
-    is_compact = False
-
-    def __init__(self, tag: int):
-        self.digest = bytes([tag]) * 32
-
-    def verify_items(self, committee):
-        return [(self.digest, b"sig", b"pk")] * 3
-
-
-class _StubVerifier:
-    def submit(self, items):
-        return list(items)
-
-    def collect(self, handle):
-        return [True] * len(handle)
-
-    def submit_groups(self, groups):
-        return list(groups)
-
-    def collect_groups(self, handle):
-        return [True] * len(handle)
-
-
-class _StubEngine:
-    committee = None
-
-    def process_batch(self, state, index, accepted):
-        return [("out", c.digest) for c in accepted]
-
-
-def test_pipeline_emits_partitioned_sub_spans():
-    """Drive the REAL FusedCertificatePipeline (stub device + engine) and
-    assert the new pack/epilogue sub-spans partition their parents — the
-    within-10% acceptance property, by construction."""
-    from narwhal_tpu.tpu.pipeline import FusedCertificatePipeline
-
-    tracer = tracing.Tracer(node="test", enabled=True, sample=1.0, ring=256)
-    pipe = FusedCertificatePipeline(
-        _StubVerifier(), _StubEngine(), state=None, depth=1, tracer=tracer
-    )
-    pipe.feed([_StubCert(1), _StubCert(2)], committee=object())
-    pipe.feed([_StubCert(3)], committee=object())  # forces resolve of batch 1
-    outs = pipe.drain()
-    assert len(outs) == 3 and not pipe.rejected
-
-    report = epilogue.attribute([tracer.dump()])
-    assert report["totals"]["batches"] == 2
-    for row in report["batches"]:
-        for stage in (
-            "device_pack", "pack_items", "pack_groups", "device_dispatch",
-            "device_mask_readback", "host_epilogue",
-            "epilogue_unpack", "epilogue_commit",
-        ):
-            assert stage in row, f"missing sub-span {stage}"
-        # The books balance far inside the 10% acceptance gate: the two
-        # epilogue sub-spans partition [t_epilogue, t_end] exactly.
-        assert row["epilogue_rel_err"] <= 0.10
-        assert row["pack_items"] + row["pack_groups"] <= row["device_pack"] + 1e-9
-    assert report["totals"]["epilogue_rel_err"] <= 0.10
-
-
-def test_epilogue_stages_registered_in_catalog():
-    """Every device-plane span stage the attributor consumes must be a
-    registered `span:<stage>` row in the metrics catalog."""
-    catalog = json.loads((REPO / "tools" / "metrics_catalog.json").read_text())
-    names = {row["name"] for row in catalog}
-    for stage in epilogue.STAGES:
-        assert f"span:{stage}" in names, f"span:{stage} not in catalog"
 
 
 # ------------------------------------------------------ simnet profiler

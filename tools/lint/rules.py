@@ -1150,8 +1150,9 @@ class MetricNaming(Rule):
     # "perf" is the observatory's namespace (tools/perf, benchmark.ab):
     # perf_* metrics describe the MEASUREMENT plane (calibration capacity,
     # leg timings), never protocol behaviour.
+    # "rpc" is the request layer above the wire (rpc_requests_failed_total).
     _SUBSYSTEMS = frozenset(
-        {"consensus", "executor", "node", "perf", "primary", "storage",
+        {"consensus", "executor", "node", "perf", "primary", "rpc", "storage",
          "telemetry", "wire", "worker"}
     )
     # Histogram units in use; 'size'/'certificate' are count-like units

@@ -24,9 +24,8 @@ CATALOG_PATH = os.path.join(os.path.dirname(__file__), "metrics_catalog.json")
 
 # Span stages are catalog rows too: the flight-recorder stage vocabulary
 # is a scrape-surface contract exactly like metric names — waterfall
-# stitching, the stage-percentile tables, and the perf attributors
-# (tools/perf/epilogue.py) all key on these strings, so a renamed or
-# drive-by stage must show up in review as catalog drift. Rows are
+# stitching and the stage-percentile tables key on these strings, so a
+# renamed or drive-by stage must show up in review as catalog drift. Rows are
 # `span:<stage>` with type "span_stage"; `roles` names the recording
 # component.
 SPAN_STAGES: tuple[tuple[str, str, str], ...] = (
@@ -35,19 +34,8 @@ SPAN_STAGES: tuple[tuple[str, str, str], ...] = (
     ("certify", "primary", "votes aggregated into a certificate"),
     ("commit", "consensus", "certificate committed by the commit rule"),
     ("execute", "executor", "committed payload applied to execution state"),
-    ("device_pack", "device", "host staging of one verify batch "
-     "(verify_items / aggregate_group)"),
-    ("pack_items", "device", "device_pack sub-span: full-format per-vote "
-     "signature item staging"),
-    ("pack_groups", "device", "device_pack sub-span: compact-format "
-     "aggregate-group decompress staging"),
-    ("device_dispatch", "device", "async submit of the verify kernels"),
-    ("device_mask_readback", "device", "blocking device->host verdict copies"),
-    ("host_epilogue", "device", "post-readback host work for one batch"),
-    ("epilogue_unpack", "device", "host_epilogue sub-span: verdict unpack "
-     "+ accept/reject routing"),
-    ("epilogue_commit", "device", "host_epilogue sub-span: process_batch "
-     "DAG insert + commit walk + output bookkeeping"),
+    ("verify_stage", "primary", "a header, vote or certificate inside the "
+     "verifier stage: taken in -> verdicts in -> forwarded to the core"),
 )
 
 

@@ -13,10 +13,7 @@ into shared, tested tooling:
                   entry point under benchmark/, gated in tier-1;
 - simnet_profile: per-component self-time attribution over a simnet
                   scenario's virtual-clock hot path (ROADMAP item 3's
-                  10x target, named);
-- epilogue:       per-batch attribution of the device pipeline's
-                  host_epilogue span from the tpu/pipeline.py sub-span
-                  stream (ROADMAP item 5's denominator).
+                  10x target, named).
 
 The A/B driver itself lives in benchmark/ab.py and composes these.
 """

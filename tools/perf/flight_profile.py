@@ -1,0 +1,286 @@
+"""The process flight ring laid on a profiler trace taken by hand.
+
+    python3 -m tools.perf.flight_profile --xplane <dir or .xplane.pb> --flight <dump.json>
+    python3 -m tools.perf.flight_profile --drive 8        # on the chip, through chipbench's set-up
+    python3 -m tools.perf.flight_profile --micro          # what one record, add or mark costs here
+
+What reads the program's four profiler marks (`narwhal/verify_submit`,
+`narwhal/verify_collect`, `narwhal/commit_walk`, `narwhal/execute`:
+tracing.annotation) and the fields of the ring no benchmark metric reads
+(`stage.msg`, `t_verdict`, `outcome`; `wake.lag_max`). Given a
+`jax.profiler` trace with `host_tracer_level >= 1` and the ring's dump
+(`tracing.flight_dump()`, or the `"process"` entry of
+`Telemetry.DumpFlightRecorder`) of the same seconds, it reports:
+
+* the clock: the offset that lays the ring's `time.monotonic()` stamps on the
+  profiler's clock, taken where both stamped one instant (a flush's
+  `t_dispatched` is the end of its `narwhal/verify_submit` mark, matched by
+  `seq`), and how far that is from the ring's (`monotonic`, `time_ns`) anchor;
+* for each flush in the trace, where its device program starts against
+  `t_dispatched`;
+* the longest idle gaps of the device, each put down to `starved` (both
+  verify lanes empty, nothing in flight), `held` (an entry queued or being
+  packed) or `in flight`, with the marks and the late heartbeats over it;
+* the verifier stage's hops by message kind and outcome.
+
+A mark around an `await` (`commit_walk`, `execute`) is as wide as its
+coroutine's wall time, other tasks' turns included; what held the loop is the
+`lag` records' to say. `--drive` needs a device and is the only part that
+imports `chipbench`; the rest is arithmetic on what it is given.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import os
+import statistics
+import sys
+import time
+
+_T_PROC = time.monotonic()
+
+from narwhal_tpu import tracing  # noqa: E402
+
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+KERNEL = "msm_accumulate_kernel"
+MARK_PREFIX = "narwhal/"
+
+# marks: name -> [(start_ns, end_ns, {stat: value})]; programs: [(start_ns,
+# end_ns, kernel)] sorted; busy: disjoint device-busy intervals, sorted.
+Profile = collections.namedtuple("Profile", "marks programs busy")
+
+
+def typed(events) -> dict[str, list]:
+    """The dump's events by kind, each a `tracing.FLIGHT_RECORD` (a dump
+    that went through JSON holds plain rows)."""
+    by: dict[str, list] = collections.defaultdict(list)
+    for row in events:
+        by[row[0]].append(tracing.FLIGHT_RECORD[row[0]](*row))
+    return by
+
+
+def union(intervals) -> list[tuple[float, float]]:
+    out: list[tuple[float, float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], b))
+        else:
+            out.append((a, b))
+    return out
+
+
+def overlap(disjoint, a: float, b: float) -> float:
+    return sum(max(0.0, min(y, b) - max(x, a)) for x, y in disjoint)
+
+
+def read_planes(planes, platform: str = "TPU") -> Profile:
+    """`planes` as `jax.profiler.ProfileData.planes` gives them."""
+    marks: dict[str, list] = collections.defaultdict(list)
+    programs, ops = [], []
+    for plane in planes:
+        device = plane.name.startswith(f"/device:{platform}:")
+        for line in plane.lines:
+            if device and line.name not in (OPS_LINE, MODULES_LINE):
+                continue
+            for ev in line.events:
+                start, end = int(ev.start_ns), int(ev.start_ns + ev.duration_ns)
+                if not device:
+                    if ev.name.startswith(MARK_PREFIX):
+                        marks[ev.name].append((start, end, dict(ev.stats)))
+                elif line.name == MODULES_LINE:
+                    name = ev.name.split("(")[0]
+                    programs.append((start, end, name[4:] if name.startswith("jit_") else name))
+                else:
+                    ops.append((start, end))
+    programs.sort()
+    return Profile(dict(marks), programs, union(ops or [(s, e) for s, e, _ in programs]))
+
+
+def read_xplane(path: str, platform: str = "TPU") -> Profile:
+    from jax.profiler import ProfileData
+
+    if os.path.isdir(path):
+        found = sorted(
+            os.path.join(root, f) for root, _, files in os.walk(path) for f in files if f.endswith(".xplane.pb"))
+        if not found:
+            raise FileNotFoundError(f"no .xplane.pb under {path}")
+        path = found[-1]
+    return read_planes(ProfileData.from_file(path).planes, platform)
+
+
+def clock_offset_ns(flushes, submit_marks) -> tuple[float, int, float] | None:
+    """(offset, flushes matched, widest residual): profiler ns = ring
+    seconds x 1e9 + offset. A flush's `t_dispatched` and the end of the
+    submit mark with its `seq` are one instant on two clocks."""
+    ends = {int(stats["seq"]): end for _, end, stats in submit_marks if "seq" in stats}
+    deltas = [ends[f.seq] - f.t_dispatched * 1e9 for f in flushes if f.seq in ends and f.failure is None]
+    if not deltas:
+        return None
+    offset = statistics.median(deltas)
+    return offset, len(deltas), max(abs(d - offset) for d in deltas)
+
+
+def kernel_leads(flushes, programs, to_ns, kernel: str = KERNEL) -> list[dict]:
+    """For each flush dispatched inside the trace, its program (the nearest
+    start among the next three of `kernel`, in order) against `t_dispatched`."""
+    starts = [(s, e) for s, e, name in programs if name == kernel]
+    if not starts:
+        return []
+    lo, hi = starts[0][0], starts[-1][1]
+    out, nxt = [], 0
+    for f in sorted(flushes, key=lambda f: f.t_dispatched):
+        td = to_ns(f.t_dispatched)
+        if not lo - 20e6 <= td <= hi or nxt >= len(starts) or f.failure is not None:
+            continue
+        j = min(range(nxt, min(nxt + 3, len(starts))), key=lambda k: abs(starts[k][0] - td))
+        nxt = j + 1
+        out.append({
+            "seq": f.seq, "lane": f.lane, "rows": [f.useful, f.padded],
+            "kernel_start_after_dispatched_ms": (starts[j][0] - td) / 1e6,
+            "seal_to_dispatched_ms": 1e3 * (f.t_dispatched - f.t_seal),
+            "dispatched_to_posted_ms": 1e3 * (f.t_posted - f.t_dispatched),
+            "kernel_ms": (starts[j][1] - starts[j][0]) / 1e6,
+        })
+    return out
+
+
+def attribute_gaps(profile: Profile, flushes, lags, to_ns, top: int = 10) -> list[dict]:
+    """The `top` longest idle gaps of the device, each split into starved,
+    held and in flight (per cent of the gap; chipbench/readers/flight_window
+    `verify_shares` makes the same split of a whole window), with the
+    milliseconds of each mark over it and the late heartbeats that woke in
+    it or within 50 ms after."""
+    busy = profile.busy
+    gaps = sorted(((b - a, a, b) for (_, a), (b, _) in zip(busy, busy[1:])), reverse=True)[:top]
+    flying = union((to_ns(f.t_dispatched), to_ns(f.t_posted)) for f in flushes)
+    either = union(flying + [(to_ns(f.t_oldest), to_ns(f.t_dispatched)) for f in flushes])
+    late = [(to_ns(r.woke), 1e3 * (r.woke - r.due)) for r in lags if r.woke - r.due > tracing.HEARTBEAT_PERIOD]
+    out = []
+    for dur, a, b in gaps:
+        fly, queued_or_fly = overlap(flying, a, b), overlap(either, a, b)
+        over = {name[len(MARK_PREFIX):]: overlap(union((s, e) for s, e, _ in evs), a, b) / 1e6
+                for name, evs in profile.marks.items()}
+        out.append({
+            "gap_ms": dur / 1e6,
+            "starved": 100.0 * (dur - queued_or_fly) / dur,
+            "held": 100.0 * (queued_or_fly - fly) / dur,
+            "in_flight": 100.0 * fly / dur,
+            "marks_ms": {k: v for k, v in over.items() if v > 0},
+            "late_heartbeats_ms": [ms for at, ms in late if a <= at <= b + 50e6],
+        })
+    return out
+
+
+def hops(stages, wakes) -> dict:
+    """The verifier stage by message kind: messages, outcomes, mean in ->
+    verdict and verdict -> forwarded (ms); and the longest posted -> resumed
+    of any flush (ms)."""
+    table = {}
+    for msg in sorted({s.msg for s in stages}):
+        rows = [s for s in stages if s.msg == msg]
+        table[msg] = {
+            "messages": len(rows),
+            "outcomes": dict(collections.Counter(s.outcome for s in rows)),
+            "in_to_verdict_ms": 1e3 * statistics.fmean(s.t_verdict - s.t_in for s in rows),
+            "verdict_to_forwarded_ms": 1e3 * statistics.fmean(s.t_forwarded - s.t_verdict for s in rows),
+        }
+    return {"stage": table, "longest_wake_ms": 1e3 * max((w.lag_max for w in wakes), default=0.0)}
+
+
+def report(dump: dict, profile: Profile) -> dict:
+    by = typed(dump["events"])
+    anchor = dump["anchor"]
+    anchored = anchor["time_ns"] - anchor["monotonic"] * 1e9  # the offset if the profiler stamped time_ns
+    found = clock_offset_ns(by["flush"], profile.marks.get(MARK_PREFIX + "verify_submit", ()))
+    offset = found[0] if found else anchored
+
+    def to_ns(t: float) -> float:
+        return t * 1e9 + offset
+
+    leads = kernel_leads(by["flush"], profile.programs, to_ns)
+    return {
+        "records": {k: len(v) for k, v in by.items()},
+        "marks": {k: len(v) for k, v in profile.marks.items()},
+        "programs": dict(collections.Counter(name for _, _, name in profile.programs)),
+        "clock": {
+            "offset_ns": offset,
+            "from": "verify_submit marks" if found else "the ring's anchor (no mark matched a flush)",
+            "flushes_matched": found[1] if found else 0,
+            "widest_residual_us": found[2] / 1e3 if found else None,
+            "profiler_minus_time_ns_s": (offset - anchored) / 1e9,
+        },
+        "kernel_start_after_dispatched_ms": leads,
+        "device_busy_ms": sum(b - a for a, b in profile.busy) / 1e6,
+        "slice_ms": (profile.busy[-1][1] - profile.busy[0][0]) / 1e6 if profile.busy else 0.0,
+        "gaps": attribute_gaps(profile, by["flush"], by["lag"], to_ns),
+        "hops": hops(by["stage"], by["wake"]),
+    }
+
+
+def drive(seconds: float, slice_s: float) -> tuple[dict, str]:
+    """A few seconds of `local-4x1.cruise` through chipbench's own set-up,
+    with a profiler slice of `slice_s` that is kept: (the ring's dump, the
+    trace directory). The caller owns the directory."""
+    from chipbench import __main__ as entry
+    from chipbench import run as runner
+
+    args = entry.parse(["--workload", "local-4x1.cruise", "--seed", str(2**31 + 2601),
+                        "--seconds", str(seconds), "--trace", "1"])
+    runner.TRACE_SLICE_S = slice_s
+    ctx = runner.prepare(args, _T_PROC)
+    rec = runner.measure(ctx, args, runner.cell_rate(ctx, args))
+    print(json.dumps({"drive": {"correct": rec["correct"], "failed": rec["failed"],
+                                "attempted": rec["attempted"], "device": ctx.device}}), flush=True)
+    return tracing.flight_dump(), rec["obs"]["trace_dir"]
+
+
+def micro(n: int = 200_000) -> dict:
+    """Microseconds per record, histogram add and mark (outside a profiler
+    session) on this host: what the always-on recorder costs a site."""
+    import timeit
+
+    observe = tracing.LOOP_LAG.labels().observe
+    keep = list(tracing.FLIGHT)
+    timed = {
+        "flight_flush_us": lambda: tracing.flight("flush", 1, "singles", 4, 4, 2048, 1.0, 0.01, 1.0, 1.0, 1.0, None),
+        "flight_stage_us": lambda: tracing.flight("stage", "vote", "aa" * 32, "primary-x", 1.0, 1.0, 1.0, "verified"),
+        "flight_lag_us": lambda: tracing.flight("lag", 1.0, 1.0, 50, 0.01),
+        "histogram_observe_us": lambda: observe(0.0004),
+        "mark_outside_session_us": lambda: tracing.annotation("narwhal/verify_submit", seq=1, lane="singles").__enter__(),
+    }
+    out = {name: 1e6 * timeit.timeit(fn, number=n) / n for name, fn in timed.items()}
+    tracing.FLIGHT.clear()
+    tracing.FLIGHT.extend(keep)
+    return out
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(prog="python3 -m tools.perf.flight_profile")
+    ap.add_argument("--xplane", help="a trace directory or an .xplane.pb")
+    ap.add_argument("--flight", help="tracing.flight_dump() of the same seconds, as JSON")
+    ap.add_argument("--drive", type=float, metavar="SECONDS", help="take both on this machine's device")
+    ap.add_argument("--slice", type=float, default=1.0, help="seconds of profile kept by --drive")
+    ap.add_argument("--platform", default="TPU")
+    ap.add_argument("--micro", action="store_true", help="time the recorder's own calls on this host")
+    args = ap.parse_args(argv)
+    if args.micro:
+        print(json.dumps({"micro": micro()}), flush=True)
+        if not (args.drive or args.xplane):
+            return 0
+    if args.drive:
+        dump, xplane = drive(args.drive, args.slice)
+    elif args.xplane and args.flight:
+        with open(args.flight) as f:
+            dump, xplane = json.load(f), args.xplane
+    else:
+        ap.error("give --xplane and --flight, or --drive")
+    print(json.dumps(report(dump, read_xplane(xplane, args.platform)), indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    code = main()
+    sys.stdout.flush()
+    os._exit(code)  # --drive leaves the committee's threads behind

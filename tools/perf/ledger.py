@@ -48,7 +48,6 @@ KINDS = frozenset(
         "multichip",
         "ab",
         "simnet_profile",
-        "epilogue_profile",
         "fuzz",
     }
 )
