@@ -118,9 +118,15 @@ def load() -> ctypes.CDLL | None:
 def load_scalar() -> ctypes.CDLL | None:
     """The ed25519 host scalar pipeline (native/scalar_ops.cpp), built on
     demand; None when the toolchain is unavailable or NARWHAL_NATIVE=0.
-    ctypes releases the GIL for the call duration, so batched hashing and
-    mod-L arithmetic genuinely overlap device compute in the verify
-    pipeline."""
+    Entry points: `ed25519_precheck_k` (canonicality + challenge scalars),
+    `scalar_fold` and `scalar_mulmod` (the msm lanes' scalars mod L),
+    `msm_epilogue_native` (the host half of a dispatch's batch check: the
+    Horner walk over the device's window sums and the identity test), and
+    the self-test hooks `sha512_test`, `reduce_mod_l_test`, `fe_test`,
+    `pt_test`, `fe_loose13_test`. ctypes releases the GIL for the call's
+    duration: none of them needs the interpreter, so whichever thread calls
+    one leaves the event loop running. The handle is cached on first load:
+    a later NARWHAL_NATIVE=0 in the environment does not take it back."""
     global _scalar, _scalar_tried
     if _scalar_tried:
         return _scalar
@@ -161,6 +167,19 @@ def load_scalar() -> ctypes.CDLL | None:
         ctypes.c_void_p,  # b rows (32B)
         ctypes.c_void_p,  # out rows (32B)
     ]
+    lib.msm_epilogue_native.restype = ctypes.c_int
+    lib.msm_epilogue_native.argtypes = [
+        ctypes.c_void_p,  # V_a int32[4, 20, 64]
+        ctypes.c_void_p,  # V_r int32[4, 20, wr]
+        ctypes.c_int64,  # wr
+        ctypes.c_char_p,  # sum_s, 32 bytes LE
+    ]
+    lib.fe_test.restype = None
+    lib.fe_test.argtypes = [ctypes.c_int64, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p]
+    lib.pt_test.restype = None
+    lib.pt_test.argtypes = [ctypes.c_int64, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p]
+    lib.fe_loose13_test.restype = None
+    lib.fe_loose13_test.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
     _scalar = lib
     return _scalar
 

@@ -106,11 +106,11 @@ class PrimaryNode:
         # Group-commit instruments (fused-WAL group size / flush latency).
         storage.engine.attach_metrics(self.registry)
         # Process-wide series this node's scrape carries too: the loop's
-        # heartbeat lateness and the shared verify service's rows and waits
-        # (zero on a backend that runs no such service).
-        from .tpu.verifier import SERVICE_ROWS, SERVICE_WAIT
+        # heartbeat lateness and the shared verify service's rows, waits and
+        # events (zero on a backend that runs no such service).
+        from .tpu.verifier import SERVICE_EVENTS, SERVICE_ROWS, SERVICE_WAIT
 
-        for series in (tracing.LOOP_LAG, SERVICE_ROWS, SERVICE_WAIT):
+        for series in (tracing.LOOP_LAG, SERVICE_ROWS, SERVICE_WAIT, SERVICE_EVENTS):
             self.registry.mount(series)
         self._heartbeat = False
         # Registered at assembly (not inside the monitor coroutine) so the

@@ -20,9 +20,21 @@ whose solo device check fails is walked on the host) and each is counted
 in `TpuVerifier.counts`, so a run on all-valid input can assert that none
 fired — a kernel that miscompiled would otherwise be "corrected" quietly.
 
-An async coalescing front (`AsyncVerifierPool`) batches concurrent requests
-with a size-or-deadline window, the BatchMaker pattern applied to crypto
-(SURVEY §7 "hard parts": offload must be batched or it adds latency).
+What runs where. `submit` / `submit_groups` (numpy packing, the native
+precheck and fold with the GIL released, the jit dispatch) run on whatever
+thread calls them; under `VerifyService` that is the event loop that sealed
+the flush, which holds the interpreter anyway, so a flush wins it from
+nobody. `collect` / `collect_groups` block on the device and then run the
+host epilogue, `msm_epilogue_check` — with the native library the walk is
+`msm_epilogue_native` in native/scalar_ops.cpp and needs no interpreter;
+without a toolchain, and in the tests as the oracle, it runs on Python
+integers — on the service's one `verify-collect` thread.
+`counts["epilogue_native"]` / `["epilogue_python"]` say which ran.
+
+Two async fronts batch concurrent requests with a size-or-deadline window,
+the BatchMaker pattern applied to crypto (SURVEY §7 "hard parts": offload
+must be batched or it adds latency): `VerifyService` (one per process, the
+device's) and `AsyncVerifierPool` (per node, host backends in an executor).
 """
 
 from __future__ import annotations
@@ -30,6 +42,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import hashlib
+import itertools
 import logging
 import queue
 import threading
@@ -128,7 +141,7 @@ def _sharded_kernels(kernel, mesh, data_axis: str):
 
 
 def msm_epilogue_check(
-    va_limbs: np.ndarray, vr_limbs: np.ndarray, sum_s: int, kernel
+    va_limbs: np.ndarray, vr_limbs: np.ndarray, sum_s: int, kernel, lib=None
 ) -> bool:
     """Host half of the batch check: Horner-collapse the device's
     per-window point sums and test
@@ -137,9 +150,14 @@ def msm_epilogue_check(
     va_limbs: int32[4, NLIMB, 64] and vr_limbs: int32[4, NLIMB, 32] loose
     X/Y/Z/T limbs from msm_accumulate_kernel (MSB-first window lanes; the
     R accumulator covers only the low 32 windows because z_i < 2^128).
-    ~450 bigint point ops (~2 ms), amortized over the whole batch; the
-    device equivalent would be sub-tile sequential work costing hundreds
-    of ms.
+    The device equivalent would be sub-tile sequential work costing
+    hundreds of ms.
+
+    `lib`: the native scalar library (`native.load_scalar()`), which walks
+    it in `msm_epilogue_native` with the GIL released — what the served
+    path passes. Without it the walk runs here on Python integers, ~750
+    bigint point operations under the GIL: the no-toolchain twin, and the
+    tests' oracle for the native one. Same mathematics, same accept set.
 
     COFACTORED (the [8]·): torsion components of adversarial A/R cancel
     deterministically, so acceptance never depends on the random z_i — a
@@ -155,6 +173,21 @@ def msm_epilogue_check(
     library) backends if adversarially-crafted torsion keys are a concern.
     """
     ref = kernel.ref
+    if lib is not None:
+        va = np.ascontiguousarray(va_limbs, np.int32)
+        vr = np.ascontiguousarray(vr_limbs, np.int32)
+        rc = -1
+        if va.shape == (4, kernel.NLIMB, 64) and vr.shape[:2] == va.shape[:2]:
+            rc = lib.msm_epilogue_native(
+                va.ctypes.data, vr.ctypes.data, vr.shape[2],
+                (sum_s % ref.L).to_bytes(32, "little"),
+            )
+        if rc < 0:
+            raise ValueError(
+                f"window sums {va.shape} / {vr.shape} are not the kernels' "
+                "[4, NLIMB, 64] / [4, NLIMB, 1..64]"
+            )
+        return rc == 1
     Wa = va_limbs.shape[2]
     off = Wa - vr_limbs.shape[2]
 
@@ -224,8 +257,9 @@ class TpuVerifier:
         # unmeasured; ROADMAP D1/S2 decide it from the benchmark's numbers.
         # The protocol-serving VerifyService runs this way.
         self.fixed_bucket = fixed_bucket
-        # Dispatch and detour counts (see the module docstring); bumped
-        # from the service's submit AND collect threads, hence the lock.
+        # Dispatch, epilogue and detour counts (see the module docstring);
+        # bumped from the service's sealing loops AND its collect thread,
+        # hence the lock.
         self.counts: collections.Counter = collections.Counter()
         self._counts_lock = threading.Lock()
         # mesh: shard verify batches over the mesh's data axis (SURVEY
@@ -664,16 +698,28 @@ class TpuVerifier:
             arr.copy_to_host_async()
         return (out, sum_s, bucket)
 
+    def _batch_passes(self, out, sum_s: int) -> bool:
+        """Force one msm dispatch: the device's validity lanes, then the
+        host epilogue identity, native where the scalar library is loaded
+        (counted `epilogue_native`) and on Python integers where it is not
+        (`epilogue_python`)."""
+        va_dev, vr_dev, valid_dev = out
+        if not bool(np.asarray(valid_dev).all()):
+            return False
+        lib = _scalar_lib()
+        event = "epilogue_native" if lib is not None else "epilogue_python"
+        self._count(event)
+        SERVICE_EVENTS.labels(event).inc()
+        return msm_epilogue_check(
+            np.asarray(va_dev), np.asarray(vr_dev), sum_s, self.kernel, lib
+        )
+
     def _chunk_passes(self, dispatched) -> bool:
-        """Force one `_dispatch_group_chunk` result: device validity lanes
-        plus the host epilogue identity."""
+        """Force one `_dispatch_group_chunk` result."""
         if dispatched is None:
             return False
-        (va_dev, vr_dev, valid_dev), sum_s, _ = dispatched
-        valid = np.asarray(valid_dev)
-        return bool(valid.all()) and msm_epilogue_check(
-            np.asarray(va_dev), np.asarray(vr_dev), sum_s, self.kernel
-        )
+        out, sum_s, _ = dispatched
+        return self._batch_passes(out, sum_s)
 
     def collect_groups(self, handle) -> list[bool]:
         """Resolve a `submit_groups` handle. A failed combined check
@@ -746,11 +792,7 @@ class TpuVerifier:
                 if kind == "item":
                     results[lo:hi] = np.asarray(out[pick])[: hi - lo]
                     continue
-                (va_dev, vr_dev, valid_dev), sum_s = out
-                valid = np.asarray(valid_dev)
-                if bool(valid.all()) and msm_epilogue_check(
-                    np.asarray(va_dev), np.asarray(vr_dev), sum_s, self.kernel
-                ):
+                if self._batch_passes(*out):
                     results[lo:hi] = True
                 else:
                     logger.warning(
@@ -781,8 +823,8 @@ def data_mesh(shards: int, devices=None):
     return device_mesh(shards, "data", "--verify-shards", devices)
 
 
-# The service's two scrape series. Process-wide like the service: every
-# node mounts them in its registry (`Registry.mount`), so a co-hosted
+# The service's scrape series. Process-wide like the service: every node
+# mounts them in its registry (`Registry.mount`), so a co-hosted
 # committee's scrapes all show the one service they share.
 SERVICE_ROWS = Counter(
     "verify_service_rows_total",
@@ -799,6 +841,14 @@ SERVICE_WAIT = Histogram(
     "coroutine resumed, per entry)",
     ("phase",),
 )
+SERVICE_EVENTS = Counter(
+    "verify_service_events_total",
+    "What a flush of the device verifier met on its way (event="
+    "epilogue_native / epilogue_python: which twin ran an msm dispatch's "
+    "host epilogue; event=deferred: a seal found every in-flight slot "
+    "taken and left its entries queued for the next completion)",
+    ("event",),
+)
 _LANES = {"s": "singles", "g": "groups"}
 
 
@@ -811,25 +861,40 @@ class VerifyService:
     device dispatches, each paying the full dispatch + readback latency.
     ONE instance per process merges every node's items into large buckets
     and keeps several batches in flight, so all protocol hops of all nodes
-    share flushes. The constants (2,048-row bucket, 3 ms seal deadline,
-    three in flight, off-thread readbacks) are unmeasured on a locally
-    attached chip; ROADMAP D1/S2 decide them from the benchmark's numbers.
+    share flushes. The constants: a 2,048-row bucket, a 3 ms seal deadline
+    counted from the oldest queued entry, at most three flushes sealed and
+    not yet answered. ROADMAP S2 sizes the bucket and merges the hops from
+    the benchmark's numbers; the deadline and the bound have not moved.
 
     Thread model (asyncio-loop agnostic — nodes on different loops can
-    share it):
-      callers     append (item, loop, future) under a lock;
-      submit thread seals a merged batch (size- or deadline-triggered)
-                  and runs TpuVerifier.submit — host packing is the
-                  GIL-releasing native pipeline;
-      collect thread blocks on the device result and resolves futures via
-                  loop.call_soon_threadsafe.
-    A bounded in-flight queue applies backpressure when the device falls
-    behind. Presents the AsyncVerifierPool interface (`await verify(...)`,
-    `close()`).
+    share it). A flush never competes for the interpreter with the loop
+    that waits for it:
+      callers     append (item, loop, future) under a lock; the first
+                  entry of an idle queue arms ONE seal on its own loop
+                  (`call_later` at the oldest entry's deadline; a full
+                  bucket seals at once, inside the enqueue);
+      the seal    runs on that loop's thread, which holds the interpreter
+                  already: takes both lanes (entries of every loop), runs
+                  TpuVerifier.submit / submit_groups inline — numpy
+                  packing, two GIL-free native calls, the jit dispatch;
+                  3.9 ms at the median on the v5e's host, PERF.md §5 —
+                  and hands the handle to the collect thread.
+                  It never blocks: with every in-flight slot taken it
+                  leaves its entries queued (`flushes["deferred"]`) and
+                  the next completion arms it again, on the loop of the
+                  oldest queued entry;
+      collect thread blocks on the device result, runs the native
+                  epilogue (no interpreter needed) and resolves a flush's
+                  futures with one `call_soon_threadsafe` per loop.
+    While one callback holds a loop nothing seals there; no waiter could
+    resume before that loop turns either, so only the overlap of device
+    work with the stall is lost. Presents the AsyncVerifierPool interface
+    (`await verify(...)`, `close()`).
 
     Flight record (tracing.flight, always on, one per flush; layout in
     tracing.FLIGHT_FIELDS): `flush`, and `wake` once its waiters have all
-    resumed. One stamp, `t_posted`, closes a flush: `collect` returned and
+    resumed. `t_seal`: the batch left the queue; `t_dispatched`: `submit`
+    returned; one stamp, `t_posted`, closes a flush: `collect` returned and
     the verdicts went to the waiters' loops, with nothing in between. The
     same sums feed SERVICE_ROWS and SERVICE_WAIT; the dispatch and the
     collect each run under a `tracing.annotation` carrying the flush's seq."""
@@ -846,37 +911,38 @@ class VerifyService:
         self.verifier = verifier
         self.max_batch = max_batch
         self.max_delay = max_delay
-        # Flushes handed to the device per lane, and flushes whose
-        # dispatch or readback raised (their waiters got the error: a
-        # failed device dispatch is never answered from the host).
+        # Flushes handed to the device per lane, flushes whose dispatch or
+        # readback raised (their waiters got the error: a failed device
+        # dispatch is never answered from the host), and seals `deferred`.
         self.flushes: collections.Counter = collections.Counter()
         self._pending: collections.deque = collections.deque()
         # Aggregate-certificate groups (compact certs) ride a second lane:
         # they dispatch through submit_groups (doubled rows, per-group
-        # random outer weights) but share the same submit/collect threads
-        # and inflight pipeline.
+        # random outer weights) but share the seal, the collect thread and
+        # the in-flight bound.
         self._pending_groups: collections.deque = collections.deque()
         self.max_group_rows = max_batch  # 2 rows per signer, same bucket
+        # Guards the lanes and everything below.
         self._lock = threading.Lock()
-        self._wake = threading.Condition(self._lock)
-        self._inflight: queue.Queue = queue.Queue(maxsize=inflight)
+        self._inflight_max = inflight
+        self._sealed = 0  # flushes sealed and not yet answered
+        self._armed: tuple | None = None  # (loop, due) of the one live seal
         self._closed = False
-        self._seq = 0  # flushes sealed; the submit thread's alone
+        self._seq = 0  # flushes sealed, ever
+        # Dispatched flushes on their way to the collect thread; bounded by
+        # `_sealed`, so a put never blocks the loop.
+        self._handoff: queue.SimpleQueue = queue.SimpleQueue()
         # Guards the per-flush wake tallies: waiters of one flush may
         # resume on different loops (threads).
         self._wake_lock = threading.Lock()
-        self._submit_thread = threading.Thread(
-            target=self._submit_loop, daemon=True, name="verify-submit"
-        )
         self._collect_thread = threading.Thread(
             target=self._collect_loop, daemon=True, name="verify-collect"
         )
-        self._submit_thread.start()
         self._collect_thread.start()
         # A daemon thread frozen inside XLA C++ during interpreter
         # finalization aborts the process ("FATAL: exception not
         # rethrown") — same hazard the DAG prewarm threads guard against.
-        # Stop the loops and bounded-join before Python tears down.
+        # Stop the loop and bounded-join before Python tears down.
         import atexit
 
         atexit.register(self.shutdown)
@@ -928,13 +994,16 @@ class VerifyService:
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         tally: list = []  # the collect thread puts the flush's wake tally here
-        with self._wake:
+        now = time.monotonic()
+        with self._lock:
             if self._closed:
-                # The submit thread is gone (or draining): an enqueued
-                # future would never resolve.
+                # Nothing seals anymore: an enqueued future would never
+                # resolve.
                 raise RuntimeError("verify service shut down")
-            lane.append((item, loop, fut, time.monotonic(), tally))
-            self._wake.notify()
+            lane.append((item, loop, fut, now, tally))
+            seal_now = self._arm(loop, now)
+        if seal_now:
+            self._on_seal()
         try:
             return await fut
         finally:
@@ -956,88 +1025,131 @@ class VerifyService:
         if done:
             tracing.flight("wake", tally[0], tally[3], tally[4], tally[5], tally[2])
 
-    def _seal(self) -> list | None:
-        """Under the lock: a singles batch worth dispatching, or None."""
-        if not self._pending:
-            return None
-        n = len(self._pending)
-        if n >= self.max_batch or (
-            time.monotonic() - self._pending[0][3] >= self.max_delay
-        ):
-            take = min(n, self.max_batch)
-            return [self._pending.popleft() for _ in range(take)]
-        return None
+    # -- the seal rule (all under the lock) ---------------------------------
 
-    def _seal_groups(self) -> list | None:
-        """Under the lock: a groups batch (by total doubled-row budget)."""
-        if not self._pending_groups:
-            return None
-        rows = sum(2 * len(g[0][0]) for g in self._pending_groups)
-        if rows >= self.max_group_rows or (
-            time.monotonic() - self._pending_groups[0][3] >= self.max_delay
-        ):
-            out, budget = [], self.max_group_rows
-            while self._pending_groups:
-                need = 2 * len(self._pending_groups[0][0][0])
-                if out and need > budget:
+    def _singles_due(self, now: float) -> bool:
+        return bool(self._pending) and (
+            len(self._pending) >= self.max_batch
+            or now - self._pending[0][3] >= self.max_delay
+        )
+
+    def _groups_due(self, now: float) -> bool:
+        """By total doubled-row budget, or the oldest group's deadline."""
+        return bool(self._pending_groups) and (
+            now - self._pending_groups[0][3] >= self.max_delay
+            or sum(2 * len(g[0][0]) for g in self._pending_groups) >= self.max_group_rows
+        )
+
+    def _take_singles(self) -> list:
+        take = min(len(self._pending), self.max_batch)
+        return [self._pending.popleft() for _ in range(take)]
+
+    def _take_groups(self) -> list:
+        out, budget = [], self.max_group_rows
+        while self._pending_groups:
+            need = 2 * len(self._pending_groups[0][0][0])
+            if out and need > budget:
+                break
+            out.append(self._pending_groups.popleft())
+            budget -= need
+        return out
+
+    def _oldest(self):
+        """The oldest queued entry of either lane, or None."""
+        heads = [lane[0] for lane in (self._pending, self._pending_groups) if lane]
+        return min(heads, key=lambda e: e[3]) if heads else None
+
+    def _seal_is_armed(self) -> bool:
+        """A seal is on its way, on a loop that can still run it."""
+        return self._armed is not None and not self._armed[0].is_closed()
+
+    def _arm(self, loop, now: float) -> bool:
+        """On `loop`'s thread, after an append: see that a seal is on its
+        way. True: a lane is due already (a full bucket, or a deadline its
+        timer is late for), the caller seals at once."""
+        if self._sealed >= self._inflight_max:
+            return False  # the next completion arms it
+        if self._singles_due(now) or self._groups_due(now):
+            return True
+        if not self._seal_is_armed():
+            self._arm_deadline(loop, now)
+        return False
+
+    def _arm_deadline(self, loop, now: float) -> None:
+        """On `loop`'s thread: one seal at the oldest entry's deadline."""
+        due = self._oldest()[3] + self.max_delay + 1e-4
+        self._armed = armed = (loop, due)
+        loop.call_later(max(0.0, due - now), self._on_seal, armed)
+
+    def _on_seal(self, armed: tuple | None = None) -> None:
+        """On a loop's thread: seal what is due and dispatch it inline.
+        `armed` names the timer that called; one that another has replaced
+        does nothing."""
+        now = time.monotonic()
+        sealed = []
+        with self._lock:
+            if armed is not None and self._armed is not armed:
+                return
+            self._armed = None
+            if self._closed:
+                return
+            for kind, due, take in (
+                ("s", self._singles_due, self._take_singles),
+                ("g", self._groups_due, self._take_groups),
+            ):
+                if not due(now):
+                    continue
+                if self._sealed >= self._inflight_max:
+                    # Never wait on the loop: the entries stay queued and
+                    # the collect thread's next completion arms the seal.
+                    self.flushes["deferred"] += 1
+                    SERVICE_EVENTS.labels("deferred").inc()
                     break
-                g = self._pending_groups.popleft()
-                out.append(g)
-                budget -= need
-            return out
-        return None
+                self._sealed += 1
+                self._seq += 1
+                sealed.append((kind, self._seq, take()))
+            else:
+                # What stays queued is not due yet, or is more than a bucket:
+                # its deadline, on this loop.
+                if self._pending or self._pending_groups:
+                    self._arm_deadline(asyncio.get_running_loop(), now)
+        for kind, seq, entries in sealed:
+            self._dispatch(kind, seq, entries, now)
 
-    def _oldest_age(self) -> float | None:
-        ages = []
-        if self._pending:
-            ages.append(time.monotonic() - self._pending[0][3])
-        if self._pending_groups:
-            ages.append(time.monotonic() - self._pending_groups[0][3])
-        return max(ages) if ages else None
+    def _release(self) -> None:
+        """A sealed flush was answered, or never left: free its slot, and
+        if entries wait with no seal on its way, start one on the loop of
+        the oldest (the first open loop, if that one closed)."""
+        now = time.monotonic()
+        with self._lock:
+            self._sealed -= 1
+            if self._closed or self._seal_is_armed():
+                return
+            oldest = self._oldest()
+            if oldest is None:
+                return
+            queued = itertools.chain((oldest,), self._pending, self._pending_groups)
+            for loop in (e[1] for e in queued):
+                armed = (loop, now)
+                try:
+                    loop.call_soon_threadsafe(self._on_seal, armed)
+                except RuntimeError:  # closed: nobody waits there anymore
+                    continue
+                self._armed = armed
+                return
 
-    def _submit_loop(self) -> None:
-        while True:
-            with self._wake:
-                batch = self._seal()
-                gbatch = self._seal_groups()
-                while batch is None and gbatch is None and not self._closed:
-                    # Wake early enough to honor the oldest item's deadline.
-                    age = self._oldest_age()
-                    timeout = (
-                        None if age is None else max(0.0, self.max_delay - age) + 1e-4
-                    )
-                    self._wake.wait(timeout=timeout)
-                    batch = self._seal()
-                    gbatch = self._seal_groups()
-                if batch is None and gbatch is None and self._closed:
-                    # Drain: anything still queued will never dispatch —
-                    # fail its futures instead of leaving awaiters hanging.
-                    leftovers = list(self._pending) + list(self._pending_groups)
-                    self._pending.clear()
-                    self._pending_groups.clear()
-                    if leftovers:
-                        self._resolve_error(
-                            leftovers, RuntimeError("verify service shut down")
-                        )
-                    self._inflight.put(None)  # collector shutdown
-                    return
-            t_seal = time.monotonic()
-            if batch is not None:
-                self._dispatch("s", batch, len(batch), t_seal)
-            if gbatch is not None:
-                rows = sum(2 * len(e[0][0]) for e in gbatch)
-                self._dispatch("g", gbatch, rows, t_seal)
-
-    def _dispatch(self, kind: str, entries: list, useful: int, t_seal: float) -> None:
-        """Submit thread: hand one sealed lane batch to the device and pass
-        it, with what the flush record needs, to the collect thread."""
+    def _dispatch(self, kind: str, seq: int, entries: list, t_seal: float) -> None:
+        """On the sealing loop's thread: hand one sealed lane batch to the
+        device and pass it, with what the flush record needs, to the
+        collect thread."""
         lane = _LANES[kind]
-        self._seq += 1
-        seq = self._seq
+        useful = len(entries) if kind == "s" else sum(2 * len(e[0][0]) for e in entries)
         waits = [t_seal - e[3] for e in entries]
         queue_wait = SERVICE_WAIT.labels("queue")
         for w in waits:
             queue_wait.observe(w)
+        # The flush record, around the rows the dispatch was padded to.
+        head, tail = (seq, lane, len(entries), useful), (entries[0][3], sum(waits), t_seal)
         submit = self.verifier.submit_groups if kind == "g" else self.verifier.submit
         try:
             with tracing.annotation("narwhal/verify_submit", seq=seq, lane=lane):
@@ -1045,24 +1157,31 @@ class VerifyService:
         except Exception as e:
             logger.exception("verify submit failed for %d %s entries", len(entries), lane)
             self.flushes["submit_failed"] += 1
-            t_failed = time.monotonic()
-            self._resolve_error(entries, e)
-            tracing.flight(
-                "flush", seq, lane, len(entries), useful, 0, entries[0][3], sum(waits),
-                t_seal, t_failed, t_failed, f"submit: {e!r}"[:160],
-            )
+            self._never_left(entries, (*head, 0, *tail), e, f"submit: {e!r}")
             return
         t_dispatched = time.monotonic()
         SERVICE_ROWS.labels(lane, "useful").inc(useful)
         SERVICE_ROWS.labels(lane, "padded").inc(handle.padded)
         self.flushes[lane] += 1
-        meta = (seq, lane, len(entries), useful, handle.padded, entries[0][3], sum(waits),
-                t_seal, t_dispatched)
-        self._inflight.put((kind, handle, entries, meta))
+        record = (*head, handle.padded, *tail)
+        with self._lock:
+            if not self._closed:
+                self._handoff.put((kind, handle, entries, (*record, t_dispatched)))
+                return
+        # shutdown() ran while this flush was packed: nobody collects it.
+        self._never_left(entries, record, RuntimeError("verify service shut down"), "shut down")
+
+    def _never_left(self, entries: list, record: tuple, exc, failure: str) -> None:
+        """A sealed flush that reaches no collect: its waiters get `exc`,
+        its record the failure, its slot goes back."""
+        t_failed = time.monotonic()
+        self._resolve_error(entries, exc)
+        tracing.flight("flush", *record, t_failed, t_failed, failure[:160])
+        self._release()
 
     def _collect_loop(self) -> None:
         while True:
-            got = self._inflight.get()
+            got = self._handoff.get()
             if got is None:
                 return
             kind, handle, entries, meta = got
@@ -1082,53 +1201,67 @@ class VerifyService:
                 # loops. Each waiter finds the flush's wake tally in its entry.
                 t_posted = time.monotonic()
                 tally = [meta[0], len(entries), t_posted, 0, 0.0, 0.0]
-                for (_, loop, fut, _, slot), res in zip(entries, results):
-                    slot.append(tally)
-                    self._post(loop, fut, res, None)
+                for entry in entries:
+                    entry[4].append(tally)
+                self._post(entries, results, None)
+            self._release()
             SERVICE_WAIT.labels("turnaround").observe(t_posted - meta[7])
             tracing.flight("flush", *meta, t_posted, failure)
 
     def _resolve_error(self, entries, exc) -> None:
-        for _, loop, fut, _, _ in entries:
-            self._post(loop, fut, None, exc)
+        self._post(entries, [None] * len(entries), exc)
 
     @staticmethod
-    def _post(loop, fut, result, exc) -> None:
-        def setter() -> None:
-            if fut.done():
-                return
-            if exc is not None:
-                fut.set_exception(exc)
-            else:
-                fut.set_result(result)
+    def _post(entries, results, exc) -> None:
+        """Resolve a flush's futures: one wake-up per loop, not per entry
+        (each `call_soon_threadsafe` is a write to the loop's self-pipe and
+        a chance to lose the interpreter between two verdicts)."""
+        by_loop: dict = {}
+        for (_, loop, fut, _, _), res in zip(entries, results):
+            by_loop.setdefault(loop, []).append((fut, res))
 
-        try:
-            loop.call_soon_threadsafe(setter)
-        except RuntimeError:
-            # The caller's loop closed (its cluster/test tore down before
-            # the device answered); nobody is waiting anymore.
-            pass
+        def deliver(pairs) -> None:
+            for fut, res in pairs:
+                if fut.done():
+                    continue
+                if exc is not None:
+                    fut.set_exception(exc)
+                else:
+                    fut.set_result(res)
+
+        for loop, pairs in by_loop.items():
+            try:
+                loop.call_soon_threadsafe(deliver, pairs)
+            except RuntimeError:
+                # The caller's loop closed (its cluster/test tore down before
+                # the device answered); nobody is waiting anymore.
+                pass
 
     async def close(self) -> None:
         """Per-node shutdown is a no-op for the process-wide instance: other
-        nodes (and the next in-process cluster) keep using it; threads are
-        daemons and idle when no traffic flows."""
+        nodes (and the next in-process cluster) keep using it; the collect
+        thread is a daemon and idle when no traffic flows."""
         return None
 
     def shutdown(self) -> bool:
-        """Really stop the threads (tests; process teardown). Returns
-        whether both stopped inside the join window."""
-        with self._wake:
+        """Really stop (tests; process teardown): fail what is queued, let
+        the collect thread answer what is in flight, and join it. Returns
+        whether it stopped inside the join window."""
+        with self._lock:
+            first = not self._closed
             self._closed = True
-            self._wake.notify_all()
-        self._submit_thread.join(timeout=10.0)
+            leftovers = [*self._pending, *self._pending_groups]
+            self._pending.clear()
+            self._pending_groups.clear()
+            if first:
+                self._handoff.put(None)  # after every flush already handed over
+        if leftovers:
+            self._resolve_error(leftovers, RuntimeError("verify service shut down"))
         self._collect_thread.join(timeout=10.0)
         for key, svc in list(self._shared.items()):
             if svc is self:
                 del self._shared[key]
-        return not (
-            self._submit_thread.is_alive() or self._collect_thread.is_alive()
-        )
+        return not self._collect_thread.is_alive()
 
 
 class AsyncVerifierPool:

@@ -93,7 +93,7 @@ _GENERATION: int = 0
 
 
 # The process flight ring. Appends come from the loop thread(s) and from
-# the verify service's submit and collect threads: `deque.append` of one
+# the verify service's collect thread: `deque.append` of one
 # tuple is atomic, and readers copy on read (`flight_dump`), as
 # `Tracer.dump` does. Sized to outlast a traced benchmark run with room to
 # spare: four co-hosted validators write ~380 records a second under the

@@ -7,7 +7,9 @@
 // batch, vs ~260 ms of device compute: the host was the bottleneck). This
 // file does the same work in C at ~1 us/item with the GIL released (ctypes
 // calls drop it), so host packing of batch N+1 genuinely overlaps the device
-// compute of batch N.
+// compute of batch N. The host half of the msm batch check (the Horner walk
+// over the device's per-window point sums and the identity test) is here
+// too, msm_epilogue_native: ~420 point operations that need no interpreter.
 //
 // Parity targets (behavior, not code): the precheck + challenge rules of
 // /root/reference/types/src/primary.rs:487-537's certificate verification
@@ -246,6 +248,202 @@ static void addmod_l(u64 acc[4], const u64 t[4]) {
   if (carry || limbs_cmp(acc, L_LIMBS, 4) >= 0) limbs_sub(acc, L_LIMBS, 4);
 }
 
+// ---- field and group arithmetic for the msm epilogue -----------------------
+// Host half of the batch check (verifier.py msm_epilogue_check is the Python
+// twin and the tests' oracle): GF(2^255 - 19) on five 51-bit limbs with
+// unsigned __int128 products, twisted-Edwards extended coordinates (a = -1)
+// with the reference module's add and double formulas, so X, Y, Z, T agree
+// with narwhal_tpu/tpu/ed25519_ref.py limb for limb once reduced.
+//
+// Limb bounds: a product's limbs are < 2^51 + 2^21; sums and differences of
+// products stay < 2^54; fe_mul takes limbs < 2^55 (5 * 19 * 2^110 < 2^128).
+
+typedef __int128 i128;
+
+struct fe { u64 v[5]; };
+struct pt { fe x, y, z, t; };
+
+static const u64 M51 = (1ULL << 51) - 1;
+static const fe FE_ZERO = {{0, 0, 0, 0, 0}};
+static const fe FE_ONE = {{1, 0, 0, 0, 0}};
+static const fe FE_2D = {{0x69b9426b2f159ULL, 0x35050762add7aULL, 0x3cf44c0038052ULL,
+                          0x6738cc7407977ULL, 0x2406d9dc56dffULL}};
+// The base point B = (x, 4/5), T = xy.
+static const pt PT_BASE = {
+    {{0x62d608f25d51aULL, 0x412a4b4f6592aULL, 0x75b7171a4b31dULL, 0x1ff60527118feULL,
+      0x216936d3cd6e5ULL}},
+    {{0x6666666666658ULL, 0x4ccccccccccccULL, 0x1999999999999ULL, 0x3333333333333ULL,
+      0x6666666666666ULL}},
+    {{1, 0, 0, 0, 0}},
+    {{0x68ab3a5b7dda3ULL, 0x00eea2a5eadbbULL, 0x2af8df483c27eULL, 0x332b375274732ULL,
+      0x67875f0fd78b7ULL}}};
+
+static inline void fe_add(fe &o, const fe &a, const fe &b) {
+  for (int i = 0; i < 5; ++i) o.v[i] = a.v[i] + b.v[i];
+}
+
+// o = a - b + 4p; b's limbs must be < 2^53 - 76 (they are products: < 2^52).
+static inline void fe_sub(fe &o, const fe &a, const fe &b) {
+  o.v[0] = a.v[0] + ((1ULL << 53) - 76) - b.v[0];
+  for (int i = 1; i < 5; ++i) o.v[i] = a.v[i] + ((1ULL << 53) - 4) - b.v[i];
+}
+
+static void fe_mul(fe &o, const fe &a, const fe &b) {
+  const u64 a0 = a.v[0], a1 = a.v[1], a2 = a.v[2], a3 = a.v[3], a4 = a.v[4];
+  const u64 b0 = b.v[0], b1 = b.v[1], b2 = b.v[2], b3 = b.v[3], b4 = b.v[4];
+  const u64 b1_19 = 19 * b1, b2_19 = 19 * b2, b3_19 = 19 * b3, b4_19 = 19 * b4;
+  u128 r0 = (u128)a0 * b0 + (u128)a1 * b4_19 + (u128)a2 * b3_19 + (u128)a3 * b2_19 + (u128)a4 * b1_19;
+  u128 r1 = (u128)a0 * b1 + (u128)a1 * b0 + (u128)a2 * b4_19 + (u128)a3 * b3_19 + (u128)a4 * b2_19;
+  u128 r2 = (u128)a0 * b2 + (u128)a1 * b1 + (u128)a2 * b0 + (u128)a3 * b4_19 + (u128)a4 * b3_19;
+  u128 r3 = (u128)a0 * b3 + (u128)a1 * b2 + (u128)a2 * b1 + (u128)a3 * b0 + (u128)a4 * b4_19;
+  u128 r4 = (u128)a0 * b4 + (u128)a1 * b3 + (u128)a2 * b2 + (u128)a3 * b1 + (u128)a4 * b0;
+  r1 += r0 >> 51;
+  r2 += r1 >> 51;
+  r3 += r2 >> 51;
+  r4 += r3 >> 51;
+  // 2^255 === 19: the top carry (< 2^67) comes back into limb 0.
+  u128 low = ((u64)r0 & M51) + (r4 >> 51) * 19;
+  o.v[0] = (u64)low & M51;
+  o.v[1] = ((u64)r1 & M51) + (u64)(low >> 51);
+  o.v[2] = (u64)r2 & M51;
+  o.v[3] = (u64)r3 & M51;
+  o.v[4] = (u64)r4 & M51;
+}
+
+// Full reduction to [0, p); limbs < 2^63 on entry.
+static void fe_canonical(fe &h) {
+  for (int pass = 0; pass < 2; ++pass) {
+    for (int i = 0; i < 4; ++i) {
+      h.v[i + 1] += h.v[i] >> 51;
+      h.v[i] &= M51;
+    }
+    h.v[0] += 19 * (h.v[4] >> 51);
+    h.v[4] &= M51;
+  }
+  // h < 2^255 + 2^13 < 2p: q = 1 iff h >= p.
+  u64 q = (h.v[0] + 19) >> 51;
+  for (int i = 1; i < 5; ++i) q = (h.v[i] + q) >> 51;
+  h.v[0] += 19 * q;
+  for (int i = 0; i < 4; ++i) {
+    h.v[i + 1] += h.v[i] >> 51;
+    h.v[i] &= M51;
+  }
+  h.v[4] &= M51;
+}
+
+static bool fe_is_zero(fe h) {
+  fe_canonical(h);
+  return (h.v[0] | h.v[1] | h.v[2] | h.v[3] | h.v[4]) == 0;
+}
+
+// One coordinate as the device leaves it: 20 int32 limbs of radix 2^13,
+// `stride` apart, loose and possibly negative (|limb| < 2^31, so the value
+// is within +-2^279). Same value mod p as kernel.limbs_to_int(...) % P.
+static void fe_from_loose13(fe &o, const int32_t *limbs, int64_t stride) {
+  i128 acc[6] = {0, 0, 0, 0, 0, 0};
+  for (int i = 0; i < 20; ++i) {
+    int bit = 13 * i;
+    acc[bit / 51] += (i128)limbs[i * stride] * ((i128)1 << (bit % 51));
+  }
+  // Add 2^26 * p (> 2^280) so the total is positive whatever the signs.
+  acc[5] += (i128)1 << 26;
+  acc[0] -= (i128)19 << 26;
+  for (int j = 0; j < 5; ++j) {
+    i128 carry = acc[j] >> 51;  // floor: g++ shifts signed values arithmetically
+    acc[j] -= carry * ((i128)1 << 51);
+    acc[j + 1] += carry;
+  }
+  // acc[5] (0 <= it < 2^27) counts 2^255 === 19.
+  u64 low = (u64)acc[0] + 19 * (u64)acc[5];
+  o.v[0] = low & M51;
+  o.v[1] = (u64)acc[1] + (low >> 51);
+  o.v[2] = (u64)acc[2];
+  o.v[3] = (u64)acc[3];
+  o.v[4] = (u64)acc[4];
+}
+
+static void fe_from_bytes(fe &o, const uint8_t in[32]) {
+  u64 w[4];
+  memcpy(w, in, 32);
+  o.v[0] = w[0] & M51;
+  o.v[1] = ((w[0] >> 51) | (w[1] << 13)) & M51;
+  o.v[2] = ((w[1] >> 38) | (w[2] << 26)) & M51;
+  o.v[3] = ((w[2] >> 25) | (w[3] << 39)) & M51;
+  o.v[4] = w[3] >> 12;  // bit 255 too: the value is taken mod p, not masked
+}
+
+static void fe_to_bytes(uint8_t out[32], fe h) {
+  fe_canonical(h);
+  u64 w[4] = {h.v[0] | (h.v[1] << 51), (h.v[1] >> 13) | (h.v[2] << 38),
+              (h.v[2] >> 26) | (h.v[3] << 25), (h.v[3] >> 39) | (h.v[4] << 12)};
+  memcpy(out, w, 32);
+}
+
+static const pt PT_IDENTITY = {FE_ZERO, FE_ONE, FE_ONE, FE_ZERO};
+
+// ed25519_ref.point_add, with C = T1 * T2 * 2d.
+static void pt_add(pt &o, const pt &p, const pt &q) {
+  fe a, b, c, d, e, f, g, h, u, v;
+  fe_sub(u, p.y, p.x);
+  fe_sub(v, q.y, q.x);
+  fe_mul(a, u, v);
+  fe_add(u, p.y, p.x);
+  fe_add(v, q.y, q.x);
+  fe_mul(b, u, v);
+  fe_mul(u, p.t, q.t);
+  fe_mul(c, u, FE_2D);
+  fe_mul(u, p.z, q.z);
+  fe_add(d, u, u);
+  fe_sub(e, b, a);
+  fe_sub(f, d, c);
+  fe_add(g, d, c);
+  fe_add(h, b, a);
+  fe_mul(o.x, e, f);
+  fe_mul(o.y, g, h);
+  fe_mul(o.z, f, g);
+  fe_mul(o.t, e, h);
+}
+
+// ed25519_ref.point_double.
+static void pt_double(pt &o, const pt &p) {
+  fe a, b, c, e, f, g, h, u;
+  fe_mul(a, p.x, p.x);
+  fe_mul(b, p.y, p.y);
+  fe_mul(u, p.z, p.z);
+  fe_add(c, u, u);
+  fe_add(h, a, b);
+  fe_add(u, p.x, p.y);
+  fe_mul(e, u, u);
+  fe_sub(e, h, e);
+  fe_sub(g, a, b);
+  fe_add(f, c, g);
+  fe_mul(o.x, e, f);
+  fe_mul(o.y, g, h);
+  fe_mul(o.z, f, g);
+  fe_mul(o.t, e, h);
+}
+
+// [j]B for the sixteen values of a 4-bit digit.
+struct base_table {
+  pt m[16];
+  base_table() {
+    m[0] = PT_IDENTITY;
+    for (int j = 1; j < 16; ++j) pt_add(m[j], m[j - 1], PT_BASE);
+  }
+};
+
+static void pt_from_loose13(pt &o, const int32_t *v, int64_t lanes, int64_t lane) {
+  fe *coord[4] = {&o.x, &o.y, &o.z, &o.t};
+  for (int c = 0; c < 4; ++c) fe_from_loose13(*coord[c], v + (c * 20) * lanes + lane, lanes);
+}
+
+static void pt_from_bytes(pt &o, const uint8_t in[128]) {
+  fe_from_bytes(o.x, in);
+  fe_from_bytes(o.y, in + 32);
+  fe_from_bytes(o.z, in + 64);
+  fe_from_bytes(o.t, in + 96);
+}
+
 // ---- exported batch entry points ------------------------------------------
 
 extern "C" {
@@ -336,6 +534,73 @@ void reduce_mod_l_test(const uint8_t *x, int64_t nx, uint8_t *out) {
   memcpy(xl, x, (size_t)nx * 8);
   reduce_mod_l(xl, (int)nx, o);
   memcpy(out, o, 32);
+}
+
+// The host half of one msm dispatch's batch check:
+//   [8]([sum_s]B + sum_w 16^(63-w) (V_a[w] + V_r[w - (64 - wr)])) == identity
+// va: int32[4][20][64], vr: int32[4][20][wr] (1 <= wr <= 64), C order: the
+// device's loose X/Y/Z/T limbs per MSB-first window lane. sum_s: 32 bytes,
+// little-endian, < L; its 4-bit digits ride the same Horner walk ([d]B from
+// a table built once), so [sum_s]B costs 64 additions and no doubling of its
+// own. Returns 1 for the identity, 0 otherwise, -1 for a wr out of range.
+int msm_epilogue_native(const int32_t *va, const int32_t *vr, int64_t wr,
+                        const uint8_t *sum_s) {
+  if (wr < 1 || wr > 64) return -1;
+  static const base_table base;  // built by the first caller (C++11: once)
+  const int64_t off = 64 - wr;
+  pt acc = PT_IDENTITY, v;
+  for (int64_t w = 0; w < 64; ++w) {
+    for (int k = 0; k < 4; ++k) pt_double(acc, acc);
+    pt_from_loose13(v, va, 64, w);
+    pt_add(acc, acc, v);
+    if (w >= off) {
+      pt_from_loose13(v, vr, wr, w - off);
+      pt_add(acc, acc, v);
+    }
+    int digit = (sum_s[(63 - w) / 2] >> (4 * ((63 - w) & 1))) & 15;
+    if (digit) pt_add(acc, acc, base.m[digit]);
+  }
+  for (int k = 0; k < 3; ++k) pt_double(acc, acc);  // cofactor 8
+  fe yz;
+  fe_sub(yz, acc.y, acc.z);
+  return fe_is_zero(acc.x) && fe_is_zero(yz) ? 1 : 0;
+}
+
+// Self-test hooks (tests/test_tpu_ed25519.py against ed25519_ref, and
+// native/scalar_selftest.cpp under the sanitizers). Field elements and
+// coordinates cross as 32 little-endian bytes, reduced on the way out.
+//   fe_test op: 0 a+b, 1 a-b, 2 a*b;  pt_test op: 0 p+q, 1 2p, 2 [q[0] & 15]B.
+void fe_test(int64_t op, const uint8_t *a, const uint8_t *b, uint8_t *out) {
+  fe x, y, o;
+  fe_from_bytes(x, a);
+  fe_from_bytes(y, b);
+  fe_mul(x, x, FE_ONE);  // limbs as the point formulas see them: reduced products
+  fe_mul(y, y, FE_ONE);
+  if (op == 0) fe_add(o, x, y);
+  else if (op == 1) fe_sub(o, x, y);
+  else fe_mul(o, x, y);
+  fe_to_bytes(out, o);
+}
+
+void pt_test(int64_t op, const uint8_t *p, const uint8_t *q, uint8_t *out) {
+  static const base_table base;
+  pt a, b, o;
+  pt_from_bytes(a, p);
+  pt_from_bytes(b, q);
+  if (op == 0) pt_add(o, a, b);
+  else if (op == 1) pt_double(o, a);
+  else o = base.m[q[0] & 15];
+  fe_to_bytes(out, o.x);
+  fe_to_bytes(out + 32, o.y);
+  fe_to_bytes(out + 64, o.z);
+  fe_to_bytes(out + 96, o.t);
+}
+
+// One loose radix-2^13 coordinate (20 int32, contiguous) reduced mod p.
+void fe_loose13_test(const int32_t *limbs, uint8_t *out) {
+  fe o;
+  fe_from_loose13(o, limbs, 1);
+  fe_to_bytes(out, o);
 }
 
 }  // extern "C"

@@ -28,13 +28,11 @@ def event(name, t0, t1, **stats):
 
 def planes():
     host = NS(name="/host:CPU", lines=[
-        NS(name="verify-submit", events=[
+        NS(name="verify-collect", events=[event("narwhal/verify_collect", 100.010, 100.030, seq=1)]),
+        NS(name="MainThread", events=[  # the loop: it seals and dispatches a flush itself
             event("narwhal/verify_submit", 100.002, 100.010, seq=1, lane="singles"),
             event("narwhal/verify_submit", 100.101, 100.105, seq=2, lane="groups"),
             event("some/other_trace_me", 100.0, 100.5),
-        ]),
-        NS(name="verify-collect", events=[event("narwhal/verify_collect", 100.010, 100.030, seq=1)]),
-        NS(name="MainThread", events=[
             event("narwhal/execute", 100.035, 100.095, index=7),
             event("narwhal/commit_walk", 100.220, 100.230, seq=3, certs=4),
         ]),

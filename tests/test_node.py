@@ -502,7 +502,7 @@ def test_more_shards_than_devices_is_a_config_error():
 # real-chip twin is the round artifact
 def test_cluster_with_tpu_crypto_shared_service(run):
     """crypto_backend="tpu": the whole committee shares ONE process-wide
-    VerifyService (merged flushes, pipelined submit/collect threads) —
+    VerifyService (merged flushes sealed on the loop, one collect thread) —
     certificates verify through the device kernel path and commits advance
     (on conftest's CPU devices; the real-chip twin is the round artifact).
 
