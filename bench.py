@@ -155,31 +155,26 @@ def main() -> None:
     # is timed separately: in the pipelined flow it overlaps the next
     # batch's device compute, so steady state is bounded by max(device,
     # epilogue), reported below as the effective rate.
-    z_dig = jnp.asarray(rng.integers(0, 16, (dev_b, 32), dtype=np.int32))
+    # The kernel takes the bucket's raw rows (A | R | ak | z; any bytes are
+    # a row: a y that decompresses to no point only clears the valid flag).
+    rows = jnp.asarray(rng.integers(0, 256, (dev_b, kern.ROW_BYTES), dtype=np.uint8))
 
     def repeat_msm(reps):
         @jax.jit
-        def f(a_y, sign, dig):
+        def f(rows):
             def body(i, acc):
-                va, vr, valid = kern.msm_accumulate_kernel(
-                    a_y, sign, a_y, sign, (dig + (i & 1)) & 15, z_dig
-                )
-                return acc + va[0, 0, 0] + vr[0, 0, 0] + jnp.sum(
-                    valid.astype(jnp.int32)
-                )
+                # Perturb the scalars' low nibbles per iteration.
+                out = kern.msm_accumulate_kernel(rows.at[:, 64].add(i.astype(jnp.uint8)))
+                return acc + out[0] + out[-1]
             return lax.fori_loop(0, reps, body, jnp.int32(0))
         return f
 
-    msm_accum_rate = chain_rate(repeat_msm, dev_b)
+    msm_accum_rate = chain_rate(repeat_msm, dev_b, args=(rows,))
 
     from narwhal_tpu.tpu.verifier import _scalar_lib, msm_epilogue_check
 
-    va_host, vr_host = (
-        np.asarray(v)
-        for v in kern.msm_accumulate_kernel(
-            np.asarray(a_y), np.asarray(sign), np.asarray(a_y), np.asarray(sign),
-            np.asarray(dig), np.asarray(z_dig),
-        )[:2]
+    va_host, vr_host, _ = kern.split_msm_result(
+        np.asarray(kern.msm_accumulate_kernel(np.asarray(rows)))
     )
     t0 = time.perf_counter()
     for _ in range(5):

@@ -579,7 +579,7 @@ def _required_walls(sz: Sizes) -> list[tuple[str, str]]:
     "1": (kernel, substring of its operand shapes)."""
     wide = f"uint8[{sz.gc_depth + 14},{sz.walk_n},{sz.walk_n}]"
     return [
-        ("msm_accumulate_kernel", f"int16[{sz.bucket},20]"),
+        ("msm_accumulate_kernel", f"uint8[{sz.bucket},112]"),
         ("verify_batch_kernel", f"int16[{sz.bucket},20]"),
         ("chain_commit", wide),
         ("roll_window", wide),

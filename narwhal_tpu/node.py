@@ -108,9 +108,16 @@ class PrimaryNode:
         # Process-wide series this node's scrape carries too: the loop's
         # heartbeat lateness and the shared verify service's rows, waits and
         # events (zero on a backend that runs no such service).
-        from .tpu.verifier import SERVICE_EVENTS, SERVICE_ROWS, SERVICE_WAIT
+        from .tpu import verifier
 
-        for series in (tracing.LOOP_LAG, SERVICE_ROWS, SERVICE_WAIT, SERVICE_EVENTS):
+        for series in (
+            tracing.LOOP_LAG,
+            verifier.SERVICE_ROWS,
+            verifier.SERVICE_TRANSFERS,
+            verifier.SERVICE_BYTES,
+            verifier.SERVICE_WAIT,
+            verifier.SERVICE_EVENTS,
+        ):
             self.registry.mount(series)
         self._heartbeat = False
         # Registered at assembly (not inside the monitor coroutine) so the

@@ -663,16 +663,82 @@ def _accumulate_windows(table, digits, chunk):
     return tuple(a[..., 0] for a in acc)  # [NLIMB, W]
 
 
+ROW_BYTES = 112  # A (32) | R (32) | ak (32) | z (16): one raw row of a bucket
+
+
+def expand_rows(rows):
+    """A bucket of raw rows -> the operands the msm stages take.
+
+    rows uint8[B, ROW_BYTES]; a row is A (32 bytes) | R (32) | ak = z*k mod
+    L (32) | z (16), each little-endian, as the host holds them; an
+    all-zero row is inert padding (y = 0 decompresses, every digit 0 picks
+    the identity). Returns, batch-leading and int32: a_y [B, NLIMB], a_sign
+    [B], r_y [B, NLIMB], r_sign [B] (the 13-bit limbs and the sign bit of
+    `bytes_to_limbs`), ak_digits [B, 64], z_digits [B, 32] (the 4-bit
+    MSB-first digits of `bytes_to_digits`). Everything here scales with
+    the bucket and nothing with the useful rows, so it runs on the device:
+    the single-chip kernel calls it inside its program, the mesh path jits
+    it on the data axis in front of its stages. The arithmetic runs with
+    the batch in the lanes ([bytes, B]); the transposes to the
+    batch-leading layout cancel against the stages' own."""
+    t = rows.astype(jnp.int32).T  # [ROW_BYTES, B]
+
+    def limbs(raw):  # [32, B] -> ([NLIMB, B], sign [B])
+        top = raw[31]
+        raw = jnp.concatenate(
+            [raw[:31], (top & 0x7F)[None], jnp.zeros_like(raw[:1])], axis=0
+        )  # sign bit cleared; one zero row for limb 19's third byte
+        out = []
+        for i in range(NLIMB):
+            bit = RADIX * i
+            b, shift = bit >> 3, bit & 7
+            val = raw[b] | (raw[b + 1] << 8)
+            if shift + RADIX > 16:
+                val = val | (raw[b + 2] << 16)
+            out.append((val >> shift) & MASK)
+        return jnp.stack(out, axis=0), top >> 7
+
+    def digits(raw):  # [n, B] little-endian bytes -> [2n, B] MSB-first nibbles
+        rev = raw[::-1]
+        return jnp.stack([rev >> 4, rev & 0xF], axis=1).reshape(2 * raw.shape[0], -1)
+
+    a_y, a_sign = limbs(t[0:32])
+    r_y, r_sign = limbs(t[32:64])
+    return a_y.T, a_sign, r_y.T, r_sign, digits(t[64:96]).T, digits(t[96:112]).T
+
+
+def msm_result(v_a, v_r, valid):
+    """The one array a bucket's check comes back in: V_a [4, NLIMB, 64],
+    V_r [4, NLIMB, 32] and the all-rows-valid flag, flattened to int32
+    (`split_msm_result` is its inverse on the host)."""
+    flag = jnp.all(valid).astype(jnp.int32)
+    return jnp.concatenate([v_a.reshape(-1), v_r.reshape(-1), flag[None]])
+
+
+MSM_RESULT_SIZE = 4 * NLIMB * (64 + 32) + 1
+
+
+def split_msm_result(flat: np.ndarray):
+    """Host views of `msm_result`'s array: (V_a, V_r, all rows valid)."""
+    n_a = 4 * NLIMB * 64
+    return (
+        flat[:n_a].reshape(4, NLIMB, 64),
+        flat[n_a:-1].reshape(4, NLIMB, 32),
+        bool(flat[-1]),
+    )
+
+
 @tracked_jit(static_argnames=("chunk",))
-def msm_accumulate_kernel(a_y, a_sign, r_y, r_sign, ak_digits, z_digits, chunk=128):
+def msm_accumulate_kernel(rows, chunk=128):
     """Device half of the batch check Σ [z_ik_i](−A_i) + Σ [z_i](−R_i):
     per-window point sums over the whole batch.
 
-    Host-facing shapes: a_y/r_y int[B, NLIMB] canonical y limbs; signs
-    int[B]; ak_digits int[B, 64] = 4-bit MSB-first digits of z_i·k_i mod L;
-    z_digits int[B, 32] = digits of the 128-bit z_i. Zero rows are inert
-    padding. Returns (V_a int32[4, NLIMB, 64], V_r int32[4, NLIMB, 32] —
-    X/Y/Z/T loose limbs per window lane — and valid bool[B]).
+    One operand up, one array down. rows uint8[B, ROW_BYTES] are the raw
+    bytes of the bucket (`expand_rows` has the layout and derives limbs,
+    signs and digits here, on the device); zero rows are inert padding.
+    Returns `msm_result`'s flat int32 array: V_a int32[4, NLIMB, 64], V_r
+    int32[4, NLIMB, 32] — X/Y/Z/T loose limbs per window lane — and
+    whether every row decompressed.
 
     The A and R points share one decompress + cached-table build
     (concatenated batch axis) but run SEPARATE window accumulates: the R
@@ -684,12 +750,11 @@ def msm_accumulate_kernel(a_y, a_sign, r_y, r_sign, ak_digits, z_digits, chunk=1
     the ~300 sequential width-1 point ops of that chain would cost ~500 ms
     as sub-tile device work, vs ~2 ms of host bigint on the tiny readback.
     """
-    ak_digits = ak_digits.astype(jnp.int32)
-    z_digits = z_digits.astype(jnp.int32)
-    B = a_y.shape[0]
+    a_y, a_sign, r_y, r_sign, ak_digits, z_digits = expand_rows(rows)
+    B = rows.shape[0]
 
-    ys = jnp.concatenate([a_y.T, r_y.T], axis=1).astype(jnp.int32)  # [NLIMB, 2B]
-    signs = jnp.concatenate([a_sign, r_sign]).astype(jnp.int32)
+    ys = jnp.concatenate([a_y.T, r_y.T], axis=1)  # [NLIMB, 2B]
+    signs = jnp.concatenate([a_sign, r_sign])
 
     points, valid = decompress(ys, signs)
     table = _pt_cached_table(pt_neg(points), 2 * B)
@@ -697,7 +762,7 @@ def msm_accumulate_kernel(a_y, a_sign, r_y, r_sign, ak_digits, z_digits, chunk=1
     table_r = tuple(t[..., B:] for t in table)
     v_a = _accumulate_windows(table_a, ak_digits, chunk)  # [NLIMB, 64] x4
     v_r = _accumulate_windows(table_r, z_digits, chunk)  # [NLIMB, 32] x4
-    return jnp.stack(v_a, axis=0), jnp.stack(v_r, axis=0), valid[:B] & valid[B:]
+    return msm_result(jnp.stack(v_a, axis=0), jnp.stack(v_r, axis=0), valid)
 
 
 def msm_field_muls_per_signature(batch: int, chunk: int = 128) -> float:

@@ -10,7 +10,13 @@ primary's certificate path and the worker's batch path call — the TPU-era
 - the SHA-512 challenge k = H(R || A || M) mod L (hashlib is C-speed; the
   device only sees 256-bit scalars as 4-bit window digits);
 - shape bucketing: pad each call to the next power-of-two batch so XLA
-  compiles a handful of programs, not one per batch size.
+  compiles a handful of programs, not one per batch size;
+- the device link: an msm dispatch is ONE buffer of raw rows up (uint8
+  [bucket, 112]: A | R | z*k mod L | z as the host holds them, zero rows
+  the padding) and ONE int32 array down (both window sums and the
+  all-rows-valid flag, ~30 KB). Limbs, sign bits, digits and padding —
+  whatever scales with the bucket and not with the useful rows — are the
+  kernel's (ed25519.expand_rows).
 
 A device dispatch that fails raises to the caller: nothing here answers
 from the host in the device's place. The detours that remain are the
@@ -20,16 +26,24 @@ whose solo device check fails is walked on the host) and each is counted
 in `TpuVerifier.counts`, so a run on all-valid input can assert that none
 fired — a kernel that miscompiled would otherwise be "corrected" quietly.
 
-What runs where. `submit` / `submit_groups` (numpy packing, the native
-precheck and fold with the GIL released, the jit dispatch) run on whatever
-thread calls them; under `VerifyService` that is the event loop that sealed
-the flush, which holds the interpreter anyway, so a flush wins it from
-nobody. `collect` / `collect_groups` block on the device and then run the
-host epilogue, `msm_epilogue_check` — with the native library the walk is
-`msm_epilogue_native` in native/scalar_ops.cpp and needs no interpreter;
-without a toolchain, and in the tests as the oracle, it runs on Python
-integers — on the service's one `verify-collect` thread.
-`counts["epilogue_native"]` / `["epilogue_python"]` say which ran.
+What runs where. `submit` / `submit_groups` (the native precheck and fold
+with the GIL released, the useful rows' raw bytes written into a staging
+buffer of the verifier's small pool, the jit dispatch of that one buffer,
+one `copy_to_host_async`) run on whatever thread calls them; under
+`VerifyService` that is the event loop that sealed the flush, which holds
+the interpreter anyway, so a flush wins it from nobody — and what it costs
+that loop no longer grows with the bucket. `collect` / `collect_groups`
+block on the device, take the one result with one `np.asarray`, hand the
+staging buffer back to the pool and run the host epilogue,
+`msm_epilogue_check`, on views of that array — with the native library the
+walk is `msm_epilogue_native` in native/scalar_ops.cpp and needs no
+interpreter; without a toolchain, and in the tests as the oracle, it runs
+on Python integers — on the service's one `verify-collect` thread.
+`counts["epilogue_native"]` / `["epilogue_python"]` say which ran;
+`counts["upload"]` / `["readback"]` (and `_bytes`) count the arrays an msm
+dispatch moved: one each. The per-item detour packs limbs and digits with
+numpy (`bytes_to_limbs`, `bytes_to_digits`) from the raw rows the handle
+keeps: invalid input only.
 
 Two async fronts batch concurrent requests with a size-or-deadline window,
 the BatchMaker pattern applied to crypto (SURVEY §7 "hard parts": offload
@@ -90,14 +104,15 @@ def _sharded_kernels(kernel, mesh, data_axis: str):
     on device between stages and donated forward. Per-lane arithmetic is
     identical to the monoliths, so verdicts are bit-equal.
 
-    Returns (item_fn, msm_fn) with the monoliths' host-facing signatures.
+    Returns (item_fn, msm_fn) with the monoliths' host-facing signatures:
+    msm_fn takes the bucket's raw rows and returns the one flat result.
     """
     from jax.sharding import PartitionSpec as P
 
     from . import kernel_registry
 
     b = P(data_axis)  # [B]
-    bn = P(data_axis, None)  # [B, NLIMB] / [B, W] host-layout rows
+    bn = P(data_axis, None)  # [B, NLIMB] / [B, W] / [B, ROW_BYTES] batch-leading rows
     cnb = P(None, None, data_axis)  # [4, NLIMB, B] coord stacks
 
     decompress = kernel_registry.sharded(
@@ -130,12 +145,24 @@ def _sharded_kernels(kernel, mesh, data_axis: str):
         acc = straus(a_pt, k_digits, s_digits)
         return verdict(acc, r_pt, r_y, r_sign, a_valid, r_valid)
 
-    def msm_fn(a_y, a_sign, r_y, r_sign, ak_digits, z_digits):
+    # The monolith's own expansion of raw rows and its one result array,
+    # each jitted on the data axis: written once, in ed25519.py.
+    expand = kernel_registry.sharded(
+        kernel.expand_rows, mesh,
+        in_specs=(bn,), out_specs=(bn, b, bn, b, bn, bn),
+    )
+    result = kernel_registry.sharded(
+        kernel.msm_result, mesh,
+        in_specs=(None, None, b), out_specs=None,
+    )
+
+    def msm_fn(rows):
+        a_y, a_sign, r_y, r_sign, ak_digits, z_digits = expand(rows)
         a_pt, a_valid = decompress(a_y, a_sign)
         r_pt, r_valid = decompress(r_y, r_sign)
         v_a = msm_window(a_pt, ak_digits)
         v_r = msm_window(r_pt, z_digits)
-        return v_a, v_r, a_valid & r_valid
+        return result(v_a, v_r, a_valid & r_valid)
 
     return item_fn, msm_fn
 
@@ -213,6 +240,12 @@ def msm_epilogue_check(
 # row passed the host prechecks).
 SubmitHandle = collections.namedtuple("SubmitHandle", "ok idx outs packed items padded")
 GroupsHandle = collections.namedtuple("GroupsHandle", "ok candidates outs groups padded")
+# One msm dispatch of either lane, until its collect: the device's one
+# result array, the host's sum of scalars for the epilogue, the staging
+# buffer the rows went up in and how many of its rows were written.
+MsmDispatch = collections.namedtuple("MsmDispatch", "out sum_s staged dirty")
+# Staging buffers kept per bucket size: the service's in-flight bound + 1.
+_STAGING_KEPT = 4
 
 
 class TpuVerifier:
@@ -262,6 +295,13 @@ class TpuVerifier:
         # hence the lock.
         self.counts: collections.Counter = collections.Counter()
         self._counts_lock = threading.Lock()
+        # Staging buffers of raw rows, uint8[bucket, ROW_BYTES], free for
+        # the next msm dispatch: bucket -> [(buffer, rows its last use
+        # wrote)]. Everything beyond those rows is zero. A buffer leaves
+        # at `submit` and comes back at `collect`, not before: the runtime
+        # may read the host memory until the program that takes it has run.
+        self._staging: dict[int, list] = {}
+        self._staging_lock = threading.Lock()
         # mesh: shard verify batches over the mesh's data axis (SURVEY
         # §7.8a's TpuVerifier service at §5.8 scale — the certificate
         # analog of `--dag-shards` for the commit walk). Items are
@@ -306,6 +346,46 @@ class TpuVerifier:
     def _count(self, key: str) -> None:
         with self._counts_lock:
             self.counts[key] += 1
+
+    def _count_transfer(self, direction: str, nbytes: int) -> None:
+        """One array crossed the device link on the msm path: `up` is
+        counted as `upload`, `down` as `readback`."""
+        key = "upload" if direction == "up" else "readback"
+        with self._counts_lock:
+            self.counts[key] += 1
+            self.counts[key + "_bytes"] += nbytes
+        SERVICE_TRANSFERS.labels(direction).inc()
+        SERVICE_BYTES.labels(direction).inc(nbytes)
+
+    def _stage(self, bucket: int, rows: int) -> np.ndarray:
+        """A staging buffer for `rows` useful rows of a `bucket`-row
+        dispatch: zero from `rows` on (only what its last use dirtied is
+        zeroed again); the caller writes every byte of the rows before."""
+        with self._staging_lock:
+            free = self._staging.get(bucket)
+            buf, dirty = free.pop() if free else (None, 0)
+        if buf is None:
+            return np.zeros((bucket, self.kernel.ROW_BYTES), np.uint8)
+        if dirty > rows:
+            buf[rows:dirty] = 0
+        return buf
+
+    def _unstage(self, dispatched: "MsmDispatch") -> None:
+        """The dispatch was collected: its buffer may be written again."""
+        buf = dispatched.staged
+        with self._staging_lock:
+            free = self._staging.setdefault(buf.shape[0], [])
+            if len(free) < _STAGING_KEPT:
+                free.append((buf, dispatched.dirty))
+
+    def _run_msm(self, staged: np.ndarray, dirty: int, sum_s: int) -> "MsmDispatch":
+        """Hand one staged bucket to the device: one array up, and the
+        copy of the one result back started as soon as the program ends,
+        so `collect` finds the bytes already local."""
+        out = self._msm_kernel(staged)
+        out.copy_to_host_async()
+        self._count_transfer("up", staged.nbytes)
+        return MsmDispatch(out, sum_s, staged, dirty)
 
     def precompile(self, sizes: Sequence[int] = ()) -> None:
         """Warm the jit trace+compile caches for the given bucket sizes —
@@ -405,11 +485,14 @@ class TpuVerifier:
         return precheck, a_raw, r_raw, s_raw, k_raw
 
     def submit(self, items: Sequence[BatchItem]):
-        """Pack + precheck on host and enqueue the device dispatch(es).
-        Returns an opaque handle for `collect` — dispatch is asynchronous, so
-        several submitted batches stay in flight and the device readback
-        latency overlaps the next batch's host packing and compute. The
-        handle's `padded` is the rows handed to the device, padding included.
+        """Precheck on host, stage the raw rows and enqueue the device
+        dispatch(es). Returns an opaque handle for `collect` — dispatch is
+        asynchronous, so several submitted batches stay in flight and the
+        device readback latency overlaps the next batch's host work and
+        compute. The handle's `padded` is the rows handed to the device,
+        padding included. An msm dispatch writes only its useful rows (112
+        raw bytes each) into a staging buffer that stays its own until
+        `collect`; nothing here runs over the padded bucket.
 
         The per-item host work (SHA-512 challenge, canonicality checks,
         msm scalars) runs in native/scalar_ops.cpp when available — the
@@ -429,22 +512,12 @@ class TpuVerifier:
         if idx.size == 0:
             return SubmitHandle(ok, idx, [], None, items, 0)
 
-        # Compact to precheck-passing rows (contiguous for the C fold and
-        # the device upload).
-        a_raw = np.ascontiguousarray(a_all[idx])
-        r_raw = np.ascontiguousarray(r_all[idx])
-        s_raw = np.ascontiguousarray(s_all[idx])
-        k_raw = np.ascontiguousarray(k_all[idx])
-        # Narrow upload dtypes (limbs < 2^13, digits < 16): ~3x fewer bytes
-        # over the device link; the kernel widens to int32 lanes on device.
-        a_y = self.kernel.bytes_to_limbs(a_raw).astype(np.int16)
-        r_y = self.kernel.bytes_to_limbs(r_raw).astype(np.int16)
-        a_sign = (a_raw[:, 31] >> 7).astype(np.int8)
-        r_sign = (r_raw[:, 31] >> 7).astype(np.int8)
-        # k/s digit planes are only needed by the per-item kernel — in msm
-        # mode that's the rare fallback path, so they're derived lazily in
-        # _dispatch_items instead of packed (and uploaded) eagerly.
-        packed = (a_y, a_sign, r_y, r_sign, k_raw, s_raw)
+        # Compact to precheck-passing rows (contiguous for the C fold).
+        # The raw bytes are all the handle keeps: an msm dispatch writes
+        # them into its staging buffer as they are, and the limbs and
+        # digits the per-item kernel takes are derived in _dispatch_items,
+        # which in msm mode is the rare detour.
+        packed = tuple(np.ascontiguousarray(x[idx]) for x in (a_all, r_all, k_all, s_all))
 
         outs = []  # (kind, lo, hi, pad, device out)
         for lo in range(0, idx.size, self.max_bucket):
@@ -460,24 +533,24 @@ class TpuVerifier:
             if self.mode == "msm" and bucket >= self.msm_min_bucket:
                 out = self._dispatch_msm(packed, lo, hi, pad)
                 kind = "msm"
-                arrays = out[0]  # ((V_a, V_r, valid), sum_s)
             else:
                 out = self._dispatch_items(packed, lo, hi, pad)
                 kind = "item"
-                arrays = out  # (strict, cofactored) device arrays
-            # Kick off the device->host copy as soon as the kernel finishes
-            # so collect() finds the bytes already local instead of paying
-            # the transfer round trip synchronously.
-            for arr in arrays:
-                arr.copy_to_host_async()
+                # Kick off the device->host copy as soon as the kernel
+                # finishes so collect() finds the bytes already local.
+                for arr in out:  # (strict, cofactored) device arrays
+                    arr.copy_to_host_async()
             outs.append((kind, lo, hi, pad, out))
         padded = sum(hi - lo + pad for _, lo, hi, pad, _ in outs)
         return SubmitHandle(ok, idx, outs, packed, items, padded)
 
     def _dispatch_items(self, packed, lo, hi, pad):
-        """Per-item Straus kernel over one padded bucket (k/s scalar rows
-        are expanded to 4-bit digit planes here, on demand)."""
-        a_y, a_sign, r_y, r_sign, k_raw, s_raw = packed
+        """Per-item Straus kernel over one padded bucket. Its operands —
+        13-bit limbs, sign bits, 4-bit digit planes, narrow dtypes the
+        kernel widens on the device — are derived here, on demand, from
+        the raw rows the handle holds: numpy over the whole bucket, which
+        in msm mode only invalid input pays."""
+        a_raw, r_raw, k_raw, s_raw = packed
 
         def pad_to(arr):
             if pad == 0:
@@ -486,12 +559,17 @@ class TpuVerifier:
                 [arr[lo:hi], np.repeat(arr[lo : lo + 1], pad, axis=0)]
             )
 
+        def point(raw):
+            return (
+                self.kernel.bytes_to_limbs(raw).astype(np.int16),
+                (raw[:, 31] >> 7).astype(np.int8),
+            )
+
         k_digits = self.kernel.bytes_to_digits(pad_to(k_raw)).astype(np.int8)
         s_digits = self.kernel.bytes_to_digits(pad_to(s_raw)).astype(np.int8)
         self._count("item_dispatch")
         return self._item_kernel(
-            pad_to(a_y), pad_to(a_sign), pad_to(r_y), pad_to(r_sign),
-            k_digits, s_digits,
+            *point(pad_to(a_raw)), *point(pad_to(r_raw)), k_digits, s_digits
         )
 
     def _fold_native(self, lib, k_rows: np.ndarray, s_rows: np.ndarray, rnd: bytes):
@@ -529,12 +607,12 @@ class TpuVerifier:
     def _dispatch_msm(self, packed, lo, hi, pad):
         """Random-linear-combination check over one bucket. Fresh 128-bit
         z_i per item per call (os.urandom — the adversary must not predict
-        them); zero rows are inert padding. Returns (device (V, valid),
-        sum_s) — the Horner/identity epilogue runs on host at collect
-        time."""
+        them); zero rows are inert padding. The rows go up as raw bytes
+        in one staging buffer (ed25519.expand_rows has the layout); the
+        Horner/identity epilogue runs on host at collect time."""
         import os as _os
 
-        a_y, a_sign, r_y, r_sign, k_raw, s_raw = packed
+        a_raw, r_raw, k_raw, s_raw = packed
         m = hi - lo
         # RLC folding weights must be unpredictable to an adversary who
         # crafts signatures (a seeded stream would let forged batches pass
@@ -542,35 +620,19 @@ class TpuVerifier:
         # fold bisects deterministically — so replays stay bit-identical
         # where it matters.
         rnd = _os.urandom(16 * m)  # lint: allow(raw-entropy)
-        k_rows = np.ascontiguousarray(k_raw[lo:hi])
-        s_rows = np.ascontiguousarray(s_raw[lo:hi])
+        k_rows, s_rows = k_raw[lo:hi], s_raw[lo:hi]  # row slices: contiguous
         lib = _scalar_lib()
         if lib is not None:
             ak_raw, sum_s = self._fold_native(lib, k_rows, s_rows, rnd)
         else:
             ak_raw, sum_s = self._fold_py(k_rows, s_rows, rnd)
-        if pad:
-            ak_raw = np.concatenate([ak_raw, np.zeros((pad, 32), np.uint8)])
-        z_raw = np.zeros((m + pad, 32), np.uint8)
-        z_raw[:m, :16] = np.frombuffer(rnd, np.uint8).reshape(m, 16)
-
-        ak_digits = self.kernel.bytes_to_digits(ak_raw).astype(np.int8)
-        # z < 2^128: the MSB-first digit vector's low half carries it.
-        z_digits = self.kernel.bytes_to_digits(z_raw)[:, 32:].astype(np.int8)
-
-        def zpad(arr):
-            if pad == 0:
-                return arr[lo:hi]
-            return np.concatenate(
-                [arr[lo:hi], np.zeros((pad,) + arr.shape[1:], arr.dtype)]
-            )
-
+        staged = self._stage(m + pad, m)
+        staged[:m, 0:32] = a_raw[lo:hi]
+        staged[:m, 32:64] = r_raw[lo:hi]
+        staged[:m, 64:96] = ak_raw
+        staged[:m, 96:112] = np.frombuffer(rnd, np.uint8).reshape(m, 16)
         self._count("msm_dispatch")
-        out = self._msm_kernel(
-            zpad(a_y), zpad(a_sign), zpad(r_y), zpad(r_sign),
-            ak_digits, z_digits,
-        )
-        return (out, sum_s)
+        return self._run_msm(staged, m, sum_s)
 
     def submit_groups(self, groups):
         """Dispatch half-aggregated certificate proofs (types.Certificate
@@ -608,13 +670,13 @@ class TpuVerifier:
             chunk = candidates[lo:hi]
             lo = hi
             outs.append((chunk, self._dispatch_group_chunk(chunk, rows)))
-        padded = sum(d[2] for _, d in outs if d is not None)
+        padded = sum(d.staged.shape[0] for _, d in outs if d is not None)
         return GroupsHandle(ok, candidates, outs, groups, padded)
 
     def _dispatch_group_chunk(self, chunk, rows):
-        """One msm dispatch over the doubled rows of `chunk`'s groups.
-        Returns ((device out), sum_s, bucket): _dispatch_msm's pair and the
-        rows the dispatch was padded to."""
+        """One msm dispatch over the doubled rows of `chunk`'s groups, as
+        `_dispatch_msm` makes it (None where an item fails the prechecks);
+        the staging buffer's row count is what it was padded to."""
         L = self.kernel.ref.L
         lib = _scalar_lib()
         sum_s = 0
@@ -672,54 +734,42 @@ class TpuVerifier:
                 )
 
         # Doubled rows: even = A_i with scalar ak_i, odd = R_i (through the
-        # A slot) with scalar y_i.
-        a_rows = np.zeros((rows, 32), np.uint8)
-        ak_rows = np.zeros((rows, 32), np.uint8)
-        a_rows[0::2] = a_all[:m]
-        a_rows[1::2] = r_all[:m]
-        ak_rows[0::2] = ak_items
-        ak_rows[1::2] = y_rows
+        # A slot) with scalar y_i; the R and z columns stay zero.
         bucket = self.max_bucket if self.fixed_bucket else _next_pow2(rows)
-        pad = bucket - rows
-        if pad:
-            a_rows = np.concatenate([a_rows, np.zeros((pad, 32), np.uint8)])
-            ak_rows = np.concatenate([ak_rows, np.zeros((pad, 32), np.uint8)])
-        a_y = self.kernel.bytes_to_limbs(a_rows).astype(np.int16)
-        a_sign = (a_rows[:, 31] >> 7).astype(np.int8)
-        zero_y = np.zeros_like(a_y)
-        zero_sign = np.zeros_like(a_sign)
-        ak_digits = self.kernel.bytes_to_digits(ak_rows).astype(np.int8)
-        z_digits = np.zeros((bucket, 32), np.int8)
+        staged = self._stage(bucket, rows)
+        staged[:rows] = 0
+        staged[0:rows:2, 0:32] = a_all[:m]
+        staged[1:rows:2, 0:32] = r_all[:m]
+        staged[0:rows:2, 64:96] = ak_items
+        staged[1:rows:2, 64:96] = y_rows
         self._count("group_dispatch")
-        out = self._msm_kernel(
-            a_y, a_sign, zero_y, zero_sign, ak_digits, z_digits
-        )
-        for arr in out:
-            arr.copy_to_host_async()
-        return (out, sum_s, bucket)
+        return self._run_msm(staged, rows, sum_s)
 
     def _batch_passes(self, out, sum_s: int) -> bool:
-        """Force one msm dispatch: the device's validity lanes, then the
-        host epilogue identity, native where the scalar library is loaded
-        (counted `epilogue_native`) and on Python integers where it is not
-        (`epilogue_python`)."""
-        va_dev, vr_dev, valid_dev = out
-        if not bool(np.asarray(valid_dev).all()):
+        """Force one msm dispatch's one result array (blocks on the
+        device): the all-rows-valid flag, then the host epilogue identity
+        on views of the same array, native where the scalar library is
+        loaded (counted `epilogue_native`) and on Python integers where it
+        is not (`epilogue_python`)."""
+        flat = np.asarray(out)
+        self._count_transfer("down", flat.nbytes)
+        va, vr, valid = self.kernel.split_msm_result(flat)
+        if not valid:
             return False
         lib = _scalar_lib()
         event = "epilogue_native" if lib is not None else "epilogue_python"
         self._count(event)
         SERVICE_EVENTS.labels(event).inc()
-        return msm_epilogue_check(
-            np.asarray(va_dev), np.asarray(vr_dev), sum_s, self.kernel, lib
-        )
+        return msm_epilogue_check(va, vr, sum_s, self.kernel, lib)
 
-    def _chunk_passes(self, dispatched) -> bool:
-        """Force one `_dispatch_group_chunk` result."""
+    def _dispatch_passes(self, dispatched) -> bool:
+        """Force one msm dispatch of either lane (None: a group chunk that
+        never left) and hand its staging buffer back to the pool."""
         if dispatched is None:
             return False
-        out, sum_s, _ = dispatched
-        return self._batch_passes(out, sum_s)
+        passed = self._batch_passes(dispatched.out, dispatched.sum_s)
+        self._unstage(dispatched)
+        return passed
 
     def collect_groups(self, handle) -> list[bool]:
         """Resolve a `submit_groups` handle. A failed combined check
@@ -737,7 +787,7 @@ class TpuVerifier:
 
         ok, candidates, outs, groups, _ = handle
         for chunk, dispatched in outs:
-            if self._chunk_passes(dispatched):
+            if self._dispatch_passes(dispatched):
                 for g, *_ in chunk:
                     ok[g] = True
                 continue
@@ -755,7 +805,7 @@ class TpuVerifier:
             else:
                 solos = [(chunk[0], dispatched)]
             for (g, items, zs, s_agg, _), disp in solos:
-                if len(chunk) > 1 and self._chunk_passes(disp):
+                if len(chunk) > 1 and self._dispatch_passes(disp):
                     ok[g] = True
                 else:
                     # The group's own device check failed: almost surely
@@ -792,7 +842,7 @@ class TpuVerifier:
                 if kind == "item":
                     results[lo:hi] = np.asarray(out[pick])[: hi - lo]
                     continue
-                if self._batch_passes(*out):
+                if self._dispatch_passes(out):
                     results[lo:hi] = True
                 else:
                     logger.warning(
@@ -832,6 +882,21 @@ SERVICE_ROWS = Counter(
     "(kind=useful: signatures, and 2 per signer of a certificate proof; "
     "kind=padded: the buckets dispatched)",
     ("lane", "kind"),
+)
+SERVICE_TRANSFERS = Counter(
+    "verify_service_transfers_total",
+    "Arrays the device verifier's msm dispatches moved over the device "
+    "link (dir=up: operands handed to the kernel; dir=down: results read "
+    "back): one each per flush. A detour's per-item dispatch is counted "
+    "as the detour it is, not here",
+    ("dir",),
+)
+SERVICE_BYTES = Counter(
+    "verify_service_bytes_total",
+    "Bytes the device verifier's msm dispatches moved over the device link "
+    "(dir=up: bucket rows x 112 raw bytes; dir=down: the window sums and "
+    "the all-rows-valid flag)",
+    ("dir",),
 )
 SERVICE_WAIT = Histogram(
     "verify_service_wait_seconds",
@@ -875,17 +940,20 @@ class VerifyService:
                   bucket seals at once, inside the enqueue);
       the seal    runs on that loop's thread, which holds the interpreter
                   already: takes both lanes (entries of every loop), runs
-                  TpuVerifier.submit / submit_groups inline — numpy
-                  packing, two GIL-free native calls, the jit dispatch;
-                  3.9 ms at the median on the v5e's host, PERF.md §5 —
-                  and hands the handle to the collect thread.
+                  TpuVerifier.submit / submit_groups inline — two
+                  GIL-free native calls, the useful rows' raw bytes
+                  into a staging buffer, the jit dispatch of that one
+                  buffer; 0.7 ms at the median on the v5e's host,
+                  PERF.md §5 — and hands the handle to the collect
+                  thread.
                   It never blocks: with every in-flight slot taken it
                   leaves its entries queued (`flushes["deferred"]`) and
                   the next completion arms it again, on the loop of the
                   oldest queued entry;
-      collect thread blocks on the device result, runs the native
-                  epilogue (no interpreter needed) and resolves a flush's
-                  futures with one `call_soon_threadsafe` per loop.
+      collect thread blocks on the device result (one array), hands
+                  the staging buffer back, runs the native epilogue (no
+                  interpreter needed) and resolves a flush's futures
+                  with one `call_soon_threadsafe` per loop.
     While one callback holds a loop nothing seals there; no waiter could
     resume before that loop turns either, so only the overlap of device
     work with the stall is lost. Presents the AsyncVerifierPool interface

@@ -137,3 +137,42 @@ def test_the_micro_timing_leaves_the_ring_as_it_was():
                         "mark_outside_session_us"} and all(v > 0 for v in out.values())
     assert list(tracing.FLIGHT) == [("ingest_first", "worker-0", 1.0)]
     tracing.new_generation()
+
+
+def test_the_split_of_a_submit_by_hand():
+    rows = [
+        {"lane": "singles", "total": 0.004, "precheck": 0.001, "fold": 0.0002, "jit_call": 0.002, "readback_start": 0.0003},
+        {"lane": "singles", "total": 0.006, "precheck": 0.001, "fold": 0.0002, "jit_call": 0.003, "readback_start": 0.0003},
+        {"lane": "singles", "total": 30.0, "precheck": 0.2, "jit_call": 29.0},  # the set-up's bucket: far from the median
+        {"lane": "groups", "total": 0.005, "precheck": 0.002, "jit_call": 0.002},  # no fold on this lane
+    ]
+    got = fp.split_report(rows)
+    assert got["singles"] == pytest.approx(
+        {"flushes": 3, "total": 6.0, "precheck": 1.0, "fold": 0.2, "jit_call": 3.0, "readback_start": 0.3, "rest": 1.5})
+    assert got["groups"] == pytest.approx(
+        {"flushes": 1, "total": 5.0, "precheck": 2.0, "fold": 0.0, "jit_call": 2.0, "readback_start": 0.0, "rest": 1.0})
+
+
+def test_the_split_times_every_part_of_a_flush_on_either_lane(monkeypatch):
+    """On the plain kernels (tests/plain_kernels.py): the wrapped names are
+    the verifier's own, so a renamed method fails here and not on the chip."""
+    import jax.numpy as jnp
+
+    from narwhal_tpu.tpu.verifier import TpuVerifier
+    from tests import test_verify_rows as rows_test
+
+    if rows_test.verifier_mod._scalar_lib() is None:
+        pytest.skip("native toolchain unavailable")
+    for owner, name in ((TpuVerifier, "_precheck_native"), (TpuVerifier, "_fold_native"), (TpuVerifier, "submit"),
+                        (TpuVerifier, "submit_groups"), (type(jnp.zeros(())), "copy_to_host_async")):
+        monkeypatch.setattr(owner, name, getattr(owner, name))  # put back when the test ends
+    rows = fp.install_split()
+    v = rows_test.plain_verifier()
+    assert v(rows_test.signatures(3)) == [True] * 3
+    assert v.collect_groups(v.submit_groups(rows_test.certificate_groups(1))) == [True]
+    assert [r["lane"] for r in rows] == ["singles", "groups"]
+    assert set(rows[0]) == {"lane", "total", "precheck", "fold", "jit_call"}  # a host array starts no readback
+    assert set(rows[1]) == {"lane", "total", "precheck", "jit_call"}
+    for r in rows:
+        assert 0 < r["precheck"] + r["jit_call"] <= r["total"]
+    assert set(fp.split_report(rows)) == {"singles", "groups"}

@@ -144,17 +144,25 @@ def test_native_epilogue_agrees_with_the_python_twin(lib, case, monkeypatch):
         assert va.min() < 0 and (case == "loose_signed" or (va.max() >= 2**31 - 8192 and va.min() < -(2**31) + 8192))
     assert msm_epilogue_check(va, vr, sum_s, k) is accepted
     assert msm_epilogue_check(va, vr, sum_s, k, lib) is accepted
-    # Through the verifier: the native twin runs and is counted; with no
-    # library, the Python one, with the same verdict. A lane the device
-    # marked invalid fails the bucket before either.
+    # Through the verifier, as the device hands a bucket back: one flat
+    # array of both window sums and the all-rows-valid flag. The native
+    # twin runs and is counted; with no library, the Python one, with the
+    # same verdict. A row the device marked invalid fails the bucket
+    # before either. Every call is one readback.
+    def result(valid: bool) -> np.ndarray:
+        return np.concatenate([va.reshape(-1), vr.reshape(-1), [int(valid)]]).astype(np.int32)
+
+    assert result(True).shape == (k.MSM_RESULT_SIZE,)
     v = TpuVerifier(max_bucket=16)
-    assert v._batch_passes((va, vr, np.ones(4, bool)), sum_s) is accepted
+    assert v._batch_passes(result(True), sum_s) is accepted
     assert (v.counts["epilogue_native"], v.counts["epilogue_python"]) == (1, 0)
     monkeypatch.setattr(verifier_mod, "_scalar_lib", lambda: None)
-    assert v._batch_passes((va, vr, np.ones(4, bool)), sum_s) is accepted
+    assert v._batch_passes(result(True), sum_s) is accepted
     assert (v.counts["epilogue_native"], v.counts["epilogue_python"]) == (1, 1)
-    assert v._batch_passes((va, vr, np.array([True, False, True, True])), sum_s) is False
-    assert sum(v.counts.values()) == 2
+    assert v._batch_passes(result(False), sum_s) is False
+    assert (v.counts["epilogue_native"], v.counts["epilogue_python"]) == (1, 1)
+    assert (v.counts["readback"], v.counts["readback_bytes"]) == (3, 3 * 4 * k.MSM_RESULT_SIZE)
+    assert v.counts["upload"] == 0
 
 
 def test_window_counts_the_native_twin_takes(lib):

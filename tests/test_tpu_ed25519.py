@@ -524,14 +524,15 @@ def test_staged_kernels_match_monolith():
     assert np.asarray(mono_strict).sum() > 0  # batch had valid rows
     assert not np.asarray(mono_strict).all()  # ... and invalid ones
 
-    ak_digits = rng.integers(0, 16, (16, 64)).astype(np.int8)
-    z_digits = rng.integers(0, 16, (16, 32)).astype(np.int8)
-    mono_va, mono_vr, mono_valid = k.msm_accumulate_kernel(
-        a_y, a_sign, r_y, r_sign, ak_digits, z_digits
+    # The msm pair takes the bucket's raw rows (A | R | ak | z) and returns
+    # one flat array: window sums of both accumulators and the valid flag.
+    rows = np.concatenate(
+        [a_all, r_all, rng.integers(0, 256, (16, 48), dtype=np.uint8)], axis=1
     )
-    st_va, st_vr, st_valid = msm_fn(
-        a_y, a_sign, r_y, r_sign, ak_digits, z_digits
-    )
-    assert np.array_equal(np.asarray(mono_va), np.asarray(st_va))
-    assert np.array_equal(np.asarray(mono_vr), np.asarray(st_vr))
-    assert np.array_equal(np.asarray(mono_valid), np.asarray(st_valid))
+    assert rows.shape == (16, k.ROW_BYTES)
+    mono = np.asarray(k.msm_accumulate_kernel(rows))
+    staged = np.asarray(msm_fn(rows))
+    assert mono.shape == (k.MSM_RESULT_SIZE,) and mono.dtype == np.int32
+    assert np.array_equal(mono, staged)
+    va, vr, valid = k.split_msm_result(mono)
+    assert va.shape == (4, k.NLIMB, 64) and vr.shape == (4, k.NLIMB, 32) and valid

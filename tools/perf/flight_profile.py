@@ -2,6 +2,7 @@
 
     python3 -m tools.perf.flight_profile --xplane <dir or .xplane.pb> --flight <dump.json>
     python3 -m tools.perf.flight_profile --drive 8        # on the chip, through chipbench's set-up
+    python3 -m tools.perf.flight_profile --drive 8 --split  # and what a flush's `seal -> dispatched` is made of
     python3 -m tools.perf.flight_profile --micro          # what one record, add or mark costs here
 
 What reads the program's four profiler marks (`narwhal/verify_submit`,
@@ -21,7 +22,14 @@ tracing.annotation) and the fields of the ring no benchmark metric reads
 * the longest idle gaps of the device, each put down to `starved` (both
   verify lanes empty, nothing in flight), `held` (an entry queued or being
   packed) or `in flight`, with the marks and the late heartbeats over it;
-* the verifier stage's hops by message kind and outcome.
+* the verifier stage's hops by message kind and outcome;
+* with `--split`, what `TpuVerifier.submit` / `submit_groups` spend where, per
+  lane, on the host's clock: the native precheck (SHA-512, canonicality), the
+  native fold, the jit call (operands handed over and the program enqueued),
+  the readback starts, and the rest (Python and numpy: staging the rows). The
+  parts are timed by wrapping names a tree before and after ISSUE 30 both has,
+  so `PYTHONPATH=<other checkout> python3 <this file> --drive 8 --split`
+  splits that checkout's flush with this file.
 
 A mark around an `await` (`commit_walk`, `execute`) is as wide as its
 coroutine's wall time, other tasks' turns included; what held the loop is the
@@ -219,6 +227,64 @@ def report(dump: dict, profile: Profile) -> dict:
     }
 
 
+SPLIT_PARTS = ("precheck", "fold", "jit_call", "readback_start")
+
+
+def install_split() -> list[dict]:
+    """Time the parts of every `TpuVerifier.submit` / `submit_groups` from
+    now on; returns the list that gets one row per call: lane, `total` and
+    each of SPLIT_PARTS in seconds (`perf_counter`). A wrapper costs two
+    clock reads; five to seven a flush."""
+    import jax.numpy as jnp
+
+    from narwhal_tpu.tpu.verifier import TpuVerifier
+
+    rows: list[dict] = []
+    parts: collections.Counter = collections.Counter()
+
+    def timed(part, fn):
+        def wrapper(*args, **kwargs):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                parts[part] += time.perf_counter() - t0
+        return wrapper
+
+    def whole(lane, fn):
+        def wrapper(self, work):
+            if not getattr(self._msm_kernel, "split_timed", False):
+                self._msm_kernel = timed("jit_call", self._msm_kernel)
+                self._msm_kernel.split_timed = True
+            parts.clear()
+            t0 = time.perf_counter()
+            try:
+                return fn(self, work)
+            finally:
+                rows.append({"lane": lane, "total": time.perf_counter() - t0, **parts})
+        return wrapper
+
+    TpuVerifier._precheck_native = timed("precheck", TpuVerifier._precheck_native)
+    TpuVerifier._fold_native = timed("fold", TpuVerifier._fold_native)
+    array = type(jnp.zeros(()))
+    array.copy_to_host_async = timed("readback_start", array.copy_to_host_async)
+    TpuVerifier.submit = whole("singles", TpuVerifier.submit)
+    TpuVerifier.submit_groups = whole("groups", TpuVerifier.submit_groups)
+    return rows
+
+
+def split_report(rows: list[dict]) -> dict:
+    """Medians in ms per lane; `rest` is what no wrapped part covers. The
+    set-up's one full bucket is among the rows and far from any median."""
+    out = {}
+    for lane in sorted({r["lane"] for r in rows}):
+        mine = [r for r in rows if r["lane"] == lane]
+        med = {part: 1e3 * statistics.median(r.get(part, 0.0) for r in mine) for part in ("total", *SPLIT_PARTS)}
+        med["rest"] = 1e3 * statistics.median(r["total"] - sum(r.get(p, 0.0) for p in SPLIT_PARTS) for r in mine)
+        out[lane] = {"flushes": len(mine), **med}
+    return out
+
+
 def drive(seconds: float, slice_s: float) -> tuple[dict, str]:
     """A few seconds of `local-4x1.cruise` through chipbench's own set-up,
     with a profiler slice of `slice_s` that is kept: (the ring's dump, the
@@ -264,13 +330,17 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--slice", type=float, default=1.0, help="seconds of profile kept by --drive")
     ap.add_argument("--platform", default="TPU")
     ap.add_argument("--micro", action="store_true", help="time the recorder's own calls on this host")
+    ap.add_argument("--split", action="store_true", help="with --drive: split submit's host time into its parts")
     args = ap.parse_args(argv)
     if args.micro:
         print(json.dumps({"micro": micro()}), flush=True)
         if not (args.drive or args.xplane):
             return 0
     if args.drive:
+        split = install_split() if args.split else None
         dump, xplane = drive(args.drive, args.slice)
+        if split is not None:
+            print(json.dumps({"submit_split_ms": split_report(split)}), flush=True)
     elif args.xplane and args.flight:
         with open(args.flight) as f:
             dump, xplane = json.load(f), args.xplane
