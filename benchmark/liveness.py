@@ -3,11 +3,10 @@ progress over time, and account the control-plane wire cost per round.
 
 Two transports:
 
-* **Real sockets** (default) — the loopback-TCP mode behind
-  `benchmark/results/n50_liveness.json`. A committee's vote mesh costs
+* **Real sockets** (default) — loopback TCP. A committee's vote mesh costs
   ~2·N·(N-1) in-process fds, which hard-caps this mode near N=90 under the
-  container's RLIMIT_NOFILE (the `n100_liveness.json` EMFILE failure); a
-  preflight now fails fast with the arithmetic instead of dying mid-run.
+  container's RLIMIT_NOFILE (an N=100 run died of EMFILE mid-run); a
+  preflight fails fast with the arithmetic instead.
 * **simnet** (`--simnet`) — the virtual-clock in-memory fabric
   (narwhal_tpu/simnet): zero sockets, zero fds on the mesh, hundreds of
   nodes in one process, `--duration` measured in *virtual* seconds (wall
@@ -15,7 +14,7 @@ Two transports:
 
     python -m benchmark.liveness --nodes 50 --duration 240
     python -m benchmark.liveness --nodes 200 --simnet --duration 10 \
-        --out benchmark/results/simnet_n200_liveness.json
+        --out .bench/simnet_n200_liveness.json
 
 No injected load: at these committee sizes each round is thousands of
 signed control messages, so the assertion is liveness (lockstep commits
@@ -70,8 +69,7 @@ def estimate_required_fds(nodes: int, workers: int, pooled: bool = True) -> int:
 def preflight_fd_check(
     nodes: int, workers: int, pooled: bool | None = None
 ) -> None:
-    """Fail fast (and actionably) instead of mid-run EMFILE — the
-    r9 n100_liveness.json failure mode."""
+    """Fail fast (and actionably) instead of mid-run EMFILE."""
     if pooled is None:
         pooled = pooling_enabled()
     soft, _hard = resource.getrlimit(resource.RLIMIT_NOFILE)
@@ -437,12 +435,6 @@ def main() -> None:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(record, f, indent=1)
-    from tools.perf import ledger as perf_ledger
-
-    perf_ledger.append(
-        "liveness", record,
-        scrape=record.get("telemetry_scrape"), argv=sys.argv[1:],
-    )
 
 
 if __name__ == "__main__":

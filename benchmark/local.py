@@ -279,9 +279,8 @@ class LocalBench:
             raise ConfigError(
                 f"{primaries} primary processes with a tpu backend need a "
                 f"device each, and this host has {devices}: one process owns "
-                "a chip. Run the committee in one process instead — "
-                "python -m benchmark.inprocess --crypto-backend tpu "
-                "--dag-backend tpu"
+                "a chip. Run the committee in one process instead "
+                "(narwhal_tpu.cluster.Cluster; python3 -m chipbench)"
             )
 
     def run(self, debug: bool = False) -> LogParser:

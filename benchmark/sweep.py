@@ -226,11 +226,6 @@ def main() -> None:
         json.dump(results, f, indent=2)
     print(f"\nwrote {len(results)} records to {args.out}\n")
     print(render_table(results))
-    import sys
-
-    from tools.perf import ledger as perf_ledger
-
-    perf_ledger.append("sweep", results, argv=sys.argv[1:])
 
 
 if __name__ == "__main__":

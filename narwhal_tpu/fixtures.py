@@ -3,9 +3,9 @@
 Reference: /root/reference/test_utils/src/lib.rs — CommitteeFixture :602-793,
 synthetic DAG generators make_optimal_certificates / make_certificates(...,
 failure_probability) / make_signed_certificates / mock_certificate :397-599.
-Lives in the package (not tests/) because the benchmark harness and bench.py
-also build committees from it, like the reference's test_utils crate being a
-workspace member.
+Lives in the package (not tests/) because the benchmark launcher, chipbench
+and chip_smoke.py also build committees from it, like the reference's
+test_utils crate being a workspace member.
 """
 
 from __future__ import annotations

@@ -30,8 +30,8 @@ Three pieces:
   by `max_checks` re-runs so shrinking a flaky failure terminates.
 
 `run_campaign` drives N seeds, shrinks every failure, and returns one
-JSON-able payload; the CLI (`bench.py --fuzz`) appends it to the perf
-ledger as one `fuzz` record per campaign.
+JSON-able payload; the CLI (`python -m narwhal_tpu.simnet.fuzz`) prints
+its counts and writes it to `--out`.
 """
 
 from __future__ import annotations
@@ -314,7 +314,7 @@ def run_campaign(
     progress=None,
 ) -> dict:
     """Explore `count` seeded plans; shrink every failure. Returns the
-    campaign payload (one perf-ledger `fuzz` record)."""
+    campaign payload."""
     t0 = time.monotonic()
     scenarios: list[dict] = []
     failures: list[dict] = []
@@ -368,7 +368,6 @@ def run_campaign(
 def main(argv=None) -> int:
     import argparse
     import json
-    import sys
 
     parser = argparse.ArgumentParser(
         description="Seeded FaultPlan fuzzer under the simnet oracles"
@@ -415,12 +414,6 @@ def main(argv=None) -> int:
             json.dump(campaign, fh, indent=1, sort_keys=True)
             fh.write("\n")
         print(f"wrote {args.out}")
-    try:
-        from tools.perf import ledger as perf_ledger
-
-        perf_ledger.append("fuzz", campaign, argv=sys.argv[1:])
-    except ImportError:
-        pass  # running outside the repo tree: the --out artifact stands
     return 0 if campaign["ok"] else 1
 
 

@@ -784,7 +784,7 @@ def msm_field_muls_per_signature(batch: int, chunk: int = 128) -> float:
         amortized over the bucket.
 
     The host Horner epilogue is not counted (it overlaps device compute in
-    the pipelined flow and is measured separately by bench.py)."""
+    the pipelined flow)."""
     sq = 210.0 / 400.0
     decompress = 2 * ((251 + 4) * sq + 21)
     table = 2 * (14 * 8 + 15)

@@ -266,19 +266,17 @@ class TpuVerifier:
     def __init__(
         self,
         max_bucket: int = _MAX_BUCKET,
-        mode: str | None = None,
+        mode: str = "msm",
         msm_min_bucket: int = 512,
         fixed_bucket: bool = False,
         mesh=None,
         data_axis: str = "data",
     ):
-        import os
-
         from . import ed25519 as kernel  # deferred: imports jax
 
         self.kernel = kernel
         self.max_bucket = max_bucket
-        self.mode = mode or os.environ.get("NARWHAL_TPU_VERIFY_MODE", "msm")
+        self.mode = mode
         # Small buckets stay on the per-item kernel: they're the latency
         # path, the msm advantage is amortization, and each extra bucket
         # shape costs a multi-minute first compile.
@@ -386,27 +384,6 @@ class TpuVerifier:
         out.copy_to_host_async()
         self._count_transfer("up", staged.nbytes)
         return MsmDispatch(out, sum_s, staged, dirty)
-
-    def precompile(self, sizes: Sequence[int] = ()) -> None:
-        """Warm the jit trace+compile caches for the given bucket sizes —
-        in msm mode also the per-item fallback kernel (via a deliberately
-        corrupt signature), so the first adversarial input at runtime
-        doesn't stall the pipeline behind a fresh trace."""
-        from ..crypto import KeyPair
-
-        kp = KeyPair.generate()
-        sig = kp.sign(b"warmup")
-        for size in sizes or (_MIN_BUCKET, self.max_bucket):
-            items = [(kp.public, b"warmup", sig)] * size
-            # Plain checks, not asserts: under python -O asserts vanish and
-            # the warmup would silently dispatch nothing.
-            if not all(self(items)):
-                raise RuntimeError("verifier warmup rejected a valid batch")
-            if self.mode == "msm" and size >= self.msm_min_bucket:
-                bad = list(items)
-                bad[-1] = (kp.public, b"not-warmup", sig)
-                if self(bad)[-1]:
-                    raise RuntimeError("verifier warmup accepted a forgery")
 
     def _precheck_native(self, items: Sequence[BatchItem], lib):
         """Batched canonicality checks + challenge scalars in C (GIL

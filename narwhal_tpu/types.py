@@ -615,9 +615,7 @@ def host_batch_verify_aggregates(groups: list[AggregateGroup]) -> list[bool]:
     with a fresh 128-bit outer weight w_g per group per call (os.urandom —
     the adversary must not predict them, so adversarially related groups
     cannot cancel each other). One MSM serves every group in the dispatch,
-    so the per-signature cost falls with batch size (>=5x the per-item
-    `host_verify_aggregate` at batch >= 32 — benchmark/microbench.py
-    --compact-verify).
+    so the per-signature cost falls with batch size.
 
     Verdicts are verdict-equivalent to per-item cofactored verification and
     DETERMINISTIC despite the random weights: a failed combined check

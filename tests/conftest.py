@@ -11,10 +11,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # whatever they actually dispatch; ahead-of-need warming is a production
 # concern.
 os.environ.setdefault("NARWHAL_TPU_PREWARM", "0")
-# Tests exercise bench entry points; none of their runs are measurements,
-# so keep them out of the checked-in perf ledger (tests that cover the
-# ledger point NARWHAL_PERF_LEDGER_PATH at a tmp file and re-enable).
-os.environ.setdefault("NARWHAL_PERF_LEDGER", "0")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -77,26 +73,6 @@ def pytest_runtest_makereport(item, call):
         )
     except Exception as exc:  # never let diagnostics break reporting
         report.sections.append(("flight recorder", f"dump failed: {exc!r}"))
-    try:
-        # Host context rides along: on this 1-core host most cluster-test
-        # flakes (test_partial_committee_change et al.) are CONTENTION, not
-        # code — the calibration probe + loadavg + a concurrent-pytest scan
-        # make that diagnosis readable from the artifact alone.
-        import json
-
-        from tools.perf import calibrate
-
-        ctx = calibrate.host_context(probe_budget_s=0.05)
-        headline = (
-            f"capacity {ctx['calibration']['ops_per_s']:.0f} ops/s, "
-            f"load {ctx['calibration']['loadavg_1m']:.2f}, "
-            f"concurrent pytest: {ctx['concurrent_pytest']}"
-        )
-        report.sections.append(
-            (f"host context ({headline})", json.dumps(ctx, indent=1, sort_keys=True))
-        )
-    except Exception as exc:
-        report.sections.append(("host context", f"capture failed: {exc!r}"))
 
 
 @pytest.fixture(autouse=True)

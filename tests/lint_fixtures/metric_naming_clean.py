@@ -8,7 +8,4 @@ def build(registry, role, name):
     ok_hist = registry.histogram("primary_propose_latency_seconds", "per stage")
     # Computed names are covered by their construction seam, not this rule.
     depth = registry.gauge(f"{role}_channel_{name}_depth", "channel depth")
-    # The perf observatory's namespace (tools/perf, benchmark.ab).
-    ok_perf = registry.gauge("perf_calibration_ops", "pinned probe capacity")
-    ok_perf_hist = registry.histogram("perf_leg_wall_seconds", "A/B leg wall")
-    return ok_counter, ok_gauge, ok_hist, depth, ok_perf, ok_perf_hist
+    return ok_counter, ok_gauge, ok_hist, depth

@@ -8,7 +8,4 @@ def build(registry):
     bad_subsystem = registry.gauge("widget_queue_depth", "no such subsystem")
     # Histogram without a unit suffix.
     bad_unit = registry.histogram("primary_propose_latency", "missing unit")
-    # "perf" is a registered subsystem, but the grammar still applies:
-    # a perf histogram needs its unit suffix like any other.
-    bad_perf = registry.histogram("perf_leg_wall", "missing unit on perf")
-    return bad_case, bad_subsystem, bad_unit, bad_perf
+    return bad_case, bad_subsystem, bad_unit

@@ -61,7 +61,7 @@ def test_sweep_table_finds_knee():
 
 def test_fd_preflight_estimates_and_fails_fast(monkeypatch):
     """The liveness preflight, honest for BOTH transport models: legacy
-    N=100 W=1 demands ~2·N·(N-1)·2 fds (the r9 n100_liveness.json EMFILE
+    N=100 W=1 demands ~2·N·(N-1)·2 fds (an N=100 run died of EMFILE
     at ~19.8k mesh sockets under a 20k limit) and fails BEFORE boot with a
     message pointing at --simnet; pooled collapses that to one link per
     node pair and fits the same rlimit."""

@@ -1,6 +1,6 @@
 """TPU DAG kernel equivalence: the vectorized adjacency-tensor commit walk
 must reproduce the host engine's sequence bit-for-bit on arbitrary DAGs.
-Runs on the virtual CPU backend (conftest); bench.py exercises the same
+Runs on the virtual CPU backend (conftest); chip_smoke.py exercises the same
 kernels on the real chip."""
 
 import random

@@ -1,6 +1,6 @@
 """FaultPlan fuzzer: generator invariants, campaign smoke, shrinker.
 
-The fuzzer (narwhal_tpu/simnet/fuzz.py, CLI `python bench.py --fuzz`)
+The fuzzer (narwhal_tpu/simnet/fuzz.py, CLI `python -m narwhal_tpu.simnet.fuzz`)
 spends the simnet perf win on adversarial coverage: seeded random fault
 schedules held to the safety/liveness oracles. These tests pin the three
 contracts the campaign artifact depends on:
@@ -77,7 +77,7 @@ def test_generated_plans_are_quorum_survivable():
 
 
 # ---------------------------------------------------------------------------
-# Campaign smoke: the tier-1 guard on `bench.py --fuzz`
+# Campaign smoke: the tier-1 guard on `python -m narwhal_tpu.simnet.fuzz`
 # ---------------------------------------------------------------------------
 
 

@@ -55,7 +55,9 @@ class _CompileLog(logging.Handler):
             self.compiles.append(msg)
 
     def count(self, name: str) -> int:
-        return sum(1 for m in self.compiles if m.startswith(f"Compiling {name}"))
+        # jax 0.9 names the computation `jit(<fn>)`; older releases `<fn>`.
+        heads = (f"Compiling {name} ", f"Compiling jit({name}) ")
+        return sum(1 for m in self.compiles if m.startswith(heads))
 
 
 @pytest.fixture

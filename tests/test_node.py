@@ -320,8 +320,8 @@ def test_fifty_node_committee_liveness(run):
     """The north-star committee size: a 50-node in-process committee over
     the authenticated mesh reaches lockstep commits (each round is ~7.5k
     signed+sealed control messages on this host's single core, so the
-    assertion is liveness, not throughput — see
-    benchmark/results/n50_liveness.json)."""
+    assertion is liveness, not throughput; `python -m benchmark.liveness
+    --nodes 50` is the same drive with wire counts)."""
     from narwhal_tpu.config import Parameters
 
     async def scenario():
@@ -517,7 +517,12 @@ def test_cluster_with_tpu_crypto_shared_service(run):
         max_batch=32,
         max_delay=0.002,
     )
-    svc.verifier.precompile((16, 32))
+    from narwhal_tpu.crypto import KeyPair
+
+    kp = KeyPair.generate()
+    item = (kp.public, b"warmup", kp.sign(b"warmup"))
+    for size in (16, 32):
+        assert all(svc.verifier([item] * size))
     VerifyService._shared["msm:1"] = svc
 
     async def scenario():

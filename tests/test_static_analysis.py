@@ -206,9 +206,8 @@ def test_fixture_finding_counts():
         "no-untracked-jit": 3,
         # certificate.verify, cert.verify, raw host_verify_aggregate
         "no-per-item-cert-verify": 3,
-        # bad snake_case, unknown subsystem, unitless histogram, unitless
-        # perf histogram (perf is a registered subsystem; grammar holds)
-        "metric-naming": 4,
+        # bad snake_case, unknown subsystem, unitless histogram
+        "metric-naming": 3,
         # transport dial, raw asyncio dial, PeerClient direct + attr form
         "no-direct-peer-connection": 4,
     }

@@ -441,8 +441,7 @@ def test_live_cluster_scrape_dump_and_waterfall(run, monkeypatch):
                 )
                 dumps.append(json.loads(resp.payload.decode()))
             # Worker rings hold the seal spans; workers expose no RPC
-            # listener of their own, so take their dumps in-process (the
-            # microbench --trace-waterfall path does the same).
+            # listener of their own, so take their dumps in-process.
             dumps.extend(
                 w.tracer.dump() for a in cluster.authorities for w in a.workers.values()
             )

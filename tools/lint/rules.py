@@ -1147,12 +1147,9 @@ class MetricNaming(Rule):
     )
 
     _METHODS = frozenset({"counter", "gauge", "histogram"})
-    # "perf" is the observatory's namespace (tools/perf, benchmark.ab):
-    # perf_* metrics describe the MEASUREMENT plane (calibration capacity,
-    # leg timings), never protocol behaviour.
     # "rpc" is the request layer above the wire (rpc_requests_failed_total).
     _SUBSYSTEMS = frozenset(
-        {"consensus", "executor", "node", "perf", "primary", "rpc", "storage",
+        {"consensus", "executor", "node", "primary", "rpc", "storage",
          "telemetry", "wire", "worker"}
     )
     # Histogram units in use; 'size'/'certificate' are count-like units
@@ -1222,7 +1219,7 @@ class NoDirectPeerConnection(Rule):
         "transport.open_connection / asyncio.open_connection or a "
         "hand-built PeerClient(...) opens a dedicated socket per call "
         "site, quietly re-growing the O(N^2*(1+W)) mesh the pool "
-        "collapsed — the socket wall n100_liveness.json died on"
+        "collapsed — the socket wall an N=100 committee died on"
     )
 
     _SCOPED_DIRS = frozenset({"primary", "worker", "executor"})

@@ -24,7 +24,7 @@ component shares are a true decomposition: the ranked table names where
 the 10x must come from, and `attributed_share` (everything but `other`)
 is the acceptance figure — below 0.8 the bucket table has drifted from
 the code and needs new patterns, which is exactly what the gate in
-tests/test_perf_observatory.py would catch.
+tests/test_trace_tools.py would catch.
 
 Run:  JAX_PLATFORMS=cpu python -m tools.perf.simnet_profile \
           --nodes 6 --duration 3 --load-rate 120 --out <artifact.json>
@@ -228,15 +228,6 @@ def main() -> int:
             json.dump(report, fh, indent=1, sort_keys=True)
             fh.write("\n")
         print(f"wrote {args.out}")
-
-    from . import ledger
-
-    ledger.append(
-        "simnet_profile",
-        report,
-        argv=["tools.perf.simnet_profile"]
-        + [f"--nodes={args.nodes}", f"--duration={args.duration}"],
-    )
     return 0
 
 
