@@ -106,9 +106,10 @@ class PrimaryNode:
         # Group-commit instruments (fused-WAL group size / flush latency).
         storage.engine.attach_metrics(self.registry)
         # Process-wide series this node's scrape carries too: the loop's
-        # heartbeat lateness and the shared verify service's rows, waits and
-        # events (zero on a backend that runs no such service).
-        from .tpu import verifier
+        # heartbeat lateness, the shared verify service's rows, waits and
+        # events (zero on a backend that runs no such service) and what the
+        # persisted kernels' exports on disk gave at their first dispatches.
+        from .tpu import kernel_registry, verifier
 
         for series in (
             tracing.LOOP_LAG,
@@ -117,6 +118,7 @@ class PrimaryNode:
             verifier.SERVICE_BYTES,
             verifier.SERVICE_WAIT,
             verifier.SERVICE_EVENTS,
+            kernel_registry.KERNEL_ARTIFACTS,
         ):
             self.registry.mount(series)
         self._heartbeat = False

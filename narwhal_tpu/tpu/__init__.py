@@ -13,7 +13,12 @@ cache key's environment, so it is never a temp name, pid or time. The big
 kernels (the per-item ed25519 Straus walk, the batch MSM accumulate, the
 chain_commit scan) take tens of seconds to minutes to compile, and every
 process (node, smoke, pytest) should pay that once per machine, not once
-per run. Jit TRACE time is not cached.
+per run. The cache holds executables, keyed by the lowered module: a jit's
+Python TRACE is paid again in every process, except by a kernel decorated
+`@tracked_jit(persist=True)` (`msm_accumulate_kernel`, the one a node's
+start waits for), whose serialised `jax.export` lives in
+`<cache dir>/kernel_artifacts/` and is loaded instead
+(kernel_registry.py has the key and the rules).
 """
 
 from __future__ import annotations

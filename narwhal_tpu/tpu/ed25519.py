@@ -728,7 +728,7 @@ def split_msm_result(flat: np.ndarray):
     )
 
 
-@tracked_jit(static_argnames=("chunk",))
+@tracked_jit(static_argnames=("chunk",), persist=True)
 def msm_accumulate_kernel(rows, chunk=128):
     """Device half of the batch check Σ [z_ik_i](−A_i) + Σ [z_i](−R_i):
     per-window point sums over the whole batch.

@@ -282,8 +282,8 @@ class TpuVerifier:
         # shape costs a multi-minute first compile.
         self.msm_min_bucket = msm_min_bucket
         # fixed_bucket pads EVERY dispatch to max_bucket: one shape means
-        # one jit trace + compile per process (the persistent cache only
-        # skips the XLA compile, not tracing). What padding a near-empty
+        # one program per process to load (or, on a tree's first start, to
+        # trace and compile). What padding a near-empty
         # flush to the full bucket costs on a locally attached chip is
         # unmeasured; ROADMAP D1/S2 decide it from the benchmark's numbers.
         # The protocol-serving VerifyService runs this way.
@@ -338,6 +338,10 @@ class TpuVerifier:
                 kernel, mesh, data_axis
             )
         else:
+            # One device: the module-level kernels. `msm_accumulate_kernel`
+            # is persisted (its first dispatch at a bucket loads its export
+            # from beside the compile cache, kernel_registry.py); the mesh
+            # wrappers above and the per-item detour trace as they always did.
             self._item_kernel = kernel.verify_batch_kernel
             self._msm_kernel = kernel.msm_accumulate_kernel
 

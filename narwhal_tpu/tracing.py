@@ -147,6 +147,11 @@ FLIGHT_FIELDS = {
     "lag": "due woke quiet quiet_sum",
     # tpu/kernel_registry.py, a first dispatch of (kernel, shapes)
     "compile": "kernel shapes t wall_s",
+    # tpu/kernel_registry.py, a persisted kernel's first dispatch at a shape:
+    # what the export on disk gave (hit | miss | stale | unreadable) and the
+    # seconds spent loading it or, on anything but a hit, tracing and
+    # exporting; the `compile` record that follows holds the whole wall
+    "kernel_load": "kernel shapes outcome t seconds",
     # storage.py StorageStats.record_group, one per fused WAL flush
     "wal_flush": "ops flush_s t",
     # worker/worker.py, a worker's first non-empty submission

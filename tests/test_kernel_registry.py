@@ -48,16 +48,24 @@ class _CompileLog(logging.Handler):
     def __init__(self):
         super().__init__(level=logging.DEBUG)
         self.compiles: list[str] = []
+        self.finished: list[str] = []
 
     def emit(self, record):
         msg = record.getMessage()
         if msg.startswith("Compiling "):
             self.compiles.append(msg)
+        elif msg.startswith("Finished XLA compilation "):
+            self.finished.append(msg)
 
     def count(self, name: str) -> int:
         # jax 0.9 names the computation `jit(<fn>)`; older releases `<fn>`.
         heads = (f"Compiling {name} ", f"Compiling jit({name}) ")
         return sum(1 for m in self.compiles if m.startswith(heads))
+
+    def executables(self, name: str) -> int:
+        """`Finished XLA compilation of jit(<fn>)`: one per executable
+        compiled or taken from the persistent cache, none per lowering."""
+        return sum(1 for m in self.finished if m.startswith(f"Finished XLA compilation of jit({name}) "))
 
 
 @pytest.fixture
@@ -233,6 +241,59 @@ def test_compile_walls_recorded():
     assert rows and all(r["wall_s"] >= 0 for r in rows)
     agg = kernel_registry.compile_walls_by_shape()
     assert "chain_commit@2:auth" in agg
+
+
+def test_one_compile_per_persisted_kernel_shape(compile_log):
+    """The recompile guard on the load path (ISSUE 32): a persisted kernel
+    dispatches its exported program, loaded from beside the compile cache
+    or traced and written there, whichever this checkout's cache gives —
+    either way one executable per (kernel, shape), under the kernel's own
+    name in jax's log lines, and a first-dispatch wall per shape."""
+    from tests.artifact_kernels import tiny_persisted_kernel as kernel
+
+    name = "tiny_persisted_kernel"
+    x = np.ones((6, 3), np.uint8)  # shapes no other test of this process dispatches
+    assert jax.block_until_ready(kernel(x, scale=2)).tolist() == [6] * 6
+    assert compile_log.executables(name) == 1
+    lowered = compile_log.count(name)  # 1 loaded; 2 traced: the body for the export, then the export
+    assert lowered in (1, 2)
+    for _ in range(3):
+        jax.block_until_ready(kernel(x, scale=2))
+    assert (compile_log.executables(name), compile_log.count(name)) == (1, lowered)
+
+    # Another shape, and another static, are programs of their own: once each.
+    jax.block_until_ready(kernel(np.ones((12, 3), np.uint8), scale=2))
+    jax.block_until_ready(kernel(x, scale=4))
+    assert jax.block_until_ready(kernel(x, scale=4)).tolist() == [12] * 6
+    assert compile_log.executables(name) == 3
+
+    rows = [r for r in kernel_registry.compile_walls() if r["kernel"] == name]
+    assert {(r["mesh"], r["shapes"]) for r in rows} >= {
+        ("1", "uint8[6,3];scale=2"), ("1", "uint8[12,3];scale=2"), ("1", "uint8[6,3];scale=4")}
+    assert all(r["wall_s"] >= 0 for r in rows)
+    assert f"{name}@1" in kernel_registry.compile_walls_by_shape()
+
+
+@pytest.mark.parametrize("which", ["tiny_persisted_kernel", "msm_accumulate_kernel"])
+def test_a_loaded_program_keeps_the_kernels_name(which):
+    """What a device trace shows, and `chipbench/trace_reduce.py` looks for:
+    the module a persisted kernel dispatches is `jit_<kernel>`, whether its
+    export was loaded or just written. (`_load` does not compile: the msm
+    kernel's minute on XLA:CPU is not paid here.)"""
+    if which == "tiny_persisted_kernel":
+        from tests.artifact_kernels import tiny_persisted_kernel as kernel
+
+        operand, statics = np.zeros((5, 2), np.uint8), {"scale": 7}
+    else:
+        from narwhal_tpu.tpu import ed25519
+
+        kernel = ed25519.msm_accumulate_kernel
+        operand, statics = np.zeros((16, ed25519.ROW_BYTES), np.uint8), {}
+    program = kernel._load(kernel_registry._shapes_sig((operand,), statics), (operand,), statics)
+    assert program.lower(operand).as_text().startswith(f"module @jit_{which} ")
+    assert kernel.__name__ == kernel.name == which and callable(kernel.__wrapped__)
+    if statics:  # the prewarm's entry traces the body: seconds for the small kernel only
+        assert kernel.lower(operand, **statics).as_text().startswith(f"module @jit_{which} ")
 
 
 def test_verify_shard_divisibility_still_fails_fast():
