@@ -151,6 +151,7 @@ class Consensus:
         call's awaits: wall time of the coroutine, not the loop's alone."""
         protocol = self.protocol
         self._walks += 1
+        tracing.charge("consensus:walk")
         t_start = now()
         with tracing.annotation("narwhal/commit_walk", seq=self._walks, certs=len(certs)):
             if len(certs) > 1:

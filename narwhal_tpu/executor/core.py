@@ -110,6 +110,7 @@ class ExecutorCore:
     ) -> None:
         """(core.rs:129-259). `batches` is the subscriber's in-memory staging;
         the temp store is only a fallback (e.g. crash replay paths)."""
+        tracing.charge("execute:certificate")
         certificate = output.certificate
         # Sorted by batch digest: matches the canonical wire order so every
         # node (author included, before and after a crash) executes batches

@@ -35,6 +35,7 @@ O(N^2 * lanes) sockets to one per unordered node pair.
 from __future__ import annotations
 
 import asyncio
+import functools
 import hashlib
 import itertools
 import logging
@@ -44,7 +45,7 @@ from typing import Awaitable, Callable, Iterable
 
 from ..bounded_cache import BoundedCache
 from ..channels import CancelOnDrop
-from ..messages import Ack, decode_message, encode_message
+from ..messages import REGISTRY, Ack, decode_message, encode_message
 from . import transport
 from .auth import (
     KIND_HELLO,
@@ -90,6 +91,14 @@ def worker_lane(worker_id: int) -> int:
 # role is not co-hosted in its process (a split primary/worker deployment):
 # the client falls back to a direct connection to the role's own address.
 LANE_UNAVAILABLE = b"lane-unavailable"
+
+@functools.lru_cache(maxsize=None)
+def dispatch_task_name(tag: int) -> str:
+    """`rpc:<message>` for a wire tag: the name of a frame's dispatch task,
+    which is the owner the loop account (tracing.py) charges its decode, its
+    handler and its reply to."""
+    cls = REGISTRY.get(tag)
+    return f"rpc:{cls.__name__ if cls else tag}"
 
 
 class RpcError(Exception):
@@ -840,6 +849,7 @@ class PeerLink:
         self._sender.send(KIND_HELLO, 0, 0, POOL_HELLO)
 
     async def run(self, reader: asyncio.StreamReader) -> None:
+        loop = asyncio.get_running_loop()
         try:
             while True:
                 kind, rid, tag, lane, body = await _read_frame(
@@ -849,11 +859,12 @@ class PeerLink:
                     # Inbound call into one of our lanes: same bounded
                     # concurrency model as RpcServer._on_connection.
                     await self._sem.acquire()
-                    t = asyncio.ensure_future(
+                    t = loop.create_task(
                         self.pool.dispatch(
                             self, lane, rid, tag, body,
                             oneway=kind == KIND_ONEWAY,
-                        )
+                        ),
+                        name=dispatch_task_name(tag),
                     )
                     self._tasks.add(t)
                     t.add_done_callback(
@@ -1101,6 +1112,7 @@ class RpcServer:
         tasks: set[asyncio.Task] = set()
         session: Session | None = None
         sender: FrameSender | None = None
+        loop = asyncio.get_running_loop()
         try:
             if self._auth_keypair is not None:
                 try:
@@ -1153,10 +1165,11 @@ class RpcServer:
                         sender.send(KIND_ERR, rid, 0, LANE_UNAVAILABLE, lane)
                     continue
                 await sem.acquire()
-                t = asyncio.ensure_future(
+                t = loop.create_task(
                     self._dispatch(
                         sender, rid, tag, body, peer, oneway=kind == KIND_ONEWAY
-                    )
+                    ),
+                    name=dispatch_task_name(tag),
                 )
                 tasks.add(t)
                 t.add_done_callback(lambda t_: (tasks.discard(t_), sem.release()))

@@ -113,6 +113,7 @@ class PrimaryNode:
 
         for series in (
             tracing.LOOP_LAG,
+            tracing.LOOP_BUSY,
             verifier.SERVICE_ROWS,
             verifier.SERVICE_TRANSFERS,
             verifier.SERVICE_BYTES,

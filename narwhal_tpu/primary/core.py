@@ -429,13 +429,16 @@ class Core:
             msg = msg.inner
         try:
             if isinstance(msg, Header):
+                tracing.charge("core:header")
                 self.sanitize_header(msg, preverified)
                 self._observe_round(msg.round)
                 await self.process_header(msg)
             elif isinstance(msg, Vote):
+                tracing.charge("core:vote")
                 self.sanitize_vote(msg, preverified)
                 await self.process_vote(msg)
             elif isinstance(msg, Certificate):
+                tracing.charge("core:certificate")
                 self.sanitize_certificate(msg, preverified)
                 self._observe_round(msg.round)
                 await self.process_certificate(msg)

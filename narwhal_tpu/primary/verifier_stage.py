@@ -31,6 +31,8 @@ from ..config import Committee, WorkerCache
 from ..types import Certificate, DagError, Header, InvalidEpoch, Vote
 
 logger = logging.getLogger("narwhal.primary")
+# The loop account's owner of a message's first turn through the stage.
+_OWNER = {kind: f"stage:{kind}" for kind in ("header", "vote", "certificate")}
 
 
 class PreVerified:
@@ -95,6 +97,7 @@ class VerifierStage:
         else:
             await self.tx_out.send(msg)
             return
+        tracing.charge(_OWNER[kind])
         t_in = now()
         forward, outcome, t_verdict = await self._decide(kind, msg, t_in)
         if forward is not None:

@@ -302,7 +302,10 @@ class StorageEngine:
                     # Encode+buffered-append on the loop (cheap memcpy,
                     # keeps WAL order loop-ordered); only the flush — the
                     # syscall — leaves the loop.
+                    t_wal = tracing.ACCOUNTING and time.perf_counter()
                     self._append_body(self._encode_ops(grp.ops))
+                    if t_wal:
+                        tracing.nested("storage:wal", t_wal)
                     await loop.run_in_executor(None, self._flush_log)
             except Exception as e:
                 if not grp.future.done():
@@ -380,6 +383,7 @@ class StorageEngine:
         Synchronous seed semantics: durable (appended + flushed) before
         returning. A pending commit group is persisted FIRST so the WAL
         record order always matches the memtable apply order."""
+        t_wal = tracing.ACCOUNTING and time.perf_counter()  # the loop account's, while it keeps a stretch
         self._drain_pending_group_sync()
         ops = [(0, cf.name, key, value) for cf, key, value in puts]
         ops += [(1, cf.name, key, b"") for cf, key in deletes]
@@ -393,6 +397,8 @@ class StorageEngine:
             self._append(ops)
         for cf, key, value in puts:
             cf._notify(key, value)
+        if t_wal:
+            tracing.nested("storage:wal", t_wal)  # a write on the loop is this owner's
 
     def _drain_pending_group_sync(self) -> None:
         """Persist + resolve the open commit group inline (loop-thread

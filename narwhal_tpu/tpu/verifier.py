@@ -1162,8 +1162,11 @@ class VerifyService:
                 # its deadline, on this loop.
                 if self._pending or self._pending_groups:
                     self._arm_deadline(asyncio.get_running_loop(), now)
+        t_pack = sealed and tracing.ACCOUNTING and time.perf_counter()
         for kind, seq, entries in sealed:
             self._dispatch(kind, seq, entries, now)
+        if t_pack:
+            tracing.nested("verify:seal", t_pack)
 
     def _release(self) -> None:
         """A sealed flush was answered, or never left: free its slot, and
@@ -1270,6 +1273,7 @@ class VerifyService:
             by_loop.setdefault(loop, []).append((fut, res))
 
         def deliver(pairs) -> None:
+            tracing.charge("verify:deliver")
             for fut, res in pairs:
                 if fut.done():
                     continue

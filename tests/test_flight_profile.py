@@ -176,3 +176,54 @@ def test_the_split_times_every_part_of_a_flush_on_either_lane(monkeypatch):
     for r in rows:
         assert 0 < r["precheck"] + r["jit_call"] <= r["total"]
     assert set(fp.split_report(rows)) == {"singles", "groups"}
+
+
+# ---------------------------------------------------------------------------
+# --owners: the loop account of a window
+# ---------------------------------------------------------------------------
+
+
+def account_ring() -> dict:
+    tracing.new_generation()
+    # loop, t0, t1, handles, busy_s, cpu_s, longest_s, longest_owner
+    tracing.flight("loop", 1, 99.5, 100.5, 4000, 0.8, 0.7, 0.02, "rpc:HeaderMsg")  # half of it in [100, 102]
+    tracing.flight("loop", 1, 100.5, 101.5, 5000, 0.9, 0.8, 0.03, "core:vote")
+    tracing.flight("loop", 2, 100.0, 101.0, 100, 0.1, 0.1, 0.01, "tool.py:main")  # a loop less busy: not read
+    # loop, t1, owner, family, calls, seconds, longest
+    tracing.flight("owner", 1, 100.5, "rpc:HeaderMsg", "network", 40, 0.6, 0.02)
+    tracing.flight("owner", 1, 100.5, "rest", "other", 100, 0.2, 0.001)
+    tracing.flight("owner", 1, 101.5, "rpc:HeaderMsg", "network", 30, 0.5, 0.01)
+    tracing.flight("owner", 1, 101.5, "core:vote", "primary", 20, 0.3, 0.03)
+    tracing.flight("owner", 1, 101.5, "rest", "other", 90, 0.1, 0.002)
+    tracing.flight("owner", 2, 101.0, "tool.py:main", "other", 100, 0.1, 0.01)
+    return tracing.flight_dump()
+
+
+def test_the_owner_table_of_a_window_per_round_and_per_second(tmp_path, capsys):
+    dump = json.loads(json.dumps(account_ring()))
+    table = fp.owners(fp.typed(dump["events"]), 100.0, 101.5, rounds=10)
+    assert (table["loop"], table["per"], table["covered_s"]) == (1, "round", 1.5)
+    assert table["busy_share_pct"] == pytest.approx(100 * (0.4 + 0.9) / 1.5)
+    assert table["offcpu_share_pct"] == pytest.approx(100 * (0.05 + 0.1) / 1.3)
+    assert table["handles_per_s"] == pytest.approx((2000 + 5000) / 1.5)
+    assert table["work_ms"] == pytest.approx(130.0)
+    assert table["families_ms"] == pytest.approx({"network": 80.0, "primary": 30.0, "other": 20.0})
+    assert list(table["families_ms"]) == ["network", "primary", "other"]
+    assert [(o["owner"], o["family"]) for o in table["owners"]] == [
+        ("rpc:HeaderMsg", "network"), ("core:vote", "primary"), ("rest", "other")]
+    head = table["owners"][0]
+    assert (head["calls"], head["ms"], head["longest_ms"]) == pytest.approx((5.0, 80.0, 20.0))
+    assert sum(o["ms"] for o in table["owners"]) == pytest.approx(table["work_ms"])
+    # An account that kept 1.5 s of a 2 s window (it rests between stretches): those stand for the whole.
+    part = fp.owners(fp.typed(dump["events"]), 100.0, 102.0, rounds=10)
+    assert part["covered_s"] == 1.5 and part["work_ms"] == pytest.approx(130.0 * 2.0 / 1.5)
+    assert part["busy_share_pct"] == pytest.approx(table["busy_share_pct"])
+    assert fp.owners(fp.typed(dump["events"]), 200.0, 202.0) is None  # no second of the account in there
+    # From the command line: the dump's whole span, per second where no rounds are given.
+    path = tmp_path / "flight.json"
+    path.write_text(json.dumps(dump))
+    assert fp.main(["--owners", "--flight", str(path)]) == 0
+    printed = json.loads(capsys.readouterr().out)["loop_account"]
+    assert printed["per"] == "second" and (printed["window_s"], printed["covered_s"]) == pytest.approx((2.0, 2.0))
+    assert printed["work_ms"] == pytest.approx(1000 * 1.7 / 2.0)
+    assert len(printed["owners"]) == 3 <= fp.OWNERS_LISTED == 20

@@ -177,7 +177,7 @@ def test_the_layout_is_the_programs_and_a_site_out_of_step_raises(ring):
     import json
 
     assert set(tracing.FLIGHT_FIELDS) == {
-        "flush", "wake", "stage", "certify", "walk", "lag", "compile", "kernel_load",
+        "flush", "wake", "stage", "certify", "walk", "lag", "loop", "owner", "compile", "kernel_load",
         "wal_flush", "ingest_first"}
     tracing.flight("walk", "primary-x", 3, 5, 1.0, 1.5)
     (w,) = records("walk")

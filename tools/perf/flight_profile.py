@@ -4,6 +4,8 @@
     python3 -m tools.perf.flight_profile --drive 8        # on the chip, through chipbench's set-up
     python3 -m tools.perf.flight_profile --drive 8 --split  # and what a flush's `seal -> dispatched` is made of
     python3 -m tools.perf.flight_profile --micro          # what one record, add or mark costs here
+    python3 -m tools.perf.flight_profile --owners --drive 51          # the loop's time by owner, a whole window of the cell
+    python3 -m tools.perf.flight_profile --owners --flight <dump.json>  # of a dump's whole span, per second
 
 What reads the program's four profiler marks (`narwhal/verify_submit`,
 `narwhal/verify_collect`, `narwhal/commit_walk`, `narwhal/execute`:
@@ -31,10 +33,21 @@ tracing.annotation) and the fields of the ring no benchmark metric reads
   so `PYTHONPATH=<other checkout> python3 <this file> --drive 8 --split`
   splits that checkout's flush with this file.
 
+`--owners` reads the loop account (`loop` and `owner` records: every callback
+the event loop ran while the account kept a stretch, charged to the task,
+handler or wire tag that ran it) through the benchmark's own arithmetic
+(`chipbench.readers.loop_account`) and prints the window's busiest loop: how
+busy it was, its callbacks a second, its families and its twenty largest
+owners by milliseconds a round, with calls a round and each owner's longest
+stretch. With `--drive` the window and its rounds are the cell's own, nothing
+is profiled, and the eleven `loop.*` readers' values are printed beside the
+table; a dump alone is read over its whole span, per second.
+
 A mark around an `await` (`commit_walk`, `execute`) is as wide as its
 coroutine's wall time, other tasks' turns included; what held the loop is the
-`lag` records' to say. `--drive` needs a device and is the only part that
-imports `chipbench`; the rest is arithmetic on what it is given.
+`lag` records' to say. `--drive` needs a device and `--owners` the
+benchmark's readers: the only parts that import `chipbench`; the rest is
+arithmetic on what it is given.
 """
 
 from __future__ import annotations
@@ -230,6 +243,34 @@ def report(dump: dict, profile: Profile) -> dict:
 SPLIT_PARTS = ("precheck", "fold", "jit_call", "readback_start")
 
 
+OWNERS_LISTED = 20
+
+
+def owners(by, t0: float, t1: float, rounds: float | None = None) -> dict | None:
+    """The loop account of [t0, t1] as a table: the busiest loop's seconds by
+    family and by owner, per round where `rounds` says how many the window
+    committed, else per second of the window."""
+    from chipbench.readers import loop_account
+
+    acct = loop_account.over(by, t0, t1)
+    if acct is None:
+        return None
+    unit = (rounds or (t1 - t0)) / acct.scale  # rounds, or seconds, that the covered seconds stand for
+    ranked = sorted(acct.owners.items(), key=lambda kv: kv[1][1], reverse=True)
+    return {
+        "window_s": t1 - t0, "covered_s": acct.covered, "rounds": rounds, "per": "round" if rounds else "second",
+        "loop": acct.loop, "busy_share_pct": 100.0 * acct.busy / acct.covered,
+        "offcpu_share_pct": 100.0 * acct.offcpu / acct.busy if acct.busy else None,
+        "handles_per_s": acct.handles / acct.covered, "work_ms": 1000.0 * acct.busy / unit,
+        "families_ms": {f: 1000.0 * s / unit for f, s in sorted(acct.families.items(), key=lambda kv: -kv[1])},
+        "owners": [
+            {"owner": owner, "family": family, "calls": calls / unit, "ms": 1000.0 * seconds / unit,
+             "longest_ms": 1000.0 * longest}
+            for (owner, family), (calls, seconds, longest) in ranked[:OWNERS_LISTED]
+        ],
+    }
+
+
 def install_split() -> list[dict]:
     """Time the parts of every `TpuVerifier.submit` / `submit_groups` from
     now on; returns the list that gets one row per call: lane, `total` and
@@ -285,21 +326,21 @@ def split_report(rows: list[dict]) -> dict:
     return out
 
 
-def drive(seconds: float, slice_s: float) -> tuple[dict, str]:
+def drive(seconds: float, slice_s: float) -> tuple[dict, dict]:
     """A few seconds of `local-4x1.cruise` through chipbench's own set-up,
-    with a profiler slice of `slice_s` that is kept: (the ring's dump, the
-    trace directory). The caller owns the directory."""
+    with a profiler slice of `slice_s` that is kept (none at 0): (the ring's
+    dump, what the run observed; its `trace_dir` is the caller's)."""
     from chipbench import __main__ as entry
     from chipbench import run as runner
 
     args = entry.parse(["--workload", "local-4x1.cruise", "--seed", str(2**31 + 2601),
-                        "--seconds", str(seconds), "--trace", "1"])
+                        "--seconds", str(seconds), "--trace", "1" if slice_s else "0"])
     runner.TRACE_SLICE_S = slice_s
     ctx = runner.prepare(args, _T_PROC)
     rec = runner.measure(ctx, args, runner.cell_rate(ctx, args))
     print(json.dumps({"drive": {"correct": rec["correct"], "failed": rec["failed"],
                                 "attempted": rec["attempted"], "device": ctx.device}}), flush=True)
-    return tracing.flight_dump(), rec["obs"]["trace_dir"]
+    return tracing.flight_dump(), dict(rec["obs"], mix=ctx.mix)
 
 
 def micro(n: int = 200_000) -> dict:
@@ -331,14 +372,40 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--platform", default="TPU")
     ap.add_argument("--micro", action="store_true", help="time the recorder's own calls on this host")
     ap.add_argument("--split", action="store_true", help="with --drive: split submit's host time into its parts")
+    ap.add_argument("--owners", action="store_true", help="the loop's time by owner (no profile is taken or read)")
     args = ap.parse_args(argv)
     if args.micro:
         print(json.dumps({"micro": micro()}), flush=True)
         if not (args.drive or args.xplane):
             return 0
+    if args.owners:
+        out: dict = {}
+        if args.drive:
+            from chipbench import run as runner
+            from chipbench.readers import flight_window
+
+            dump, obs = drive(args.drive, 0.0)
+            win = flight_window.window(obs)
+            t0, t1, rounds = win.t0, win.t1, obs["window"]["rounds"]
+            out["metrics"] = {
+                name: runner.load_reader(name)(obs)
+                for name in ("loop.busy_share", "loop.work_ms_per_round", "loop.offcpu_share",
+                             *(f"loop.{family}_ms_per_round" for family in tracing.FAMILIES))
+            }
+        elif args.flight:
+            with open(args.flight) as f:
+                dump = json.load(f)
+            spans = [(r[2], r[3]) for r in dump["events"] if r[0] == "loop"]  # each kept stretch
+            t0, t1, rounds = min((a for a, _ in spans), default=0.0), max((b for _, b in spans), default=0.0), None
+        else:
+            ap.error("--owners reads --flight, or takes --drive")
+        out["loop_account"] = owners(typed(dump["events"]), t0, t1, rounds)
+        print(json.dumps(out, indent=1))
+        return 0
     if args.drive:
         split = install_split() if args.split else None
-        dump, xplane = drive(args.drive, args.slice)
+        dump, obs = drive(args.drive, args.slice)
+        xplane = obs["trace_dir"]
         if split is not None:
             print(json.dumps({"submit_split_ms": split_report(split)}), flush=True)
     elif args.xplane and args.flight:
