@@ -5,7 +5,10 @@ each validator executed, in order, byte for byte against what was offered),
 and what the validators left on disk, read back with the plain reference
 once the committee is shut down: the certificates each stored, the commit
 sequence each recorded, the batches each worker holds. The plain reference
-(chipbench/reference) imports nothing of the program.
+(chipbench/reference) imports nothing of the program. The commit sequence is
+held to the plain rule of the engine the configuration names
+(`consensus_protocol`): `chipbench/reference/<engine>.py`, found by that name,
+so a configuration on a new engine brings its file and edits nothing here.
 
 All counts; every limit is 0 (an exact comparison) and is the guarantee the
 configuration states. PERF.md gives the readings the limits stand between.
@@ -19,10 +22,13 @@ sequence, repeats included). The stores' side is its second witness:
 
 from __future__ import annotations
 
+import importlib
 import os
 import random
 
-from .reference import bullshark, ed25519, formats
+from .reference import ed25519, formats
+
+REFERENCE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "reference")
 
 LIMITS = {
     # clients' side
@@ -38,6 +44,19 @@ LIMITS = {
     "bad_certificates": 0,      # sampled certificates failing the plain Ed25519 / digest checks
     "bad_batches": 0,           # stored batches whose digest or transactions are wrong
 }
+
+
+def ordering_reference(engine: str):
+    """The plain commit rule of `engine`: the module
+    `chipbench/reference/<engine>.py`, whose `commit_sequence(certs,
+    gc_depth)` every validator's recorded sequence is held to. LookupError,
+    naming the file, where the engine has none."""
+    if not os.path.isfile(os.path.join(REFERENCE_DIR, f"{engine}.py")):
+        raise LookupError(
+            f"consensus_protocol {engine!r} has no plain commit rule: "
+            f"chipbench/reference/{engine}.py is missing"
+        )
+    return importlib.import_module(f"{__package__}.reference.{engine}")
 
 
 def _common_prefix_equal(a: list[int], b: list[int]) -> bool:
@@ -62,6 +81,7 @@ def stores_side(rec: dict, seed: int, sample: int) -> tuple[dict, dict]:
     n = rec["validators"]
     base = rec["store_base"]
     gc_depth = rec["gc_depth"]
+    engine = ordering_reference(rec["consensus_protocol"])
     txs = rec["txs"]
     certs: dict[bytes, formats.Cert] = {}
     sequences: list[list[bytes]] = []
@@ -92,8 +112,8 @@ def stores_side(rec: dict, seed: int, sample: int) -> tuple[dict, dict]:
         sequences.append([seq[i] for i in sorted(seq)])
 
     # The commit walk: each validator's recorded sequence against the plain
-    # rule run over the union of everybody's certificates.
-    reference = bullshark.commit_sequence(list(certs.values()), gc_depth)
+    # rule of its engine run over the union of everybody's certificates.
+    reference = engine.commit_sequence(list(certs.values()), gc_depth)
     walk_mismatch = 0
     for seq in sequences:
         if len(seq) > len(reference) or seq != reference[: len(seq)]:

@@ -70,7 +70,12 @@ def load_cell(workload: str) -> tuple[dict, dict, dict, dict]:
         raise HarnessFault(f"no workload {workload!r} in BENCHMARK.json: {sorted(cells)}")
     cell = cells[workload]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    return bench, cell, load_json(os.path.join(ROOT, cfg_entry["file"])), traffic.load_mix(cell["traffic"])
+    cfg = load_json(os.path.join(ROOT, cfg_entry["file"]))
+    try:  # before the committee boots, not after a window
+        judge.ordering_reference(cfg["consensus_protocol"])
+    except LookupError as e:
+        raise HarnessFault(str(e)) from None
+    return bench, cell, cfg, traffic.load_mix(cell["traffic"])
 
 
 def load_reader(metric: str):
@@ -470,6 +475,7 @@ async def serve(ctx: "Ctx", args, rate: float, store_root: str, setup: dict, fau
         shed_tx = sum(b.count for i, b in window if state[i] == SHED)
         rec.update(
             validators=n, workers=workers, gc_depth=cluster.parameters.gc_depth,
+            consensus_protocol=cluster.consensus_protocol,
             txs=txs, orders=orders, twice=twice, unknown=unknown,
             unexecuted_acked=unexecuted_acked,
             detours=sum(snaps["drained"]["verifier"][d] for d in DETOURS),

@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from chipbench import judge, run as runner, trace_reduce, traffic, work  # noqa: E402
-from chipbench.reference import bullshark, ed25519, formats  # noqa: E402
+from chipbench.reference import ed25519, formats  # noqa: E402
 
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -86,6 +86,7 @@ def test_entry_names_units_and_files(kind, entry):
         cfg = json.load(open(os.path.join(ROOT, entry["file"])))
         assert cfg["name"] == entry["name"] and set(entry["reduced"]) == set(cfg["reduced"])
         assert all(NAME.match(k) for k in entry["reduced"]) and cfg["guarantees"] and cfg["storage_engine"]
+        assert judge.ordering_reference(cfg["consensus_protocol"]).commit_sequence  # its engine's plain rule
     elif kind == "workload":
         assert NAME.match(entry["config"]) and NAME.match(entry["traffic"]) and entry["chips"] in (1, 4)
         assert len(entry["why"]) <= 200
@@ -233,6 +234,7 @@ def test_reference_reads_the_programs_certificates_and_proofs(committee7):
 
 @pytest.mark.parametrize("n,loss,seed", [(4, 0.0, 1), (4, 0.3, 2), (7, 0.25, 3), (10, 0.2, 4)])
 def test_plain_bullshark_commits_what_the_programs_host_engine_commits(n, loss, seed):
+    """The plain rule as the judge finds it, by the engine's name."""
     from narwhal_tpu.consensus import Bullshark, ConsensusState
     from narwhal_tpu.fixtures import CommitteeFixture, make_certificates
     from narwhal_tpu.stores import NodeStorage
@@ -251,8 +253,14 @@ def test_plain_bullshark_commits_what_the_programs_host_engine_commits(n, loss, 
     Plain = namedtuple("Plain", "author round epoch parents digest")
     plain = [Plain(c.origin, c.round, c.epoch, tuple(sorted(c.header.parents)), c.digest) for c in certs]
     random.Random(seed).shuffle(plain)  # the union of stores comes in no order
+    bullshark = judge.ordering_reference("bullshark")
     assert len(want) > n and bullshark.commit_sequence(plain, gc_depth) == want
     assert bullshark.leader_of(6, 0, sorted(fx.committee.authority_keys())) == fx.committee.leader(6)
+
+
+def test_an_engine_without_a_plain_rule_is_named_by_its_missing_file():
+    with pytest.raises(LookupError, match=re.escape("chipbench/reference/no-such-engine.py is missing")):
+        judge.ordering_reference("no-such-engine")
 
 
 def wal_record(ops) -> bytes:
@@ -426,6 +434,17 @@ def test_rehearsal_traced_and_overloaded_still_exits_0_with_failed_counts():
     assert result["metrics"]["verify.detours"]["value"] == 0
 
 
+# What a PR that only appends has to leave passing: every entry, every cell,
+# and the pins of the per-layer entries, which go by name.
+STRUCTURAL = [
+    "tests/chipbench/test_chipbench.py::test_entry_names_units_and_files",
+    "tests/chipbench/test_chipbench.py::test_every_cell_reports_setup_another_end_to_end_metric_and_a_layer_metric",
+    "tests/chipbench/test_flight_readers.py::test_the_eleven_are_entries_each_with_a_reader_of_its_own",
+    "tests/chipbench/test_loop_readers.py::"
+    "test_the_eleven_are_entries_a_reader_each_by_the_accounts_families_and_the_helper_is_no_metric",
+]
+
+
 def test_a_copy_without_git_cache_and_libraries_runs_a_cell_that_only_new_files_add(tmp_path):
     copy = tmp_path / "checkout"
     shutil.copytree(ROOT, copy, ignore=shutil.ignore_patterns(
@@ -449,8 +468,16 @@ def test_a_copy_without_git_cache_and_libraries_runs_a_cell_that_only_new_files_
                                "chips": 1, "why": "test"})
     bench["per_layer"].append({"name": "verify.dispatches", "unit": "count", "better": "lower",
                                "source": "program_counter", "layer": "verify stage",
-                               "moves": "executed_tx_per_s", "workloads": ["local-5x1.trickle"]})
+                               "moves": "executed_tx_per_s"})
     json.dump(bench, open(copy / "BENCHMARK.json", "w"))
+    # The appends alone leave the structural tests passing, run inside the copy.
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider", "-p", "no:randomly", *STRUCTURAL],
+        cwd=copy, env=dict(env, JAX_PLATFORMS="cpu"), text=True, capture_output=True, timeout=300,
+    )
+    entries = sum(len(bench[k]) for k in ("configs", "workloads", "end_to_end", "per_layer"))
+    assert proc.returncode == 0 and f"{entries + 3} passed" in proc.stdout, proc.stdout[-3000:] + proc.stderr[-2000:]
     rehearsal = {"verify_bucket": 16, "parameters": {"commit_latency_target": 60}}  # five validators, the file's rate
     proc = chipbench(driver_args("local-5x1.trickle", 79, 1), rehearsal=rehearsal, cwd=str(copy))
     result = last_json(proc)
@@ -463,6 +490,13 @@ def test_a_copy_without_git_cache_and_libraries_runs_a_cell_that_only_new_files_
     assert list((copy / "native").glob("*.so")), "the run builds its own libraries"
     out = json.load(open(copy / "chipbench/out/local-5x1.trickle.79.json"))
     assert len(out["notes"]["validator_commits"]) == 5
+    # An engine with no plain rule of its own faults before the device is
+    # looked for, let alone a committee booted, and names the missing file.
+    json.dump(dict(cfg, consensus_protocol="no-such-engine"), open(copy / "chipbench/configs/local-5x1.json", "w"))
+    proc = chipbench(driver_args("local-5x1.trickle", 80, 0), rehearsal=rehearsal, cwd=str(copy), timeout=300)
+    last = proc.stdout.strip().splitlines()[-1]
+    assert proc.returncode == 1 and "HarnessFault" in last and "chipbench/reference/no-such-engine.py" in last
+    assert "load_cell" in proc.stderr and "device {" not in proc.stdout
 
 
 def run_faults(names: str, seconds: str) -> dict[str, dict]:

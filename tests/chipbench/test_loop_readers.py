@@ -5,6 +5,7 @@ each edge of it — and each returning nothing on a ring without the records."""
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 
@@ -16,6 +17,7 @@ sys.path.insert(0, ROOT)
 from chipbench import run as runner  # noqa: E402
 from narwhal_tpu import tracing  # noqa: E402
 
+BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 # The window: first submission at 100.0, a 3 s ramp, 10 s long: [103, 113]; 50 rounds in it.
 OBS = {"mix": {"warm_s": 3.0}, "seconds": 10.0, "window": {"rounds": 50.0}}
 
@@ -78,11 +80,17 @@ def _empty_ring_afterwards():
     tracing.new_generation()
 
 
-def test_the_eleven_are_a_reader_each_by_the_accounts_families_and_the_helper_is_no_metric():
-    """`BENCHMARK.json` does not list them yet (PERF.md section 7: its
-    `per_layer` is pinned by position in `test_flight_readers.py`, which only
-    a `benchmark` PR may edit), so nothing here goes by that file."""
+def test_the_eleven_are_entries_a_reader_each_by_the_accounts_families_and_the_helper_is_no_metric():
+    """Each is a `per_layer` entry of `BENCHMARK.json`, found by name and in
+    the order it was appended (PR 36): entries appended after them, and a
+    `workloads` list that keeps one out of a later cell, leave this as it is."""
     assert set(BY_HAND) == set(LOOP_METRICS) and len(LOOP_METRICS) == 11
+    entries = [m for m in BENCH["per_layer"] if m["name"] in LOOP_METRICS]
+    assert [m["name"] for m in entries] == LOOP_METRICS
+    assert all(m["source"] == "program_span" and m["better"] == "lower" and m["moves"] == "latency_p50_ms"
+               for m in entries)
+    assert {m["name"]: m["unit"] for m in entries} == {
+        name: "%" if name.endswith("_share") else "ms" for name in LOOP_METRICS}
     families = {n.split(".")[1].removesuffix("_ms_per_round") for n in LOOP_METRICS if n.endswith("_ms_per_round")}
     assert families == set(tracing.FAMILIES) | {"work"}
     readers = os.path.join(ROOT, "chipbench", "readers")
