@@ -2,13 +2,21 @@
 
 Not a metric's reader: the helper of the readers that take theirs from
 `narwhal_tpu.tracing.flight_dump()` — the ring of `flush`, `wake`, `stage`,
-`certify`, `walk`, `lag`, `compile`, `wal_flush` and `ingest_first` records
-the program keeps always, which outlives the committee's shutdown. `obs`
-holds no absolute time, so the window is found from the ring itself: the
-workers' first non-empty submission (`ingest_first`; the generator's first
-burst is due 50 ms after it starts) plus the mix's ramp opens it, and it is
-`obs["seconds"]` long. A program without the ring, or a ring without that
-record, gives None, and so does every reader that asks.
+`certify`, `walk`, `lag`, `loop`, `owner`, `compile`, `wal_flush` and
+`ingest_first` records the program keeps always. The run takes the ring once,
+when its drain ends (`obs["flight"]`, `chipbench/run.py` `take_flight`): the
+committee writes on through the trace write and its shutdown, and a large one
+would push the window's first records out of the ring before the readers came
+to them. Every record a reader uses is written inside the window or soon
+after its end (a stretch of the loop account that straddles it, within 0.1 s;
+a verifier stage about a header certified in it, within its hop), and the
+drain lasts at least 0.2 s past it. Without `obs["flight"]` (a hand-filled ring in a test)
+the live ring is read. `obs` holds no absolute time, so the window is found
+from the records themselves: the workers' first non-empty submission
+(`ingest_first`; the generator's first burst is due 50 ms after it starts)
+plus the mix's ramp opens it, and it is `obs["seconds"]` long. A program
+without the ring, or records without that one, give None, and so does every
+reader that asks.
 
 The program owns the records' layout (`narwhal_tpu.tracing.FLIGHT_FIELDS`):
 a record is a namedtuple whose first field is its kind, and the readers go by
@@ -22,24 +30,57 @@ import collections
 # t0, t1: the window; by: the ring's records by kind; period: the loop
 # heartbeat's (a wake later than one period has a `lag` record of its own).
 Window = collections.namedtuple("Window", "t0 t1 by period")
+# The field of each kind that says when a record was written, or within a
+# few milliseconds of it: what `coverage` tells a record's age by.
+STAMPS = {
+    "flush": "t_posted", "wake": "t_posted", "stage": "t_forwarded", "certify": "t1", "walk": "t_done",
+    "lag": "woke", "loop": "t1", "owner": "t1", "compile": "t", "kernel_load": "t", "wal_flush": "t",
+    "ingest_first": "t",
+}
 
 
 def window(obs) -> Window | None:
-    """The window's bounds and the ring's records by kind, or None."""
+    """The window's bounds and the records by kind, or None."""
     try:
         from narwhal_tpu import tracing
     except ImportError:
         return None
-    dump = getattr(tracing, "flight_dump", None)
-    if dump is None:
-        return None
+    flight = obs.get("flight")
+    if flight is None:
+        dump = getattr(tracing, "flight_dump", None)
+        if dump is None:
+            return None
+        flight = dump()
     by: dict[str, list] = collections.defaultdict(list)
-    for record in dump()["events"]:
+    for record in flight["events"]:
         by[record.kind].append(record)
     if not by["ingest_first"]:
         return None
     t0 = min(r.t for r in by["ingest_first"]) + float(obs["mix"].get("warm_s", 0.0))
     return Window(t0, t0 + float(obs["seconds"]), by, tracing.HEARTBEAT_PERIOD)
+
+
+def coverage(obs) -> dict | None:
+    """What the run's snapshot of the ring kept, for its record: the records
+    and the ring's capacity; the seconds from the oldest record kept to the
+    snapshot, and to the window's opening (the ring's headroom; None where
+    the window was lost); and the records written a second inside the window
+    and in the drain after it. None where the run took no snapshot."""
+    flight = obs.get("flight")
+    if flight is None:
+        return None
+    stamps = [getattr(r, STAMPS[r.kind]) for r in flight["events"] if r.kind in STAMPS]
+    taken = flight["t_taken"]
+    oldest = min(stamps, default=taken)
+    out = {"records": len(flight["events"]), "ring_capacity": flight["ring_capacity"],
+           "seconds_kept": taken - oldest, "headroom_s": None, "per_s_window": None, "per_s_drain": None}
+    win = window(obs)
+    if win is not None:
+        out["headroom_s"] = win.t0 - oldest
+        out["per_s_window"] = sum(win.t0 <= t <= win.t1 for t in stamps) / (win.t1 - win.t0)
+        if taken > win.t1:
+            out["per_s_drain"] = sum(t > win.t1 for t in stamps) / (taken - win.t1)
+    return out
 
 
 def within(win: Window | None, kind: str, at: str) -> list:

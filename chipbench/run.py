@@ -23,6 +23,7 @@ import tempfile
 import time
 
 from . import judge, trace_reduce, traffic
+from .readers import flight_window
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -155,6 +156,30 @@ def warm_shapes(cfg: dict) -> int:
                 getattr(leaf, "block_until_ready", lambda: None)()
             done += 1
     return done
+
+
+def take_flight(traced: bool, acked: int) -> dict | None:
+    """The program's process flight ring, taken once when the drain ends:
+    before the trace is written and the committee shut down, while it writes
+    on into the ring, so a committee that writes fast does not push the
+    window's first records out before the readers come to them. None for a
+    program without the ring. A traced run whose clients were answered and
+    whose snapshot holds no `ingest_first` has lost its window to the ring's
+    size, which the fault names."""
+    from narwhal_tpu import tracing
+
+    dump = getattr(tracing, "flight_dump", None)
+    if dump is None:
+        return None
+    flight = dump()
+    flight["t_taken"] = time.monotonic()
+    if traced and acked and not any(r.kind == "ingest_first" for r in flight["events"]):
+        kept = flight_window.coverage({"flight": flight})
+        raise HarnessFault(
+            f"the flight ring lost the window: no ingest_first among the {kept['records']} records kept "
+            f"by a ring of {kept['ring_capacity']}, which cover the last {kept['seconds_kept']:.1f} s; "
+            "this committee needs a larger narwhal_tpu.tracing.FLIGHT_RING")
+    return flight
 
 
 def device_rtt_ms(jax) -> float:
@@ -439,6 +464,7 @@ async def serve(ctx: "Ctx", args, rate: float, store_root: str, setup: dict, fau
             await asyncio.sleep(0.1)
         await asyncio.sleep(0.2)  # a duplicate execution would land now
         t_drained = time.monotonic()
+        flight = take_flight(bool(args.trace), state.count(ACKED))
         if trace_jobs:
             await asyncio.gather(*trace_jobs)
             setup["trace_write_s"] = trace_window[2]
@@ -503,6 +529,7 @@ async def serve(ctx: "Ctx", args, rate: float, store_root: str, setup: dict, fau
                 "verify_bucket": svc.verifier.max_bucket,
                 "trace_dir": trace_dir if tracing_on else None,
                 "trace_window_s": trace_window[1] - trace_window[0],
+                "flight": flight,
             },
         )
         devs = jax.devices()
@@ -696,6 +723,7 @@ def finish(ctx: Ctx, args, rec: dict) -> dict:
     result["checks"] = rec["checks"]
 
     summary = {k: v for k, v in obs.items() if k not in ("latencies_ms", "late_ms", "config", "mix")}
+    summary["flight"] = flight_window.coverage(obs)  # the snapshot's size, not its records
     out = {
         "workload": args.workload, "seed": args.seed, "seconds": args.seconds,
         "trace": args.trace, "rate_tx_per_s": rec["rate"], "rehearsal": rehearsal,

@@ -1,7 +1,8 @@
 """The readers that take their metric from the program's process flight ring
 (`narwhal_tpu.tracing.flight_dump`): each on a hand-built ring against a value
-worked by hand, the window cut from `ingest_first` plus the mix's ramp, and one
-CPU rehearsal whose line carries the three that are counts."""
+worked by hand, the window cut from `ingest_first` plus the mix's ramp; the
+run's snapshot of the ring, taken when the drain ends, against the live ring;
+and one CPU rehearsal whose line carries the three that are counts."""
 
 from __future__ import annotations
 
@@ -18,6 +19,8 @@ sys.path.insert(0, ROOT)
 from chipbench import run as runner  # noqa: E402
 from chipbench.readers import flight_window as fw  # noqa: E402
 from narwhal_tpu import tracing  # noqa: E402
+from tests.chipbench.test_loop_readers import BY_HAND as LOOP_BY_HAND  # noqa: E402
+from tests.chipbench.test_loop_readers import LOOP_METRICS, LOOPS, OWNERS  # noqa: E402
 
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 # The window: first submission at 100.0, a 3 s ramp, 10 s long: [103, 113].
@@ -172,6 +175,66 @@ def test_readers_with_no_record_of_their_kind_in_the_window():
     assert runner.load_reader("wal.flushes_per_tx")(dict(OBS, executed_in_window=0)) is None
 
 
+# The 22 readers of the ring (the eleven above and the loop account's eleven) on
+# one hand-built ring of both kinds of record, 50 rounds in the window.
+RING_METRICS = FLIGHT_METRICS + LOOP_METRICS
+RING_OBS = dict(OBS, window={"rounds": 50.0})
+RING_BY_HAND = {**BY_HAND, **LOOP_BY_HAND}
+
+
+def fill_both() -> None:
+    fill()
+    for row in LOOPS:
+        tracing.flight("loop", *row)
+    for row in OWNERS:
+        tracing.flight("owner", *row)
+
+
+def test_the_readers_keep_reading_the_snapshot_after_the_ring_is_overwritten():
+    """What the run keeps when its drain ends is what the readers read, however
+    much the committee writes after it (the trace write, the shutdown)."""
+    fill_both()
+    obs = dict(RING_OBS, flight=runner.take_flight(traced=True, acked=1))
+    for _ in range(tracing.FLIGHT_RING):
+        tracing.flight("kernel_load", "msm_accumulate_kernel", "uint8[2048,112]", "hit", 500.0, 0.01)
+    assert not any(r.kind == "ingest_first" for r in tracing.flight_dump()["events"])
+    for metric in RING_METRICS:
+        assert runner.load_reader(metric)(obs) == pytest.approx(RING_BY_HAND[metric], rel=1e-6), metric
+        assert runner.load_reader(metric)(RING_OBS) is None, metric  # the live ring lost the window
+
+
+@pytest.mark.parametrize("metric", RING_METRICS)
+def test_the_snapshot_and_the_live_ring_read_alike(metric):
+    fill_both()
+    obs = dict(RING_OBS, flight=runner.take_flight(traced=True, acked=1))
+    assert runner.load_reader(metric)(obs) == runner.load_reader(metric)(RING_OBS)
+
+
+def test_a_traced_run_whose_snapshot_lost_the_window_is_a_harness_fault():
+    fill(firsts=())  # the ring pushed the first submissions out
+    with pytest.raises(runner.HarnessFault, match=f"records kept by a ring of {tracing.FLIGHT_RING}, which cover"):
+        runner.take_flight(traced=True, acked=3)
+    # Untraced, or no client answered: no reader needs the window.
+    assert runner.take_flight(traced=False, acked=3)["ring_capacity"] == tracing.FLIGHT_RING
+    assert runner.take_flight(traced=True, acked=0)["events"]
+
+
+def test_the_snapshots_record_gives_its_size_and_the_rings_headroom():
+    fill()
+    flight = runner.take_flight(traced=True, acked=1)
+    flight["t_taken"] = 114.0  # a drain of 1 s past the window [103, 113]
+    kept = fw.coverage(dict(OBS, flight=flight))
+    # 5 flushes, 3 wakes, 7 stages, 4 walks, 6 lags, 5 compiles, 7 WAL flushes, 3 firsts, 3 certified.
+    # Written in the window: 4 + 2 + 7 + 3 + 5 + 2 + 5 + 0 + 2; after it: 1 + 0 + 0 + 0 + 0 + 2 + 1 + 0 + 1.
+    assert kept == {"records": 43, "ring_capacity": tracing.FLIGHT_RING, "seconds_kept": 14.0,
+                    "headroom_s": 3.0, "per_s_window": 3.0, "per_s_drain": 5.0}
+    assert fw.coverage(OBS) is None  # no snapshot taken
+    fill(firsts=())
+    flight = runner.take_flight(traced=False, acked=1)
+    flight["t_taken"] = 114.0
+    assert fw.coverage(dict(OBS, flight=flight))["headroom_s"] is None  # the window is lost
+
+
 def test_rehearsal_traced_line_holds_the_three_counts():
     """The real entry on the CPU: the ring is filled by the program, survives
     the committee's shutdown, and the three count metrics reach the line."""
@@ -197,6 +260,10 @@ def test_rehearsal_traced_line_holds_the_three_counts():
         assert metrics["wal.flushes_per_tx"]["value"] > 0
     else:
         assert "wal.flushes_per_tx" not in metrics
+    # The snapshot of the ring is in the run's record by its size, not its records.
+    kept = out["observed"]["flight"]
+    assert kept["ring_capacity"] == 2**18 and "events" not in kept and 0 < kept["records"] < 2**18
+    assert kept["headroom_s"] >= 3.0 and kept["per_s_window"] > 0  # the ring holds the ramp and the boot
     # A CPU run prints counts only: none of the eight spans.
     assert not set(metrics) & (set(FLIGHT_METRICS) - {"verify.row_fill_share", "wal.flushes_per_tx",
                                                       "kernels.first_dispatches_in_window"})
