@@ -143,11 +143,20 @@ class ExecutorCore:
     ) -> None:
         """Burst drain: apply the whole batch in one tight loop, buffering
         (result, transaction) pairs and flushing them with one send_many.
-        The cursor advances per applied transaction, so a crash anywhere
-        mid-batch replays from exactly the next unapplied transaction —
-        and the flush runs in a finally so results applied before a crash
-        still reach the output channel exactly once (replay skips them
-        below the watermark and never re-emits)."""
+        The cursor advances per applied transaction, so for a state that
+        persists it per transaction a crash anywhere mid-batch replays from
+        exactly the next unapplied transaction — and the flush runs in a
+        finally so results applied before a crash still reach the output
+        channel exactly once (replay skips them below the watermark and
+        never re-emits).
+
+        A handler that never suspends gives the loop up nowhere in this
+        loop, so the batch runs whole and cancellation or shutdown lands
+        between batches. Such a state may persist only the cursor a
+        batch's last transaction is handed (`next_transaction_index == 0`),
+        as the default `SimpleExecutionState` does: a crash mid-batch then
+        replays the batch from its first transaction, whose results died
+        with the process."""
         total_transactions = len(batch.transactions)
         outbox: list | None = [] if self.tx_output is not None else None
         try:

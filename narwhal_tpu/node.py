@@ -53,8 +53,22 @@ class SimpleExecutionState(ExecutionState):
         self._indices = ExecutionIndices()
 
     async def handle_consensus_transaction(self, output, indices, transaction):
+        """Keeps the cursor in memory per transaction and writes it to the
+        store once per executed batch: when `indices` sits on a batch
+        boundary (`next_transaction_index == 0`, which `ExecutionIndices.next`
+        gives exactly as a batch's last transaction executes).
+
+        Sound because this state applies nothing and never suspends, so
+        `ExecutorCore._execute_batch` runs a whole batch without giving the
+        loop up: no other task, shutdown or cancellation sees the cursor
+        between two transactions of one batch, and a graceful stop leaves
+        the same boundary on disk as a per-transaction write would. The
+        batch's results leave in one `send_many` after its last transaction,
+        so a crash mid-batch loses them with the process and the restart
+        replays the batch from its first transaction: each output is emitted
+        once. A state that applies effects persists its cursor with them."""
         self._indices = indices
-        if self._cf is not None:
+        if self._cf is not None and indices.next_transaction_index == 0:
             self._cf.put(b"indices", indices.to_bytes())
         return b""
 
