@@ -1,0 +1,21 @@
+"""Milliseconds a committed round of the loop's time under the program's
+`net:aead` label (`tracing.nested`, family `network`): the AES-GCM seal and
+open of a frame's body (`Session.seal_body` in `_write_frame`,
+`Session.open_body` in `_read_frame`).
+An estimate from the stretches the loop account keeps (`loop_account`). A
+site's label has a row in every stretch that ran it, however small, so the
+three labels, the family's other owners and its `rest` row sum to
+`loop.network_ms_per_round`. None where no kept stretch in the window holds
+the label: a program that does not split the network family."""
+
+from chipbench.readers import loop_account
+
+OWNER = "net:aead"
+
+
+def read(obs):
+    acct = loop_account.account(obs)
+    if acct is None:
+        return None
+    row = next((r for (owner, _), r in acct.owners.items() if owner == OWNER), None)
+    return None if row is None else loop_account.ms_per_round(obs, acct, row[1])

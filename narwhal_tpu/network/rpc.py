@@ -41,8 +41,10 @@ import itertools
 import logging
 import random
 import struct
+import time
 from typing import Awaitable, Callable, Iterable
 
+from .. import tracing
 from ..bounded_cache import BoundedCache
 from ..channels import CancelOnDrop
 from ..messages import REGISTRY, Ack, decode_message, encode_message
@@ -95,8 +97,9 @@ LANE_UNAVAILABLE = b"lane-unavailable"
 @functools.lru_cache(maxsize=None)
 def dispatch_task_name(tag: int) -> str:
     """`rpc:<message>` for a wire tag: the name of a frame's dispatch task,
-    which is the owner the loop account (tracing.py) charges its decode, its
-    handler and its reply to."""
+    which is the owner the loop account (tracing.py) charges its handler and
+    the sending of its reply to (the decode and the reply's encode are
+    `net:codec`'s)."""
     cls = REGISTRY.get(tag)
     return f"rpc:{cls.__name__ if cls else tag}"
 
@@ -158,28 +161,24 @@ def _pack(kind: int, rid: int, tag: int, body: bytes, lane: int = 0) -> bytes:
 class WireStats:
     """Process-wide wire counters: every frame written/read by every peer
     link in this process (an in-process committee's WHOLE control plane).
-    Two integer adds per frame — cheap enough to stay always-on; the
+    Integer adds per frame — cheap enough to stay always-on; the
     benchmark harness samples `snapshot()` around its measurement window
-    to report bytes-per-round (the metric the compact-certificate wire
-    form exists to move) and frames-per-drain (the metric the write
-    coalescer exists to move)."""
+    to report bytes and frames per transaction, frames per drain, socket
+    sends per frame and drainer starts per drain."""
 
     frames_sent = 0
     bytes_sent = 0
     frames_received = 0
     bytes_received = 0
-    # Write-coalescing accounting: one "drain" = one socket flush covering
-    # every frame queued on that connection at that moment.
+    # Write-coalescing accounting: one "drain" = one turn of a connection's
+    # drainer, every frame queued on it at that moment written, then one
+    # flush.
     drains = 0
-    frames_per_drain: dict[int, int] = {}  # power-of-two bucket -> drains
-
-    @classmethod
-    def record_drain(cls, frames: int) -> None:
-        cls.drains += 1
-        bucket = 1
-        while bucket < frames:
-            bucket <<= 1
-        cls.frames_per_drain[bucket] = cls.frames_per_drain.get(bucket, 0) + 1
+    # Transport writes that found the transport's write buffer empty: there
+    # asyncio's `write` calls the socket's `send` at once, one system call.
+    sends = 0
+    # Drainer tasks `FrameSender.send` started: bursts that found none running.
+    drainer_starts = 0
 
     @classmethod
     def snapshot(cls) -> dict:
@@ -189,7 +188,8 @@ class WireStats:
             "frames_received": cls.frames_received,
             "bytes_received": cls.bytes_received,
             "drains": cls.drains,
-            "frames_per_drain": dict(sorted(cls.frames_per_drain.items())),
+            "sends": cls.sends,
+            "drainer_starts": cls.drainer_starts,
         }
 
 
@@ -331,17 +331,26 @@ def _write_frame(
     # (hundreds of KB) and the header+body copy showed up at high rates.
     # On authenticated connections the body is AEAD-sealed (AES-GCM,
     # counter nonce, header as AAD); seal+write happen without an await in
-    # between so the nonce sequence matches the wire order.
+    # between so the nonce sequence matches the wire order. While the loop
+    # account keeps a stretch, the seal is `net:aead`'s and the writes (where
+    # asyncio calls the socket's `send`) are `net:write`'s.
     if session is not None:
-        ct = session.seal_body(kind, rid, tag, body, lane)
-        writer.write(_FRAME_HDR.pack(len(ct), kind, rid, tag, lane))
-        writer.write(ct)
-        wire_len = _FRAME_HDR.size + len(ct)
-    else:
-        writer.write(_FRAME_HDR.pack(len(body), kind, rid, tag, lane))
-        if body:
-            writer.write(body)
-        wire_len = _FRAME_HDR.size + len(body)
+        t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+        body = session.seal_body(kind, rid, tag, body, lane)
+        if t0:
+            tracing.nested("net:aead", t0)
+    transport = getattr(writer, "transport", None)  # none behind a buffer or simnet's writer
+    t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+    if transport is not None and not transport.get_write_buffer_size():
+        WireStats.sends += 1
+    writer.write(_FRAME_HDR.pack(len(body), kind, rid, tag, lane))
+    if body:
+        if transport is not None and not transport.get_write_buffer_size():
+            WireStats.sends += 1
+        writer.write(body)
+    if t0:
+        tracing.nested("net:write", t0)
+    wire_len = _FRAME_HDR.size + len(body)
     WireStats.frames_sent += 1
     WireStats.bytes_sent += wire_len
     if counters is not None:
@@ -379,8 +388,21 @@ async def _read_frame(
     if session is not None:
         if length < MAC_LEN:
             raise RpcError("unauthenticated frame on authenticated connection")
+        t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
         body = session.open_body(kind, rid, tag, body, lane)  # AuthError on forgery
+        if t0:
+            tracing.nested("net:aead", t0)
     return kind, rid, tag, lane, body
+
+
+def _decode(tag: int, body: bytes):
+    """`decode_message`, its time the loop account's `net:codec` while the
+    account keeps a stretch."""
+    t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+    msg = decode_message(tag, body)
+    if t0:
+        tracing.nested("net:codec", t0)
+    return msg
 
 
 class FrameSender:
@@ -466,6 +488,7 @@ class FrameSender:
         if self._inline:
             self._drain_inline()
         elif self._task is None or self._task.done():
+            WireStats.drainer_starts += 1
             self._task = asyncio.ensure_future(self._drain_loop())
 
     def _take_interleaved(self) -> list[tuple[int, int, int, int, bytes]]:
@@ -508,14 +531,16 @@ class FrameSender:
                         buf, kind, rid, tag, body, self._session,
                         self._counters, lane,
                     )
-                WireStats.record_drain(len(batch))
+                WireStats.drains += 1
                 # _FrameBuffer is a per-drain local scratch buffer: created,
                 # filled and read inside this one call frame (creator
                 # pattern) — the class is shared, the instance never is.
                 parts = buf.parts  # lint: allow(multi-task-mutation)
-                self._writer.write(
-                    parts[0] if len(parts) == 1 else b"".join(parts)
-                )
+                data = parts[0] if len(parts) == 1 else b"".join(parts)
+                t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+                self._writer.write(data)
+                if t0:
+                    tracing.nested("net:write", t0)
         except (ConnectionError, OSError) as e:
             self._closed = True
             self._queues.clear()
@@ -532,7 +557,7 @@ class FrameSender:
                         self._writer, kind, rid, tag, body, self._session,
                         self._counters, lane,
                     )
-                WireStats.record_drain(len(batch))
+                WireStats.drains += 1
                 # Frames enqueued while this drain awaits ride the next
                 # iteration — one flush each for whatever coalesced.
                 await self._writer.drain()
@@ -653,7 +678,7 @@ class PeerClient:
                     continue
                 if kind == KIND_RESP:
                     try:
-                        fut.set_result(decode_message(tag, body))
+                        fut.set_result(_decode(tag, body))
                     except Exception as e:  # decode error
                         fut.set_exception(RpcError(str(e)))
                 elif kind == KIND_ERR:
@@ -876,7 +901,7 @@ class PeerLink:
                     continue
                 if kind == KIND_RESP:
                     try:
-                        fut.set_result(decode_message(tag, body))
+                        fut.set_result(_decode(tag, body))
                     except Exception as e:  # decode error
                         fut.set_exception(RpcError(str(e)))
                 elif kind == KIND_ERR:
@@ -1232,7 +1257,7 @@ class RpcServer:
                 if cached is not None:
                     resp = await dedup(cached, peer)
                 else:
-                    msg = decode_message(tag, body)
+                    msg = _decode(tag, body)
                     # First write wins in BoundedCache, so a concurrent
                     # decode of the same body settles on one canonical
                     # message object; weight tracks the encoded size the
@@ -1240,7 +1265,7 @@ class RpcServer:
                     self._dedup.put(key, msg, weight=len(body) + 64)
                     resp = await handler(msg, peer)
             else:
-                msg = decode_message(tag, body)
+                msg = _decode(tag, body)
                 resp = await handler(msg, peer)
             if oneway:
                 # Fire-and-forget frame: the handler ran, nothing to write
@@ -1248,7 +1273,10 @@ class RpcServer:
                 return
             if resp is None:
                 resp = Ack()
+            t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
             rtag, rbody = encode_message(resp)
+            if t0:
+                tracing.nested("net:codec", t0)
             out = (KIND_RESP, rid, rtag, rbody)
         except asyncio.CancelledError:
             raise

@@ -171,8 +171,9 @@ FLIGHT_FIELDS = {
     # less what the loop itself burnt between them), the longest single
     # stretch of one owner and who that was
     "loop": "loop t0 t1 handles busy_s cpu_s longest_s longest_owner",
-    # the same stretch by owner: the OWNER_ROWS owners with the most seconds,
-    # then one row `rest` per family for the others, so that a stretch's rows
+    # the same stretch by owner: the OWNER_ROWS owners with the most seconds
+    # and every label a site passed to `charge` or `nested`, then one row
+    # `rest` per family for the others, so that a stretch's rows
     # sum to its busy_s; calls = callbacks (for a label: segments opened),
     # longest = the owner's longest single stretch
     "owner": "loop t1 owner family calls seconds longest",
@@ -262,6 +263,7 @@ _HEARTBEATS: dict = {}  # running loop -> [task, holders]
 # and its qualified name: `narwhal_tpu/primary/core.py:Core.run`.
 OWNER_FAMILIES = (
     ("rpc:", "network"),
+    ("net:", "network"),
     ("core:", "primary"),
     ("stage:", "verify"),
     ("verify:", "verify"),
@@ -284,7 +286,9 @@ OWNER_FAMILIES = (
     ("narwhal_tpu/stores.py", "storage"),
 )
 FAMILIES = ("network", "primary", "worker", "verify", "execute", "storage", "harness", "other")
-# Owners that get a row of their own in a stretch's `owner` records.
+# Owners that get a row of their own in a stretch's `owner` records, beside
+# the sites' labels, which always do: they are few, and readers ask for them
+# by name.
 OWNER_ROWS = 24
 # The account keeps a stretch of ACCOUNT_KEEP_S, then rests ACCOUNT_REST_S
 # (`Handle._run` is asyncio's own meanwhile and the sites read ACCOUNTING
@@ -467,15 +471,21 @@ class _LoopAccount:
         longest_owner, longest = max(((o, r[3]) for o, r in ranked), key=lambda x: x[1], default=(None, 0.0))
         flight("loop", self.ordinal, self.t_flushed, t1, sum(r[1] for _, r in ranked) - opened,
                sum(r[2] for _, r in ranked), cpu - self.cpu_mark, longest, longest_owner)
-        rest: dict = {}  # family -> the owners past the first OWNER_ROWS, folded into one row
-        for _, (family, calls, seconds, longest) in ranked[OWNER_ROWS:]:
-            row = rest.setdefault(family, [family, 0, 0.0, 0.0])
-            row[1] += calls
-            row[2] += seconds
-            row[3] = max(row[3], longest)
+        labels = {key for key in tally if isinstance(key, str)}  # what sites passed to `charge` and `nested`
+        named: list = []  # the owners with a row of their own
+        rest: dict = {}  # family -> the others, folded into one row
+        for i, (owner, row) in enumerate(ranked):
+            if i < OWNER_ROWS or owner in labels:
+                named.append((owner, row))
+                continue
+            family, calls, seconds, longest = row
+            folded = rest.setdefault(family, [family, 0, 0.0, 0.0])
+            folded[1] += calls
+            folded[2] += seconds
+            folded[3] = max(folded[3], longest)
         kept = t1 - self.t_flushed
         stands_for = (t1 - self.t_before) / kept if self.t_before is not None and kept > 0 else 1.0
-        for owner, (family, calls, seconds, longest) in (*ranked[:OWNER_ROWS], *(("rest", r) for r in rest.values())):
+        for owner, (family, calls, seconds, longest) in (*named, *(("rest", r) for r in rest.values())):
             flight("owner", self.ordinal, t1, owner, family, calls, seconds, longest)
             LOOP_BUSY.labels(family).inc(seconds * stands_for)
         self.t_flushed, self.cpu_mark = t1, cpu
@@ -557,7 +567,7 @@ def charge(label: str):
 def nested(label: str, t0: float) -> None:
     """A synchronous stretch of the running callback, from `t0` (a
     `time.perf_counter()` reading the site took when it began) to now, goes
-    to `label` (`storage:wal`, `verify:seal`) and comes off what it
+    to `label` (`storage:wal`, `verify:seal`, `net:write`) and comes off what it
     interrupted, which resumes. One call and one clock read, for sites that
     run thousands of times a second; such a site reads `ACCOUNTING` first and
     takes `t0` only while it is true. Not across an `await`, and not around

@@ -139,6 +139,17 @@ def test_the_micro_timing_leaves_the_ring_as_it_was():
     tracing.new_generation()
 
 
+def test_the_site_timing_keeps_a_stretch_and_leaves_the_ring_and_the_loop_as_they_were():
+    tracing.new_generation()
+    tracing.flight("ingest_first", "worker-0", 1.0)
+    out = fp.sites(n=200)
+    assert set(out) == {"nested_site_resting_us", "nested_site_kept_us", "write_buffer_size_us"}
+    assert all(v > 0 for v in out.values())
+    assert list(tracing.FLIGHT) == [("ingest_first", "worker-0", 1.0)]
+    assert tracing.ACCOUNTING is False and not tracing._ACCOUNTS
+    tracing.new_generation()
+
+
 def test_the_split_of_a_submit_by_hand():
     rows = [
         {"lane": "singles", "total": 0.004, "precheck": 0.001, "fold": 0.0002, "jit_call": 0.002, "readback_start": 0.0003},
@@ -227,3 +238,16 @@ def test_the_owner_table_of_a_window_per_round_and_per_second(tmp_path, capsys):
     assert printed["per"] == "second" and (printed["window_s"], printed["covered_s"]) == pytest.approx((2.0, 2.0))
     assert printed["work_ms"] == pytest.approx(1000 * 1.7 / 2.0)
     assert len(printed["owners"]) == 3 <= fp.OWNERS_LISTED == 20
+
+
+def test_the_owner_table_lists_the_network_familys_parts_past_the_twenty_largest():
+    tracing.new_generation()
+    tracing.flight("loop", 1, 100.0, 101.0, 4000, 0.6, 0.6, 0.02, "o0")
+    for i in range(25):
+        tracing.flight("owner", 1, 101.0, f"o{i}", "other", 10, 0.02, 0.001)
+    for label, seconds in (("net:write", 0.03), ("net:aead", 0.001), ("net:codec", 0.002)):
+        tracing.flight("owner", 1, 101.0, label, "network", 10, seconds, 0.001)
+    table = fp.owners(fp.typed(tracing.flight_dump()["events"]), 100.0, 101.0, rounds=10)
+    listed = [o["owner"] for o in table["owners"]]
+    assert listed[0] == "net:write" and listed[-2:] == ["net:codec", "net:aead"]
+    assert len(listed) == fp.OWNERS_LISTED + 2 and "o24" not in listed

@@ -180,6 +180,34 @@ def test_the_rows_of_a_stretch_sum_to_its_busy_seconds_and_the_rest_is_folded_by
         sum(r.seconds for r in rows if r.family == "network"), rel=1e-9)
 
 
+def test_a_sites_label_keeps_a_row_of_its_own_past_the_largest_owners(monkeypatch):
+    """Tasks past `OWNER_ROWS` fold into their family's `rest`; a label a
+    site passed to `nested` or `charge` never does, however small, since a
+    reader asks for it by name."""
+    monkeypatch.setattr(tracing, "OWNER_ROWS", 1)
+
+    async def body():
+        tasks = []
+        for i in range(3):
+            t = asyncio.ensure_future(worker(0.01 * (i + 1)))
+            t.set_name(f"rpc:M{i}")
+            tasks.append(t)
+        await asyncio.gather(*tasks)
+        t0 = time.perf_counter()
+        block(0.001)
+        tracing.nested("net:codec", t0)
+        tracing.charge("core:vote")
+        block(0.001)
+
+    accounted(body)
+    rows = {(r.owner, r.family): r for r in records("owner")}
+    assert {"rpc:M2", "net:codec", "core:vote"} <= {owner for owner, _ in rows}
+    assert "rpc:M1" not in {owner for owner, _ in rows}
+    assert rows[("rest", "network")].calls == 4  # M0 and M1: two steps each
+    (kept,) = records("loop")
+    assert sum(r.seconds for r in rows.values()) == pytest.approx(kept.busy_s, rel=1e-9)
+
+
 def test_the_threads_cpu_time_tells_sleeping_from_working():
     async def sleeper():
         block(0.2)
@@ -294,6 +322,10 @@ def test_the_two_kinds_are_laid_out_as_flight_fields_says():
     ("narwhal_tpu/node.py:SimpleExecutionState.handle_consensus_transaction", "execute"),
     ("narwhal_tpu/node.py:PrimaryNode.spawn", "other"),
     ("storage:wal", "storage"),
+    ("net:aead", "network"),
+    ("net:write", "network"),
+    ("net:codec", "network"),
+    ("narwhal_tpu/network/rpc.py:FrameSender._drain_loop", "network"),
     ("narwhal_tpu/storage.py:StorageEngine._run_committer", "storage"),
     ("chipbench/run.py:serve.<locals>.submit", "harness"),
     ("chipbench/traffic.py:Generator.run", "harness"),
@@ -361,6 +393,128 @@ def test_the_verify_service_charges_its_seal_and_its_delivery():
     assert {r.family for r in records("owner") if r.owner.startswith("verify:")} == {"verify"}
 
 
+class _SlowSession:
+    """Seals and opens as `auth.Session` does (a 16-byte tag), each in 10 ms."""
+
+    def seal_body(self, kind, rid, tag, body, lane=0) -> bytes:
+        block(0.01)
+        return bytes(body) + bytes(16)
+
+    def open_body(self, kind, rid, tag, body, lane=0) -> bytes:
+        block(0.01)
+        return bytes(body[:-16])
+
+
+class _EmptyBuffer:
+    def get_write_buffer_size(self) -> int:
+        return 0
+
+
+class _SlowWriter:
+    """A StreamWriter whose every write stands for a socket `send` of 5 ms."""
+
+    def __init__(self):
+        self.transport = _EmptyBuffer()
+        self.chunks: list[bytes] = []
+
+    def write(self, data: bytes) -> None:
+        block(0.005)
+        self.chunks.append(bytes(data))
+
+    async def drain(self) -> None:
+        pass
+
+
+async def request_and_reply(monkeypatch) -> list:
+    """One request through `RpcServer._dispatch` in its dispatch task (a
+    decode and the reply's encode of 10 ms each), the reply sealed and
+    written by the connection's drainer (10 ms, and two writes of 5), then
+    read back and opened (10 ms); the clock readings the network's sites
+    took meanwhile."""
+    from narwhal_tpu.messages import SubmitTransactionMsg
+    from narwhal_tpu.network import rpc
+    from narwhal_tpu.network.auth import Peer
+
+    decode, encode = rpc.decode_message, rpc.encode_message
+    reads = []
+
+    class Clock:
+        @staticmethod
+        def perf_counter() -> float:
+            reads.append(1)
+            return time.perf_counter()
+
+    def slow_decode(tag, body):
+        block(0.01)
+        return decode(tag, body)
+
+    def slow_encode(msg):
+        block(0.01)
+        return encode(msg)
+
+    monkeypatch.setattr(rpc, "decode_message", slow_decode)
+    monkeypatch.setattr(rpc, "encode_message", slow_encode)
+    monkeypatch.setattr(rpc, "time", Clock)
+    server = rpc.RpcServer()
+
+    async def on_tx(msg, peer):
+        return None
+
+    server.route(SubmitTransactionMsg, on_tx)
+    writer = _SlowWriter()
+    sender = rpc.FrameSender(writer, _SlowSession())
+    tag, body = encode(SubmitTransactionMsg(b"tx"))
+    await asyncio.get_running_loop().create_task(
+        server._dispatch(sender, 1, tag, body, Peer("peer")), name=rpc.dispatch_task_name(tag))
+    await sender._task  # the drainer the reply started
+    reader = asyncio.StreamReader()
+    reader.feed_data(b"".join(writer.chunks))
+    reader.feed_eof()
+    kind, rid, _, _, reply = await rpc._read_frame(reader, _SlowSession())
+    assert (kind, rid) == (rpc.KIND_RESP, 1) and decode(*encode(rpc.Ack())) == decode(rpc.Ack.TAG, reply)
+    return reads
+
+
+def test_the_network_family_by_part_comes_off_the_drainer_and_the_dispatch_task(monkeypatch):
+    """The seal and the open are `net:aead`'s, the transport's writes
+    `net:write`'s, the request's decode and the reply's encode `net:codec`'s,
+    all three under `network`; what they take comes off the drainer and the
+    dispatch task they interrupted, and the stretch's rows still sum to its
+    busy seconds."""
+    monkeypatch.setattr(tracing, "OWNER_ROWS", 1000)  # every owner a row of its own
+    reads = []
+
+    async def body():
+        reads.extend(await request_and_reply(monkeypatch))
+
+    seconds = accounted(body)
+    rows = {r.owner: r for r in records("owner")}
+    assert {rows[o].family for o in ("net:aead", "net:write", "net:codec")} == {"network"}
+    assert (rows["net:aead"].calls, rows["net:write"].calls, rows["net:codec"].calls) == (2, 1, 2)
+    assert 0.02 <= seconds["net:aead"] < 0.03  # the seal and the open
+    assert 0.01 <= seconds["net:write"] < 0.02  # the header's write and the ciphertext's
+    assert 0.02 <= seconds["net:codec"] < 0.03  # the request's decode and the reply's encode
+    assert seconds["narwhal_tpu/network/rpc.py:FrameSender._drain_loop"] < 0.005
+    assert seconds["rpc:SubmitTransactionMsg"] < 0.005
+    assert len(reads) == 5  # one reading a site: decode, encode, seal, writes, open
+    (kept,) = records("loop")
+    assert sum(r.seconds for r in records("owner")) == pytest.approx(kept.busy_s, rel=1e-9)
+
+
+def test_a_resting_account_takes_no_clock_reading_at_the_network_sites(monkeypatch):
+    monkeypatch.setattr(tracing, "ACCOUNT_KEEP_S", 0.0)  # the heartbeat's first wake ends the stretch
+    monkeypatch.setattr(tracing, "ACCOUNT_REST_S", 60.0)
+    reads = [None]
+
+    async def body():
+        await asyncio.sleep(0.1)
+        assert tracing.ACCOUNTING is False and tracing._ACCOUNTS
+        reads[:] = await request_and_reply(monkeypatch)
+
+    seconds = accounted(body)
+    assert reads == [] and not {"net:aead", "net:write", "net:codec"} & set(seconds)
+
+
 def test_a_committee_on_one_loop_labels_its_own_sites(monkeypatch):
     """A four-validator `Cluster` on real loopback sockets, host crypto: the
     node's own heartbeat opens the account, and the labels of the served
@@ -383,6 +537,7 @@ def test_a_committee_on_one_loop_labels_its_own_sites(monkeypatch):
     assert {"core:header", "core:vote", "core:certificate"} <= set(family)
     assert {"stage:header", "stage:vote", "stage:certificate"} <= set(family)
     assert {"consensus:walk", "execute:certificate", "storage:wal"} <= set(family)
+    assert all(family.get(o) == "network" for o in ("net:aead", "net:write", "net:codec"))
     rpc = {o for o in family if o.startswith("rpc:")}
     assert rpc and all(family[o] == "network" for o in rpc) and not any(o[4:].isdigit() for o in rpc)
     assert family["narwhal_tpu/primary/proposer.py:Proposer.run"] == "primary"

@@ -382,8 +382,9 @@ def test_rpc_coalescing_equivalence_concurrent_vs_sequential(run):
 
 
 def test_wire_stats_records_frames_per_drain(run):
-    """The coalescing instrumentation: drains and the frames-per-drain
-    histogram advance, and frame counts reconcile with drains."""
+    """The coalescing instrumentation: four frames of one turn are one
+    drain, and the frames sent over the drains made is the mean a drain
+    carries (what the benchmark's `wire.frames_per_drain` reads)."""
     from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, WireStats
 
     async def scenario():
@@ -395,8 +396,97 @@ def test_wire_stats_records_frames_per_drain(run):
         await asyncio.sleep(0)
         after = WireStats.snapshot()
         assert after["drains"] == before["drains"] + 1
-        bucket4 = after["frames_per_drain"].get(4, 0)
-        assert bucket4 == before["frames_per_drain"].get(4, 0) + 1
+        assert after["frames_sent"] == before["frames_sent"] + 4
+        assert "frames_per_drain" not in after  # the histogram is gone: the two counts give its mean
+
+    run(scenario())
+
+
+class _BufferedTransport:
+    """A transport that sends at once what finds its buffer empty, as
+    asyncio's socket transport does, and holds whatever `pending` says."""
+
+    def __init__(self, pending: int = 0):
+        self.pending = pending
+
+    def get_write_buffer_size(self) -> int:
+        return self.pending
+
+
+class _TransportWriter(_MockTransportWriter):
+    def __init__(self, transport):
+        super().__init__()
+        self.transport = transport
+
+
+def test_a_send_is_a_write_that_found_the_buffer_empty(run):
+    """`WireStats.sends` counts the writes of a frame that found the
+    transport's buffer empty (header and body each: two), none where bytes
+    were pending, and none where no transport stands behind the writer."""
+    from narwhal_tpu.network.auth import Session
+    from narwhal_tpu.network.rpc import KIND_REQ, WireStats, _write_frame
+
+    def sends(writer, body=b"body", session=None) -> int:
+        before = WireStats.snapshot()
+        _write_frame(writer, KIND_REQ, 1, 7, body, session)
+        after = WireStats.snapshot()
+        assert after["frames_sent"] == before["frames_sent"] + 1
+        return after["sends"] - before["sends"]
+
+    assert sends(_TransportWriter(_BufferedTransport())) == 2
+    assert sends(_TransportWriter(_BufferedTransport()), body=b"") == 1  # an empty body is not written
+    assert sends(_TransportWriter(_BufferedTransport(pending=100))) == 0
+    assert sends(_MockTransportWriter()) == 0
+    session = Session(b"k" * 32, b"k" * 32)
+    assert sends(_TransportWriter(_BufferedTransport()), session=session) == 2  # header and ciphertext
+
+    async def on_loopback():
+        server = RpcServer()
+
+        async def on_tx(msg, peer):
+            return None
+
+        server.route(SubmitTransactionMsg, on_tx)
+        port = await server.start("127.0.0.1", 0)
+        net = NetworkClient()
+        before = WireStats.snapshot()
+        assert await net.unreliable_send(f"127.0.0.1:{port}", SubmitTransactionMsg(b"tx"))
+        after = WireStats.snapshot()
+        net.close()
+        await server.stop()
+        # A request and its Ack on an idle link: every write found its buffer
+        # empty, the request's header and body and the Ack's header (and its
+        # body, had it one).
+        from narwhal_tpu.messages import encode_message
+
+        assert after["frames_sent"] - before["frames_sent"] == 2
+        assert after["sends"] - before["sends"] == 3 + bool(encode_message(Ack())[1])
+
+    run(on_loopback())
+
+
+def test_a_drainer_start_is_a_burst_that_found_no_drainer_running(run):
+    """`WireStats.drainer_starts` counts the drainer tasks `send` started:
+    one for a burst of frames enqueued in one turn, one more for a burst
+    after that drainer ended, none for a transport that drains inline."""
+    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, WireStats
+
+    class _InlineWriter(_MockTransportWriter):
+        sync_drain = True
+
+    async def scenario():
+        before = WireStats.snapshot()
+        sender = FrameSender(_MockTransportWriter())
+        for rid in range(3):
+            sender.send(KIND_REQ, rid, 1, b"x")
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)  # the drainer's drain() returned and the task ended
+        sender.send(KIND_REQ, 9, 1, b"x")
+        await asyncio.sleep(0)
+        FrameSender(_InlineWriter()).send(KIND_REQ, 10, 1, b"x")
+        after = WireStats.snapshot()
+        assert after["drainer_starts"] - before["drainer_starts"] == 2
+        assert after["drains"] - before["drains"] == 3  # two by the drainers, one inline
 
     run(scenario())
 

@@ -3,7 +3,7 @@
     python3 -m tools.perf.flight_profile --xplane <dir or .xplane.pb> --flight <dump.json>
     python3 -m tools.perf.flight_profile --drive 8        # on the chip, through chipbench's set-up
     python3 -m tools.perf.flight_profile --drive 8 --split  # and what a flush's `seal -> dispatched` is made of
-    python3 -m tools.perf.flight_profile --micro          # what one record, add or mark costs here
+    python3 -m tools.perf.flight_profile --micro          # what one record, add, mark or nested site costs here
     python3 -m tools.perf.flight_profile --owners --drive 51          # the loop's time by owner, a whole window of the cell
     python3 -m tools.perf.flight_profile --owners --flight <dump.json>  # of a dump's whole span, per second
 
@@ -38,10 +38,12 @@ the event loop ran while the account kept a stretch, charged to the task,
 handler or wire tag that ran it) through the benchmark's own arithmetic
 (`chipbench.readers.loop_account`) and prints the window's busiest loop: how
 busy it was, its callbacks a second, its families and its twenty largest
-owners by milliseconds a round, with calls a round and each owner's longest
-stretch. With `--drive` the window and its rounds are the cell's own, nothing
-is profiled, and the eleven `loop.*` readers' values are printed beside the
-table; a dump alone is read over its whole span, per second.
+owners by milliseconds a round (and the network family's `net:` parts
+whatever their rank), with calls a round and each owner's longest stretch.
+With `--drive` the window and its rounds are the cell's own, nothing is
+profiled, and the fifteen `loop.*` readers' values and the three `wire.*`
+counts of the drainers are printed beside the table; a dump alone is read
+over its whole span, per second.
 
 A mark around an `await` (`commit_walk`, `execute`) is as wide as its
 coroutine's wall time, other tasks' turns included; what held the loop is the
@@ -243,7 +245,7 @@ def report(dump: dict, profile: Profile) -> dict:
 SPLIT_PARTS = ("precheck", "fold", "jit_call", "readback_start")
 
 
-OWNERS_LISTED = 20
+OWNERS_LISTED = 20  # and every `net:` label past them: the network family's parts
 
 
 def owners(by, t0: float, t1: float, rounds: float | None = None) -> dict | None:
@@ -266,7 +268,8 @@ def owners(by, t0: float, t1: float, rounds: float | None = None) -> dict | None
         "owners": [
             {"owner": owner, "family": family, "calls": calls / unit, "ms": 1000.0 * seconds / unit,
              "longest_ms": 1000.0 * longest}
-            for (owner, family), (calls, seconds, longest) in ranked[:OWNERS_LISTED]
+            for i, ((owner, family), (calls, seconds, longest)) in enumerate(ranked)
+            if i < OWNERS_LISTED or owner.startswith("net:")
         ],
     }
 
@@ -363,6 +366,43 @@ def micro(n: int = 200_000) -> dict:
     return out
 
 
+def sites(n: int = 200_000) -> dict:
+    """Microseconds a `nested` site costs on this host: a clock reading and
+    the call while the account keeps a stretch, the flag's read while it
+    rests; and the write-buffer read `WireStats.sends` makes before each
+    transport write, on a loopback socket."""
+    import asyncio
+    import timeit
+
+    keep = list(tracing.FLIGHT)
+
+    def site() -> None:
+        t0 = tracing.ACCOUNTING and time.perf_counter()
+        if t0:
+            tracing.nested("net:write", t0)
+
+    async def body() -> dict:
+        out = {"nested_site_resting_us": 1e6 * timeit.timeit(site, number=n) / n}
+        tracing.heartbeat_acquire()
+        try:
+            await asyncio.sleep(0)  # the account times callbacks from the next one on
+            assert tracing.ACCOUNTING
+            out["nested_site_kept_us"] = 1e6 * timeit.timeit(site, number=n) / n
+        finally:
+            tracing.heartbeat_release()
+        server = await asyncio.start_server(lambda r, w: None, "127.0.0.1", 0)
+        _, writer = await asyncio.open_connection("127.0.0.1", server.sockets[0].getsockname()[1])
+        out["write_buffer_size_us"] = 1e6 * timeit.timeit(writer.transport.get_write_buffer_size, number=n) / n
+        writer.close()
+        server.close()
+        return out
+
+    out = asyncio.run(body())
+    tracing.FLIGHT.clear()
+    tracing.FLIGHT.extend(keep)
+    return out
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="python3 -m tools.perf.flight_profile")
     ap.add_argument("--xplane", help="a trace directory or an .xplane.pb")
@@ -375,7 +415,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--owners", action="store_true", help="the loop's time by owner (no profile is taken or read)")
     args = ap.parse_args(argv)
     if args.micro:
-        print(json.dumps({"micro": micro()}), flush=True)
+        print(json.dumps({"micro": micro(), "sites": sites()}), flush=True)
         if not (args.drive or args.xplane):
             return 0
     if args.owners:
@@ -390,7 +430,9 @@ def main(argv: list[str] | None = None) -> int:
             out["metrics"] = {
                 name: runner.load_reader(name)(obs)
                 for name in ("loop.busy_share", "loop.work_ms_per_round", "loop.offcpu_share",
-                             *(f"loop.{family}_ms_per_round" for family in tracing.FAMILIES))
+                             *(f"loop.{family}_ms_per_round" for family in tracing.FAMILIES),
+                             *(f"loop.net_{part}_ms_per_round" for part in ("write", "aead", "codec", "drainer")),
+                             "wire.frames_per_drain", "wire.sends_per_frame", "wire.drainer_starts_per_drain")
             }
         elif args.flight:
             with open(args.flight) as f:
