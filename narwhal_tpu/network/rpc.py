@@ -174,8 +174,10 @@ class WireStats:
     # drainer, every frame queued on it at that moment written, then one
     # flush.
     drains = 0
-    # Transport writes that found the transport's write buffer empty: there
-    # asyncio's `write` calls the socket's `send` at once, one system call.
+    # Socket system calls asyncio makes at once for the frames written: one
+    # per `writelines` on a transport (`_write_parts`; Python 3.12's socket
+    # transport calls `sendmsg` there whatever its buffer holds), so one per
+    # drain. None behind a writer with no transport (simnet's).
     sends = 0
     # Drainer tasks `FrameSender.send` started: bursts that found none running.
     drainer_starts = 0
@@ -317,6 +319,60 @@ class WireCounters:
             pair[1].value += 1.0
 
 
+def _pack_frame(
+    parts: list,
+    kind: int,
+    rid: int,
+    tag: int,
+    body: bytes,
+    session: Session | None = None,
+    counters: WireCounters | None = None,
+    lane: int = 0,
+) -> None:
+    """Append one frame's header and body to `parts`, in wire order, and
+    count it as sent. On authenticated connections the body is AEAD-sealed
+    (AES-GCM, counter nonce, header as AAD) here, so a caller that packs
+    frames in the order it writes them keeps the nonce sequence in wire
+    order. While the loop account keeps a stretch, the seal is
+    `net:aead`'s."""
+    if session is not None:
+        t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+        body = session.seal_body(kind, rid, tag, body, lane)
+        if t0:
+            tracing.nested("net:aead", t0)
+    parts.append(_FRAME_HDR.pack(len(body), kind, rid, tag, lane))
+    if body:
+        parts.append(body)
+    wire_len = _FRAME_HDR.size + len(body)
+    WireStats.frames_sent += 1
+    WireStats.bytes_sent += wire_len
+    if counters is not None:
+        counters.record_sent(tag, wire_len, lane)
+
+
+def _write_parts(writer: asyncio.StreamWriter, parts: list) -> None:
+    """Hand packed frames to the writer as ONE `writelines`: asyncio's socket
+    transport (Python 3.12) wraps the parts in memoryviews and calls the
+    socket's `sendmsg` at once, whatever its buffer already holds — one
+    system call, no copy of a large body; what the socket does not take it
+    buffers and writes when the socket is ready, as `write` does. That call
+    is what `WireStats.sends` counts (none behind a writer with no
+    transport: simnet's, a test's). The write is `net:write`'s while the
+    loop account keeps a stretch."""
+    transport = getattr(writer, "transport", None)
+    if transport is not None:
+        if transport.is_closing():
+            # Python 3.12's `writelines` does not drop data for a lost
+            # connection as `write` does; it would re-register the closed
+            # socket with the selector. The drain would raise this anyway.
+            raise ConnectionResetError("transport is closing")
+        WireStats.sends += 1
+    t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
+    writer.writelines(parts)
+    if t0:
+        tracing.nested("net:write", t0)
+
+
 def _write_frame(
     writer: asyncio.StreamWriter,
     kind: int,
@@ -327,48 +383,11 @@ def _write_frame(
     counters: WireCounters | None = None,
     lane: int = 0,
 ) -> None:
-    # Two writes instead of one concatenated buffer: batch frames are large
-    # (hundreds of KB) and the header+body copy showed up at high rates.
-    # On authenticated connections the body is AEAD-sealed (AES-GCM,
-    # counter nonce, header as AAD); seal+write happen without an await in
-    # between so the nonce sequence matches the wire order. While the loop
-    # account keeps a stretch, the seal is `net:aead`'s and the writes (where
-    # asyncio calls the socket's `send`) are `net:write`'s.
-    if session is not None:
-        t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
-        body = session.seal_body(kind, rid, tag, body, lane)
-        if t0:
-            tracing.nested("net:aead", t0)
-    transport = getattr(writer, "transport", None)  # none behind a buffer or simnet's writer
-    t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
-    if transport is not None and not transport.get_write_buffer_size():
-        WireStats.sends += 1
-    writer.write(_FRAME_HDR.pack(len(body), kind, rid, tag, lane))
-    if body:
-        if transport is not None and not transport.get_write_buffer_size():
-            WireStats.sends += 1
-        writer.write(body)
-    if t0:
-        tracing.nested("net:write", t0)
-    wire_len = _FRAME_HDR.size + len(body)
-    WireStats.frames_sent += 1
-    WireStats.bytes_sent += wire_len
-    if counters is not None:
-        counters.record_sent(tag, wire_len, lane)
-
-
-class _FrameBuffer:
-    """Write-capture shim for FrameSender's inline fast path: collects the
-    header/body writes `_write_frame` emits so a whole burst can reach the
-    transport as one buffer."""
-
-    __slots__ = ("parts",)
-
-    def __init__(self) -> None:
-        self.parts: list[bytes] = []
-
-    def write(self, data: bytes) -> None:
-        self.parts.append(data)
+    """Write one frame on its own (the handshake's): header and body in one
+    `writelines`."""
+    parts: list[bytes] = []
+    _pack_frame(parts, kind, rid, tag, body, session, counters, lane)
+    _write_parts(writer, parts)
 
 
 async def _read_frame(
@@ -409,12 +428,13 @@ class FrameSender:
     """Per-connection write coalescer with PER-LANE flow control: frames
     enqueue synchronously into their lane's queue; a single drainer task
     interleaves the lane queues ROUND-ROBIN (one frame per non-empty lane
-    per pass) and packs the interleaved burst into `writer.write` calls
-    followed by ONE `drain()`. Nagle without the delay — nothing ever waits
-    for more traffic, but whatever is already pending when the socket
-    flushes shares that flush, so an N-frame burst (a broadcast fan-in, a
-    server's concurrent responses) costs one syscall round-trip instead
-    of N.
+    per pass) and hands the interleaved burst's headers and bodies to ONE
+    `writer.writelines` (one `sendmsg`, `_write_parts`) followed by ONE
+    `drain()`. Nagle without the delay — nothing ever waits for more
+    traffic, but whatever is already pending when the drainer runs shares
+    its system call, so an N-frame burst (a broadcast fan-in, a server's
+    concurrent responses) costs one syscall instead of 2N. The bytes on the
+    wire are exactly those of the same frames written one by one.
 
     The round-robin is the pool's fairness mechanism: on a multiplexed
     connection, a saturated bulk lane (a worker's batch relay backlog)
@@ -426,7 +446,7 @@ class FrameSender:
 
     AEAD sealing happens at WRITE time in interleaved order, so the
     session's counter-nonce sequence always matches the wire order (the
-    invariant `_write_frame` documents). Post-handshake, a connection's
+    invariant `_pack_frame` documents). Post-handshake, a connection's
     frames MUST all go through its sender — a second writer would fork the
     nonce sequence.
 
@@ -518,24 +538,22 @@ class FrameSender:
         self._depth = 0
         return batch
 
+    def _pack_turn(self) -> list[bytes]:
+        """One turn's frames, taken round-robin and sealed in that order
+        (the nonce sequence is the wire order), as their headers and bodies
+        in wire order."""
+        parts: list[bytes] = []
+        for kind, rid, tag, lane, body in self._take_interleaved():
+            _pack_frame(parts, kind, rid, tag, body, self._session, self._counters, lane)
+        WireStats.drains += 1
+        return parts
+
     def _drain_inline(self) -> None:
-        """Synchronous drain for no-buffer transports: seal in interleaved
-        order (same nonce invariant as the task path) and hand the packed
-        burst to the writer as ONE write."""
+        """Synchronous drain for no-buffer transports: the packed burst goes
+        to the writer as ONE joined write."""
         try:
             while self._depth:
-                batch = self._take_interleaved()
-                buf = _FrameBuffer()
-                for kind, rid, tag, lane, body in batch:
-                    _write_frame(
-                        buf, kind, rid, tag, body, self._session,
-                        self._counters, lane,
-                    )
-                WireStats.drains += 1
-                # _FrameBuffer is a per-drain local scratch buffer: created,
-                # filled and read inside this one call frame (creator
-                # pattern) — the class is shared, the instance never is.
-                parts = buf.parts  # lint: allow(multi-task-mutation)
+                parts = self._pack_turn()
                 data = parts[0] if len(parts) == 1 else b"".join(parts)
                 t0 = tracing.ACCOUNTING and time.perf_counter()  # lint: allow(no-wall-clock-in-actors)
                 self._writer.write(data)
@@ -551,13 +569,9 @@ class FrameSender:
     async def _drain_loop(self) -> None:
         try:
             while self._depth:
-                batch = self._take_interleaved()
-                for kind, rid, tag, lane, body in batch:
-                    _write_frame(
-                        self._writer, kind, rid, tag, body, self._session,
-                        self._counters, lane,
-                    )
-                WireStats.drains += 1
+                # One `writelines` a turn: one socket system call for every
+                # frame the turn took.
+                _write_parts(self._writer, self._pack_turn())
                 # Frames enqueued while this drain awaits ride the next
                 # iteration — one flush each for whatever coalesced.
                 await self._writer.drain()

@@ -140,6 +140,9 @@ class _SimWriter:
             raise ConnectionResetError("write after close")
         self._conn.fabric._transmit(self._conn, self._dir, bytes(data))
 
+    def writelines(self, data) -> None:
+        self.write(b"".join(data))
+
     async def drain(self) -> None:
         # No kernel send buffer to fill; readers buffer without bound (the
         # per-connection volume is capped by the protocol's own

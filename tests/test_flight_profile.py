@@ -142,12 +142,27 @@ def test_the_micro_timing_leaves_the_ring_as_it_was():
 def test_the_site_timing_keeps_a_stretch_and_leaves_the_ring_and_the_loop_as_they_were():
     tracing.new_generation()
     tracing.flight("ingest_first", "worker-0", 1.0)
-    out = fp.sites(n=200)
-    assert set(out) == {"nested_site_resting_us", "nested_site_kept_us", "write_buffer_size_us"}
-    assert all(v > 0 for v in out.values())
+    out = fp.sites(n=200, calls=64)
+    assert set(out) == {"nested_site_resting_us", "nested_site_kept_us", "is_closing_us", *LOOPBACK}
+    assert all(v > 0 for k, v in out.items() if k != "per_byte_ns")
     assert list(tracing.FLIGHT) == [("ingest_first", "worker-0", 1.0)]
     assert tracing.ACCOUNTING is False and not tracing._ACCOUNTS
     tracing.new_generation()
+
+
+LOOPBACK = {"send_21B_us", "send_3200B_us", "two_sends_frame_us", "sendmsg_frame_us",
+            "transport_two_writes_frame_us", "transport_joined_write_frame_us",
+            "writelines_drain_1.1_frames_us", "loop_reader_two_writes_frame_us",
+            "loop_reader_writelines_drain_1.1_frames_us", "per_byte_ns"}
+
+
+def test_the_loopback_calls_time_each_way_a_frame_reaches_the_socket():
+    """Each timing is of calls that all reached the peer: a run whose reads
+    came up short would hang or raise, not print."""
+    out = fp.loopback_calls(n=64, batch=16)
+    assert set(out) == LOOPBACK
+    assert all(v > 0 for k, v in out.items() if k != "per_byte_ns")
+    assert out["per_byte_ns"] == 1e3 * (out["send_3200B_us"] - out["send_21B_us"]) / (3200 - 21)
 
 
 def test_the_split_of_a_submit_by_hand():

@@ -405,21 +405,22 @@ class _SlowSession:
         return bytes(body[:-16])
 
 
-class _EmptyBuffer:
-    def get_write_buffer_size(self) -> int:
-        return 0
+class _OpenTransport:
+    def is_closing(self) -> bool:
+        return False
 
 
 class _SlowWriter:
-    """A StreamWriter whose every write stands for a socket `send` of 5 ms."""
+    """A StreamWriter whose every `writelines` stands for a socket `sendmsg`
+    of 10 ms."""
 
     def __init__(self):
-        self.transport = _EmptyBuffer()
+        self.transport = _OpenTransport()
         self.chunks: list[bytes] = []
 
-    def write(self, data: bytes) -> None:
-        block(0.005)
-        self.chunks.append(bytes(data))
+    def writelines(self, data) -> None:
+        block(0.01)
+        self.chunks.extend(bytes(d) for d in data)
 
     async def drain(self) -> None:
         pass
@@ -428,7 +429,7 @@ class _SlowWriter:
 async def request_and_reply(monkeypatch) -> list:
     """One request through `RpcServer._dispatch` in its dispatch task (a
     decode and the reply's encode of 10 ms each), the reply sealed and
-    written by the connection's drainer (10 ms, and two writes of 5), then
+    written by the connection's drainer (10 ms, and one writelines of 10), then
     read back and opened (10 ms); the clock readings the network's sites
     took meanwhile."""
     from narwhal_tpu.messages import SubmitTransactionMsg
@@ -476,7 +477,7 @@ async def request_and_reply(monkeypatch) -> list:
 
 
 def test_the_network_family_by_part_comes_off_the_drainer_and_the_dispatch_task(monkeypatch):
-    """The seal and the open are `net:aead`'s, the transport's writes
+    """The seal and the open are `net:aead`'s, the transport's writelines
     `net:write`'s, the request's decode and the reply's encode `net:codec`'s,
     all three under `network`; what they take comes off the drainer and the
     dispatch task they interrupted, and the stretch's rows still sum to its
@@ -492,11 +493,11 @@ def test_the_network_family_by_part_comes_off_the_drainer_and_the_dispatch_task(
     assert {rows[o].family for o in ("net:aead", "net:write", "net:codec")} == {"network"}
     assert (rows["net:aead"].calls, rows["net:write"].calls, rows["net:codec"].calls) == (2, 1, 2)
     assert 0.02 <= seconds["net:aead"] < 0.03  # the seal and the open
-    assert 0.01 <= seconds["net:write"] < 0.02  # the header's write and the ciphertext's
+    assert 0.01 <= seconds["net:write"] < 0.02  # the one writelines of header and ciphertext
     assert 0.02 <= seconds["net:codec"] < 0.03  # the request's decode and the reply's encode
     assert seconds["narwhal_tpu/network/rpc.py:FrameSender._drain_loop"] < 0.005
     assert seconds["rpc:SubmitTransactionMsg"] < 0.005
-    assert len(reads) == 5  # one reading a site: decode, encode, seal, writes, open
+    assert len(reads) == 5  # one reading a site: decode, encode, seal, writelines, open
     (kept,) = records("loop")
     assert sum(r.seconds for r in records("owner")) == pytest.approx(kept.busy_s, rel=1e-9)
 

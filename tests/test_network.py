@@ -290,14 +290,19 @@ def test_large_frame(run):
 
 
 class _MockTransportWriter:
-    """StreamWriter stand-in recording every write and drain."""
+    """StreamWriter stand-in recording every write, writelines and drain."""
 
     def __init__(self):
         self.chunks: list[bytes] = []
+        self.writelines_calls = 0
         self.drains = 0
 
     def write(self, data: bytes) -> None:
         self.chunks.append(bytes(data))
+
+    def writelines(self, data) -> None:
+        self.writelines_calls += 1
+        self.chunks.extend(bytes(d) for d in data)
 
     async def drain(self) -> None:
         self.drains += 1
@@ -305,9 +310,10 @@ class _MockTransportWriter:
 
 def test_frame_sender_coalesces_one_drain_byte_identical(run):
     """K frames enqueued in one loop turn must reach the transport as ONE
-    drain whose bytes are exactly the K sequentially-written frames, in
-    enqueue order (the coalescer must never reorder or re-frame)."""
-    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, _write_frame
+    drain and ONE `writelines` whose bytes are exactly the K sequentially-
+    written frames, in enqueue order (the coalescer must never reorder or
+    re-frame)."""
+    from narwhal_tpu.network.rpc import _FRAME_HDR, KIND_REQ, FrameSender, _write_frame
 
     async def scenario():
         mock = _MockTransportWriter()
@@ -319,11 +325,47 @@ def test_frame_sender_coalesces_one_drain_byte_identical(run):
         assert mock.chunks == [] and mock.drains == 0
         await asyncio.sleep(0)  # let the drainer run once
         assert mock.drains == 1, "8 same-turn frames must share one drain"
+        assert mock.writelines_calls == 1, "and one writelines: one system call"
 
         sequential = _MockTransportWriter()
         for f in frames:
             _write_frame(sequential, *f)
+        assert sequential.writelines_calls == len(frames)
         assert b"".join(mock.chunks) == b"".join(sequential.chunks)
+        by_hand = b"".join(
+            _FRAME_HDR.pack(len(body), kind, rid, tag, 0) + body for kind, rid, tag, body in frames
+        )
+        assert b"".join(mock.chunks) == by_hand
+
+    run(scenario())
+
+
+def test_a_sealed_multi_lane_burst_opens_in_wire_order(run):
+    """A burst over three lanes, sealed, is one `writelines` whose frames
+    come round-robin by lane, and the peer opens every one in wire order:
+    the counter nonces were drawn in the order the frames lie on the wire."""
+    from narwhal_tpu.network.auth import Session
+    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, _read_frame
+
+    async def scenario():
+        mock = _MockTransportWriter()
+        sender = FrameSender(mock, Session(b"a" * 32, b"b" * 32))
+        queued = {0: [b"v0", b"v1", b"v2"], 1: [b"B" * 3200], 2: [b"c0", b""]}
+        rid = 0
+        for lane, bodies in queued.items():
+            for body in bodies:
+                rid += 1
+                sender.send(KIND_REQ, rid, 5, body, lane)
+        await asyncio.sleep(0)
+        assert (mock.drains, mock.writelines_calls) == (1, 1)
+
+        reader = asyncio.StreamReader()
+        reader.feed_data(b"".join(mock.chunks))
+        reader.feed_eof()
+        peer = Session(b"b" * 32, b"a" * 32)
+        got = [(lane, body) for _, _, _, lane, body in [await _read_frame(reader, peer) for _ in range(rid)]]
+        assert got == [(0, b"v0"), (1, b"B" * 3200), (2, b"c0"), (0, b"v1"), (2, b""), (0, b"v2")]
+        assert reader.at_eof()
 
     run(scenario())
 
@@ -403,14 +445,16 @@ def test_wire_stats_records_frames_per_drain(run):
 
 
 class _BufferedTransport:
-    """A transport that sends at once what finds its buffer empty, as
-    asyncio's socket transport does, and holds whatever `pending` says."""
+    """A transport that holds whatever `pending` says, and is open."""
 
     def __init__(self, pending: int = 0):
         self.pending = pending
 
     def get_write_buffer_size(self) -> int:
         return self.pending
+
+    def is_closing(self) -> bool:
+        return False
 
 
 class _TransportWriter(_MockTransportWriter):
@@ -420,9 +464,11 @@ class _TransportWriter(_MockTransportWriter):
 
 
 def test_a_send_is_a_write_that_found_the_buffer_empty(run):
-    """`WireStats.sends` counts the writes of a frame that found the
-    transport's buffer empty (header and body each: two), none where bytes
-    were pending, and none where no transport stands behind the writer."""
+    """`WireStats.sends` counts the system calls asyncio makes at once: one
+    `writelines` a frame written on its own (header and body together, or
+    header and ciphertext), one where bytes were pending too (Python 3.12's
+    socket transport calls `sendmsg` there whatever its buffer holds), none
+    where no transport stands behind the writer; and one a drain."""
     from narwhal_tpu.network.auth import Session
     from narwhal_tpu.network.rpc import KIND_REQ, WireStats, _write_frame
 
@@ -433,12 +479,12 @@ def test_a_send_is_a_write_that_found_the_buffer_empty(run):
         assert after["frames_sent"] == before["frames_sent"] + 1
         return after["sends"] - before["sends"]
 
-    assert sends(_TransportWriter(_BufferedTransport())) == 2
-    assert sends(_TransportWriter(_BufferedTransport()), body=b"") == 1  # an empty body is not written
-    assert sends(_TransportWriter(_BufferedTransport(pending=100))) == 0
+    assert sends(_TransportWriter(_BufferedTransport())) == 1
+    assert sends(_TransportWriter(_BufferedTransport()), body=b"") == 1
+    assert sends(_TransportWriter(_BufferedTransport(pending=100))) == 1
     assert sends(_MockTransportWriter()) == 0
     session = Session(b"k" * 32, b"k" * 32)
-    assert sends(_TransportWriter(_BufferedTransport()), session=session) == 2  # header and ciphertext
+    assert sends(_TransportWriter(_BufferedTransport()), session=session) == 1  # header and ciphertext
 
     async def on_loopback():
         server = RpcServer()
@@ -454,15 +500,149 @@ def test_a_send_is_a_write_that_found_the_buffer_empty(run):
         after = WireStats.snapshot()
         net.close()
         await server.stop()
-        # A request and its Ack on an idle link: every write found its buffer
-        # empty, the request's header and body and the Ack's header (and its
-        # body, had it one).
-        from narwhal_tpu.messages import encode_message
-
+        # A request and its Ack on an idle link: one drain each, one
+        # `sendmsg` each.
         assert after["frames_sent"] - before["frames_sent"] == 2
-        assert after["sends"] - before["sends"] == 3 + bool(encode_message(Ack())[1])
+        assert after["drains"] - before["drains"] == 2
+        assert after["sends"] - before["sends"] == 2
 
     run(on_loopback())
+
+
+class _CountingSocket:
+    """Stands in for a socket transport's socket: forwards every call and
+    records the names of the send calls."""
+
+    def __init__(self, sock):
+        self._sock = sock
+        self.calls: list[str] = []
+
+    def send(self, data, *args):
+        self.calls.append("send")
+        return self._sock.send(data, *args)
+
+    def sendmsg(self, buffers, *args):
+        self.calls.append("sendmsg")
+        return self._sock.sendmsg(buffers, *args)
+
+    def __getattr__(self, name):
+        return getattr(self._sock, name)
+
+
+async def _read_frames(reader, n: int) -> list[tuple[int, int, bytes]]:
+    from narwhal_tpu.network.rpc import _read_frame
+
+    out = []
+    for _ in range(n):
+        _, rid, _, lane, body = await _read_frame(reader)
+        out.append((rid, lane, bytes(body)))
+    return out
+
+
+def test_a_loopback_burst_of_k_frames_costs_one_sendmsg(run):
+    """On a real loopback socket a burst of K frames enqueued in one turn
+    reaches the socket as ONE `sendmsg` (no `send` at all), counted as one
+    send, and the peer reads the K frames whole and in order."""
+    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, WireStats
+
+    async def scenario():
+        got = asyncio.get_running_loop().create_future()
+        k = 8
+
+        async def on_conn(reader, writer):
+            got.set_result(await _read_frames(reader, k))
+            writer.close()
+
+        server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
+        _, writer = await asyncio.open_connection("127.0.0.1", server.sockets[0].getsockname()[1])
+        transport = writer.transport
+        real = transport._sock
+        transport._sock = spy = _CountingSocket(real)
+        try:
+            sender = FrameSender(writer)
+            sent = [(rid, rid % 2, bytes([rid]) * (21 if rid % 2 else 3200)) for rid in range(1, k + 1)]
+            before = WireStats.snapshot()
+            for rid, lane, body in sent:
+                sender.send(KIND_REQ, rid, 3, body, lane)
+            await sender._task
+            after = WireStats.snapshot()
+        finally:
+            transport._sock = real
+        assert spy.calls == ["sendmsg"]
+        assert (after["drains"] - before["drains"], after["sends"] - before["sends"]) == (1, 1)
+        # Lanes 1 and 0 alternate, so the round-robin keeps the enqueue order.
+        assert await asyncio.wait_for(got, 5.0) == sent
+        writer.close()
+        server.close()
+        await server.wait_closed()
+
+    run(scenario())
+
+
+def test_a_drain_the_socket_takes_in_part_arrives_whole_and_in_order(run):
+    """A drain far larger than the socket's buffers: the one `sendmsg` takes
+    part of it, asyncio holds the rest and writes it as the peer reads, and
+    every frame arrives whole, in the order it was written."""
+    import socket
+
+    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender
+
+    async def scenario():
+        listener = socket.create_server(("127.0.0.1", 0))
+        listener.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 8192)  # inherited by the accepted socket
+        release = asyncio.Event()
+        got = asyncio.get_running_loop().create_future()
+        sent = [(rid, rid % 3, bytes([rid]) * size)
+                for rid, size in enumerate((500_000, 21, 3200, 500_000, 0, 64_000, 500_000, 7), start=1)]
+
+        async def on_conn(reader, writer):
+            await release.wait()  # the peer reads nothing until the drain is under way
+            got.set_result(await _read_frames(reader, len(sent)))
+            writer.close()
+
+        server = await asyncio.start_server(on_conn, sock=listener)
+        _, writer = await asyncio.open_connection("127.0.0.1", listener.getsockname()[1])
+        writer.transport.get_extra_info("socket").setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 8192)
+        sender = FrameSender(writer)
+        for rid, lane, body in sent:
+            sender.send(KIND_REQ, rid, 3, body, lane)
+        await asyncio.sleep(0)  # the drainer ran its one writelines and waits in drain()
+        assert writer.transport.get_write_buffer_size() > 0, "the socket took only part of the drain"
+        release.set()
+        frames = await asyncio.wait_for(got, 10.0)
+        await asyncio.wait_for(sender._task, 5.0)
+        lanes = {lane: [f for f in sent if f[1] == lane] for lane in (1, 2, 0)}
+        round_robin = [q[i] for i in range(3) for q in lanes.values() if i < len(q)]
+        assert frames == round_robin
+        writer.close()
+        server.close()
+        await server.wait_closed()
+
+    run(scenario())
+
+
+def test_a_drain_on_a_closing_transport_fails_without_writing(run):
+    """A drainer that finds its transport closing writes nothing (asyncio's
+    `writelines` would hold the bytes of a lost connection) and reports the
+    connection as failed, as its `drain()` would have."""
+    from narwhal_tpu.network.rpc import KIND_REQ, FrameSender, RpcError
+
+    class _Closing(_BufferedTransport):
+        def is_closing(self) -> bool:
+            return True
+
+    async def scenario():
+        errors = []
+        writer = _TransportWriter(_Closing())
+        sender = FrameSender(writer, on_error=errors.append)
+        sender.send(KIND_REQ, 1, 3, b"x")
+        await sender._task
+        assert writer.chunks == [] and writer.writelines_calls == 0
+        assert [type(e) for e in errors] == [ConnectionResetError]
+        with pytest.raises(RpcError):
+            sender.send(KIND_REQ, 2, 3, b"y")
+
+    run(scenario())
 
 
 def test_a_drainer_start_is_a_burst_that_found_no_drainer_running(run):

@@ -3,7 +3,7 @@
     python3 -m tools.perf.flight_profile --xplane <dir or .xplane.pb> --flight <dump.json>
     python3 -m tools.perf.flight_profile --drive 8        # on the chip, through chipbench's set-up
     python3 -m tools.perf.flight_profile --drive 8 --split  # and what a flush's `seal -> dispatched` is made of
-    python3 -m tools.perf.flight_profile --micro          # what one record, add, mark or nested site costs here
+    python3 -m tools.perf.flight_profile --micro          # what one record, add, mark, nested site or socket call costs here
     python3 -m tools.perf.flight_profile --owners --drive 51          # the loop's time by owner, a whole window of the cell
     python3 -m tools.perf.flight_profile --owners --flight <dump.json>  # of a dump's whole span, per second
 
@@ -366,11 +366,12 @@ def micro(n: int = 200_000) -> dict:
     return out
 
 
-def sites(n: int = 200_000) -> dict:
+def sites(n: int = 200_000, calls: int = 20_000) -> dict:
     """Microseconds a `nested` site costs on this host: a clock reading and
     the call while the account keeps a stretch, the flag's read while it
-    rests; and the write-buffer read `WireStats.sends` makes before each
-    transport write, on a loopback socket."""
+    rests; the `is_closing()` read `_write_parts` makes before each
+    `writelines`; and what a socket system call costs on loopback
+    (`loopback_calls`)."""
     import asyncio
     import timeit
 
@@ -392,14 +393,147 @@ def sites(n: int = 200_000) -> dict:
             tracing.heartbeat_release()
         server = await asyncio.start_server(lambda r, w: None, "127.0.0.1", 0)
         _, writer = await asyncio.open_connection("127.0.0.1", server.sockets[0].getsockname()[1])
-        out["write_buffer_size_us"] = 1e6 * timeit.timeit(writer.transport.get_write_buffer_size, number=n) / n
+        out["is_closing_us"] = 1e6 * timeit.timeit(writer.transport.is_closing, number=n) / n
         writer.close()
         server.close()
         return out
 
     out = asyncio.run(body())
+    out.update(loopback_calls(calls))
     tracing.FLIGHT.clear()
     tracing.FLIGHT.extend(keep)
+    return out
+
+
+HEADER_B, BODY_B = 21, 3200  # a frame header; a body of the cell's typical frame
+
+
+def loopback_calls(n: int = 20_000, batch: int = 16) -> dict:
+    """Microseconds a socket call costs on a loopback TCP connection on this
+    host, so a `send` splits into a part per call and a part per byte: a
+    21 B `send`, a 3,200 B `send`, a frame (header, body) as two `send`s and
+    as one `sendmsg`; and through asyncio's socket transport, a frame as two
+    `write`s (as the drainer wrote before it took `writelines`), as one
+    `write` of the joined bytes, and a drain of 1.1 frames as one
+    `writelines` (every tenth drain two frames), as `FrameSender` writes it;
+    the last two again with the peer read by the same event loop. Only the
+    calls are timed: after every `batch` of them the peer reads
+    what arrived, untimed, so no call finds the socket full."""
+    import asyncio
+    import socket
+
+    listener = socket.create_server(("127.0.0.1", 0))
+    tx = socket.create_connection(listener.getsockname())
+    rx, _ = listener.accept()
+    listener.close()
+    tx.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)  # as asyncio's TCP transports set it
+    # `tx` stays blocking with no timeout: a socket with one polls before
+    # every `send`, a second system call. A batch fits its buffers.
+    rx.settimeout(10.0)
+    header, body = bytes(HEADER_B), bytes(BODY_B)
+    rounds = max(1, n // batch)
+
+    def read_back(nbytes: int) -> None:
+        while nbytes:
+            nbytes -= len(rx.recv(min(nbytes, 1 << 20)))
+
+    def timed(call, nbytes: int) -> float:
+        spent = 0.0
+        for _ in range(rounds):
+            t0 = time.perf_counter()
+            for _ in range(batch):
+                call()
+            spent += time.perf_counter() - t0
+            read_back(batch * nbytes)
+        return 1e6 * spent / (rounds * batch)
+
+    def two_sends() -> None:
+        tx.send(header)
+        tx.send(body)
+
+    frame = HEADER_B + BODY_B
+    out = {
+        "send_21B_us": timed(lambda: tx.send(header), HEADER_B),
+        "send_3200B_us": timed(lambda: tx.send(body), BODY_B),
+        "two_sends_frame_us": timed(two_sends, frame),
+        "sendmsg_frame_us": timed(lambda: tx.sendmsg([header, body]), frame),
+    }
+
+    async def transport(writer, frames_per_call, call) -> float:
+        """Microseconds a `call(writer, frames)` takes through asyncio's
+        socket transport; `frames_per_call(i)` frames on the i-th call."""
+        spent, done = 0.0, 0
+        for _ in range(rounds):
+            counts = [frames_per_call(done + i) for i in range(batch)]
+            t0 = time.perf_counter()
+            for k in counts:
+                call(writer, k)
+            spent += time.perf_counter() - t0
+            done += batch
+            left = sum(counts) * frame
+            while left:  # what the transport held back goes out as the loop turns
+                try:
+                    left -= len(rx.recv(1 << 20))
+                except BlockingIOError:
+                    await asyncio.sleep(0)
+        return 1e6 * spent / done
+
+    def two_writes(writer, k: int) -> None:
+        writer.write(header)
+        writer.write(body)
+
+    def joined_write(writer, k: int) -> None:
+        writer.write(header + body)
+
+    def writelines(writer, k: int) -> None:
+        writer.writelines([header, body] * k)
+
+    async def to_loop_reader(frames_per_call, call) -> float:
+        """As `transport`, with the peer a stream the same loop reads, as
+        in a co-hosted committee: each send also makes a socket the loop
+        polls readable."""
+        got = [0]
+
+        async def on_conn(reader, w) -> None:
+            while chunk := await reader.read(1 << 20):
+                got[0] += len(chunk)
+            w.close()
+
+        server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
+        _, writer = await asyncio.open_connection("127.0.0.1", server.sockets[0].getsockname()[1])
+        spent, done, want = 0.0, 0, 0
+        for _ in range(rounds):
+            counts = [frames_per_call(done + i) for i in range(batch)]
+            t0 = time.perf_counter()
+            for k in counts:
+                call(writer, k)
+            spent += time.perf_counter() - t0
+            done += batch
+            want += sum(counts) * frame
+            while got[0] < want:
+                await asyncio.sleep(0)
+        writer.close()
+        await writer.wait_closed()
+        server.close()
+        await server.wait_closed()
+        return 1e6 * spent / done
+
+    async def both() -> None:
+        rx.setblocking(False)
+        _, writer = await asyncio.open_connection(sock=tx)
+        out["transport_two_writes_frame_us"] = await transport(writer, lambda i: 1, two_writes)
+        out["transport_joined_write_frame_us"] = await transport(writer, lambda i: 1, joined_write)
+        out["writelines_drain_1.1_frames_us"] = await transport(
+            writer, lambda i: 2 if i % 10 == 9 else 1, writelines)
+        writer.close()
+        await writer.wait_closed()
+        out["loop_reader_two_writes_frame_us"] = await to_loop_reader(lambda i: 1, two_writes)
+        out["loop_reader_writelines_drain_1.1_frames_us"] = await to_loop_reader(
+            lambda i: 2 if i % 10 == 9 else 1, writelines)
+
+    asyncio.run(both())
+    rx.close()
+    out["per_byte_ns"] = 1e3 * (out["send_3200B_us"] - out["send_21B_us"]) / (BODY_B - HEADER_B)
     return out
 
 
